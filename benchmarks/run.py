@@ -91,6 +91,9 @@ def main(argv=None) -> None:
         tracer = Tracer()
         set_tracer(tracer)      # streaming drivers pick it up themselves
 
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
+
     from benchmarks.history import append_history, provenance, stamp
     prov = provenance(quick=args.quick)
 

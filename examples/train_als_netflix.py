@@ -83,6 +83,7 @@ import jax.numpy as jnp
 from repro.checkpoint import CheckpointManager
 from repro.core import als as als_mod
 from repro.core.partition import plan_for, plan_partitions
+from repro.launch.cache import REPO_ROOT, enable_compile_cache
 from repro.sparse import synth
 
 
@@ -349,7 +350,9 @@ def main():
                     choices=("constant", "inverse_time", "cosine"))
     ap.add_argument("--g", type=int, default=4,
                     help="block-grid side for the SGD solvers")
-    ap.add_argument("--ckpt", default="/tmp/cumf_ckpt")
+    ap.add_argument("--ckpt", default=os.path.join(REPO_ROOT, ".ckpt"),
+                    help="checkpoint directory; a run resumes from what it "
+                         "finds there")
     ap.add_argument("--autotune", action="store_true",
                     help="pick the layout knobs (ALS n_bins/k_multiple, "
                          "SGD per_tile_k/degree_sort) by the cuMF Alg.-2 "
@@ -386,6 +389,7 @@ def main():
         ap.error("--mesh requires --out-of-core (the in-core paths use "
                  "their own sharding entry points)")
 
+    enable_compile_cache()
     tracer = None
     if args.trace:
         from repro.obs import Tracer, set_tracer

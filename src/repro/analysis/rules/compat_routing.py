@@ -14,7 +14,7 @@ attribute chains and call vocabulary do):
   ``jax.shard_map`` / ``jax.experimental.shard_map`` imports or attribute
   chains are flagged, as is the legacy ``check_rep=`` vocabulary;
 - ``pallas_call`` must be spelled ``compat.pallas_call`` (that is where
-  the off-TPU ``interpret=`` degrade lives) — ``pl.pallas_call`` and
+  the compile-on-TPU / interpret-on-CPU choice lives) — ``pl.pallas_call`` and
   ``from jax.experimental.pallas import pallas_call`` are flagged, and an
   ``interpret=`` keyword on such a direct call is flagged on its own line
   so the fix is obvious.
@@ -92,8 +92,8 @@ class CompatRoutingRule(Rule):
                     dotted = dotted_name(node)
                     if dotted and not _base_is_compat(dotted):
                         flag(node, f"'{dotted}' bypasses the compat shim; "
-                                   f"use compat.{node.attr} (off-TPU "
-                                   "interpret fallback / vocabulary "
+                                   f"use compat.{node.attr} (the "
+                                   "backend dispatch / argument "
                                    "translation live there)")
             elif isinstance(node, ast.Call):
                 for kw in node.keywords:

@@ -1,123 +1,53 @@
-"""Version-compatibility shims for every version-sensitive JAX surface.
+"""One home for the JAX surfaces whose spelling is version-sensitive.
 
-This module is the *only* place in the repo allowed to reference JAX APIs
-that were renamed, added, or removed across the versions we support
-(floor: JAX 0.4.37, the pinned CI environment).  Everything else imports
-from here, so the next API drift is a one-file fix plus a green
-import-sweep test — not a call-site hunt.
+The supported JAX is the one the TPU image carries (0.9.0, see
+``pyproject.toml``).  Everything else imports these names from here, so the
+next API drift is a one-file fix plus a green import-sweep test — not a
+call-site hunt.
 
-Shimmed surfaces (see tests/test_compat.py for both branches of each):
+- :func:`make_mesh`: ``jax.make_mesh`` with ``axis_types`` spelled as
+  ``"auto"`` / ``"explicit"`` / ``None``.
+- :func:`shard_map`: ``jax.shard_map`` (``axis_names=`` = the manual axes,
+  ``check_vma=``).
+- :func:`tpu_compiler_params` / :func:`vmem`: ``pltpu.CompilerParams`` and
+  ``pltpu.VMEM``.
+- :func:`pallas_call`: ``pl.pallas_call`` that compiles on TPU, runs the
+  Pallas interpreter on the CPU backend (where the tests run), and refuses
+  every other backend rather than silently interpreting there.
 
-- ``AxisType`` / ``axis_types=`` mesh construction: ``jax.sharding.AxisType``
-  and the ``axis_types`` kwarg of ``jax.make_mesh`` appeared after 0.4.37;
-  :func:`make_mesh` passes them through when present and silently drops
-  them when not (all axes are Auto on old JAX anyway).
-- ``shard_map``: new JAX exposes ``jax.shard_map(..., axis_names=,
-  check_vma=)``; 0.4.37 only has ``jax.experimental.shard_map.shard_map(...,
-  auto=, check_rep=)``.  :func:`shard_map` accepts the *new* vocabulary and
-  translates (``axis_names`` = manual axes -> ``auto`` = mesh axes minus
-  manual; ``check_vma`` -> ``check_rep``).
-- ``pltpu.CompilerParams``: renamed from ``TPUCompilerParams``;
-  :func:`tpu_compiler_params` resolves whichever exists and drops kwargs
-  the resolved dataclass doesn't know.
-- ``pallas_call``: :func:`pallas_call` transparently degrades to
-  ``interpret=True`` when the default backend has no Mosaic compiler
-  (CPU-only hosts), so the kernel path runs everywhere tests run.
-- ``pltpu.VMEM`` scratch allocation via :func:`vmem`, gated on the
-  ``jax.experimental.pallas.tpu`` import itself succeeding.
-
-All resolution happens through module-level attributes looked up at call
-time, so tests can monkeypatch a branch (present / absent) without owning
-a second JAX install.
+Resolution goes through module-level attributes looked up at call time, so
+tests can monkeypatch them.
 """
 from __future__ import annotations
 
-import inspect
-import re
-from typing import Any
-
 import jax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as _pltpu
+from jax.sharding import AxisType
 
-try:  # absent on builds without the Mosaic/TPU pallas backend
-    from jax.experimental.pallas import tpu as _pltpu
-except ImportError:  # pragma: no cover - present on every pinned CI env
-    _pltpu = None
-
-try:
-    from jax.sharding import AxisType as _axis_type
-except ImportError:
-    _axis_type = None
-
-# New-style shard_map (axis_names/check_vma vocabulary).
-_NEW_SHARD_MAP = getattr(jax, "shard_map", None)
-
-# Legacy shard_map (auto/check_rep vocabulary); removed in newest JAX.
-try:
-    from jax.experimental.shard_map import shard_map as _LEGACY_SHARD_MAP
-except ImportError:  # pragma: no cover - still present on 0.4.37
-    _LEGACY_SHARD_MAP = None
-
-_JAX_MAKE_MESH = getattr(jax, "make_mesh", None)
+_NEW_SHARD_MAP = jax.shard_map
+_JAX_MAKE_MESH = jax.make_mesh
 _PALLAS_CALL = pl.pallas_call
-
-# Public probe results (read-only convenience; the functions below re-derive
-# their branch from the module attributes so monkeypatching works).
-AxisType = _axis_type
-HAS_AXIS_TYPE = _axis_type is not None
-
-
-def jax_version() -> tuple[int, ...]:
-    """``jax.__version__`` as a comparable int tuple (rc/dev tags dropped)."""
-    return tuple(int(p) for p in re.findall(r"\d+", jax.__version__)[:3])
 
 
 # ---------------------------------------------------------------------------
 # Mesh construction
 # ---------------------------------------------------------------------------
 
-def _make_mesh_kwargs(fn) -> set:
-    try:
-        return set(inspect.signature(fn).parameters)
-    except (TypeError, ValueError):  # pragma: no cover - C-level callables
-        return set()
-
-
 def make_mesh(axis_shapes, axis_names, *, devices=None, axis_types="auto"):
-    """Build a ``Mesh`` portably.
-
-    ``axis_types``: ``"auto"`` / ``"explicit"`` / ``None``.  Honored only
-    when both ``jax.sharding.AxisType`` and the ``axis_types`` kwarg of
-    ``jax.make_mesh`` exist; on older JAX every axis is implicitly Auto,
-    which is exactly what this repo's meshes want, so dropping the kwarg
-    is semantics-preserving.
-    """
+    """Build a ``Mesh``; ``axis_types`` is ``"auto"`` / ``"explicit"`` /
+    ``None`` (None leaves the choice to ``jax.make_mesh``)."""
     if axis_types not in (None, "auto", "explicit"):
         raise ValueError(
             f"axis_types must be 'auto', 'explicit', or None; got "
             f"{axis_types!r}")
     axis_shapes = tuple(axis_shapes)
     axis_names = tuple(axis_names)
-    fn = _JAX_MAKE_MESH
-    if fn is not None:
-        if (axis_types is not None and AxisType is not None
-                and "axis_types" in _make_mesh_kwargs(fn)):
-            kind = {"auto": AxisType.Auto,
-                    "explicit": AxisType.Explicit}[axis_types]
-            return fn(axis_shapes, axis_names, devices=devices,
-                      axis_types=(kind,) * len(axis_names))
-        return fn(axis_shapes, axis_names, devices=devices)
-    # Pre-``jax.make_mesh`` fallback: plain Mesh over a device grid.  Like
-    # jax.make_mesh, take the first prod(axis_shapes) devices when none are
-    # given (create_device_mesh requires an exact count).
-    from jax.experimental import mesh_utils
-    if devices is None:
-        n = 1
-        for s in axis_shapes:
-            n *= s
-        devices = jax.devices()[:n]
-    devs = mesh_utils.create_device_mesh(axis_shapes, devices=devices)
-    return jax.sharding.Mesh(devs, axis_names)
+    if axis_types is None:
+        return _JAX_MAKE_MESH(axis_shapes, axis_names, devices=devices)
+    kind = {"auto": AxisType.Auto, "explicit": AxisType.Explicit}[axis_types]
+    return _JAX_MAKE_MESH(axis_shapes, axis_names, devices=devices,
+                          axis_types=(kind,) * len(axis_names))
 
 
 # ---------------------------------------------------------------------------
@@ -126,76 +56,27 @@ def make_mesh(axis_shapes, axis_names, *, devices=None, axis_types="auto"):
 
 def shard_map(f, *, mesh, in_specs, out_specs, axis_names=None,
               check_vma=False):
-    """``shard_map`` in the new vocabulary, on any supported JAX.
-
-    ``axis_names``: mesh axes to run manually (None = all of them —
-    fully-manual, the new API's default).  ``check_vma`` maps onto the
-    legacy ``check_rep``.
-    """
-    if _NEW_SHARD_MAP is not None:
-        kwargs: dict[str, Any] = {}
-        if axis_names is not None:
-            kwargs["axis_names"] = set(axis_names)
-        return _NEW_SHARD_MAP(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_vma=check_vma,
-                              **kwargs)
-    if _LEGACY_SHARD_MAP is None:  # pragma: no cover
-        raise ImportError(
-            "no shard_map implementation found in this JAX install "
-            f"({jax.__version__}); need jax.shard_map or "
-            "jax.experimental.shard_map")
-    auto = frozenset()
+    """``jax.shard_map``; ``axis_names``: mesh axes to run manually (None =
+    all of them, the API's default)."""
+    kwargs = {}
     if axis_names is not None:
-        auto = frozenset(mesh.axis_names) - frozenset(axis_names)
-    return _LEGACY_SHARD_MAP(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_rep=check_vma,
-                             auto=auto)
+        kwargs["axis_names"] = set(axis_names)
+    return _NEW_SHARD_MAP(f, mesh=mesh, in_specs=in_specs,
+                          out_specs=out_specs, check_vma=check_vma, **kwargs)
 
 
 # ---------------------------------------------------------------------------
 # Pallas
 # ---------------------------------------------------------------------------
 
-def has_pallas_tpu() -> bool:
-    return _pltpu is not None
-
-
 def tpu_compiler_params(**kwargs):
-    """``pltpu.CompilerParams`` under either of its historical names.
-
-    Unknown kwargs are dropped (the param set also drifts between
-    versions); returns None when no TPU pallas backend is importable, which
-    ``pallas_call`` accepts.
-    """
-    if _pltpu is None:
-        return None
-    cls = getattr(_pltpu, "CompilerParams", None)
-    if cls is None:
-        cls = getattr(_pltpu, "TPUCompilerParams", None)
-    if cls is None:
-        return None
-    try:
-        known = set(inspect.signature(cls).parameters)
-        kwargs = {k: v for k, v in kwargs.items() if k in known}
-    except (TypeError, ValueError):  # pragma: no cover
-        pass
-    return cls(**kwargs)
+    """``pltpu.CompilerParams(**kwargs)``."""
+    return _pltpu.CompilerParams(**kwargs)
 
 
 def vmem(shape, dtype):
-    """A VMEM scratch allocation (``pltpu.VMEM(shape, dtype)``).
-
-    Without the TPU pallas backend the kernels only ever run interpreted
-    (see :func:`pallas_call`), where scratch needs nothing more than
-    shape/dtype — a generic ANY-space ``MemoryRef`` stands in so the
-    kernel path degrades instead of crashing.
-    """
-    if _pltpu is not None:
-        return _pltpu.VMEM(shape, dtype)
-    if hasattr(pl, "MemoryRef") and hasattr(pl, "ANY"):
-        return pl.MemoryRef(tuple(shape), dtype, pl.ANY)
-    raise RuntimeError(  # pragma: no cover - no known JAX hits this
-        "no VMEM-like scratch allocator found in this JAX install")
+    """A VMEM scratch allocation (``pltpu.VMEM(shape, dtype)``)."""
+    return _pltpu.VMEM(tuple(shape), dtype)
 
 
 def _backend() -> str:
@@ -203,17 +84,27 @@ def _backend() -> str:
 
 
 def interpret_default() -> bool:
-    """True when Pallas kernels must run interpreted (no Mosaic compiler)."""
-    return _backend() not in ("tpu",)
+    """Whether Pallas kernels run interpreted by default on this backend.
+
+    False on TPU, where Mosaic compiles them; True on the CPU backend, the
+    test host.  Any other backend raises: the kernels are written for the
+    TPU, and interpreting them elsewhere would hide that the device path
+    never ran.
+    """
+    backend = _backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"Pallas kernels compile only for TPU and are interpreted only on "
+        f"CPU; backend {backend!r} is neither (pass interpret=True to "
+        f"interpret explicitly)")
 
 
 def pallas_call(kernel, *, interpret=False, **kwargs):
-    """``pl.pallas_call`` that degrades to ``interpret=True`` off-TPU.
-
-    Compiled Mosaic lowering only exists on TPU backends; everywhere else
-    (the CPU-only CI host in particular) the same kernel runs through the
-    Pallas interpreter so the whole kernel path stays exercised.
-    """
-    if not interpret and interpret_default():
-        interpret = True
+    """``pl.pallas_call``: compiled on TPU, interpreted on CPU or when the
+    caller asks for it (see :func:`interpret_default`)."""
+    if not interpret:
+        interpret = interpret_default()
     return _PALLAS_CALL(kernel, interpret=interpret, **kwargs)

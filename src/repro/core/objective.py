@@ -18,7 +18,8 @@ def _sq_err_padded(x, theta, idx, val, cnt):
     idx   [m, K], val [m, K], cnt [m]
     """
     g = jnp.take(theta, idx, axis=0)                    # [m, K, f]
-    pred = jnp.einsum("uf,ukf->uk", x, g)
+    pred = jnp.einsum("uf,ukf->uk", x, g,
+                      precision=jax.lax.Precision.HIGHEST)
     mask = kref.mask_from_cnt(cnt, idx.shape[1], x.dtype)
     err = (val - pred) * mask
     return jnp.sum(err * err), jnp.sum(cnt)

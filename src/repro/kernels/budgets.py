@@ -44,11 +44,17 @@ are set against, with headroom for interpreter/layout slack):
   that is the point of the Fig. 7 ablation)          -> limit 4 MiB.
 - ``batch_solve_pallas`` (tb=8, F=128): ~1.02 MiB     -> limit 2 MiB.
 - ``sgd_tile_pallas``    (mb=nb=1024, f=128): streamed in ~1.01 MiB +
-  out 1 MiB, doubled, + 1 MiB scratch ~= 5.02 MiB     -> limit 8 MiB.
+  out 1 MiB, doubled, + 1 MiB scratch ~= 5.02 MiB     -> limit 8 MiB
+  (the factors stream transposed, ``[f, mb]`` / ``[f, nb]``, and each ELL
+  slot as a ``[1, 1, mb]`` row — the same element counts).
 
-All well under the 16 MiB chip VMEM, with room for the compiler's own
-temporaries.  A new kernel (or a tile-size bump) that blows its limit
-fails the lint job before it ever reaches hardware.
+All well under the 16 MiB default scoped VMEM, with room for the
+compiler's own temporaries — except the SGD tile's in-kernel ``[nb, mb]``
+one-hot, which is no block and which the model does not see: that kernel
+asks Mosaic for ``SGD_VMEM_LIMIT``.  A new kernel (or a tile-size bump)
+that blows its limit fails the lint job before it ever reaches hardware;
+``tests/test_tpu_compile.py`` compiles every kernel for a described v5e,
+which also catches what this model cannot.
 """
 from __future__ import annotations
 
@@ -57,6 +63,12 @@ import dataclasses
 #: mirror of launch.mesh.VMEM_BYTES (kept import-free; cross-checked in
 #: tests/test_analysis.py so the two cannot drift)
 VMEM_BYTES = 16 * (1 << 20)
+
+#: scoped-VMEM limit the SGD tile kernel asks Mosaic for: its in-kernel
+#: ``[nb, mb]`` one-hot temporaries are not blocks, so the footprint model
+#: below does not see them (19.4 MiB at mb = nb = 1024, F = 128 when
+#: compiled for a v5e, against the 16 MiB default scoped limit)
+SGD_VMEM_LIMIT = 32 * (1 << 20)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -99,8 +111,8 @@ def footprint_bytes(name: str, **dims) -> int:
         return 4 * 2 * (inb + out)
     if name == "sgd_tile_pallas":
         mb, nb, f = dims["mb"], dims["nb"], dims["f"]
-        fac = (mb + nb) * f                         # x + theta blocks
-        inb = 1 + 3 * mb + fac                      # lr, idx/val/mask, x0/t0
+        fac = (mb + nb) * f                         # x^T + theta^T blocks
+        inb = 1 + 3 * mb + fac                      # lr, slot rows, x0/t0
         out = fac
         scratch = fac                               # resident factor carry
         return 4 * (2 * (inb + out) + scratch)

@@ -10,7 +10,10 @@ memory exactly once.  The TPU analogue implemented here:
   the memory TPUs actually expose;
 - the per-thread outer products ``theta_v theta_v^T`` are re-associated into
   ``TM`` batched ``[F, TK] x [TK, F]`` MXU matmuls (``dot_general`` with a
-  batch dim) — a systolic array wants matmuls, not scalar FMAs;
+  batch dim) — a systolic array wants matmuls, not scalar FMAs.  B_u's
+  rating vector enters as a ``[TM, 1, TK]`` operand so that every Mosaic
+  matmul keeps a non-contracting dimension on both sides; all contractions
+  run at ``HIGHEST`` precision (f32, not one bf16 pass);
 - the rated feature rows arrive pre-gathered (``g = theta[idx]``, an XLA
   DMA-gather playing the role of the texture cache) and are streamed
   HBM -> VMEM tile by tile via BlockSpec (the shared-memory ``bin`` of the
@@ -32,6 +35,27 @@ from jax.experimental import pallas as pl
 from repro import compat
 
 
+def _herm_tile(g, v, msk):
+    """(A, B) contributions of one ``[TM, TK]`` rating tile.
+
+    A[u] = g_m[u]^T @ g[u]: TM batched [F,TK]x[TK,F] MXU matmuls.
+    B[u] = (val*mask)[u] @ g[u]: TM batched [1,TK]x[TK,F] matmuls; the
+    rating row is a ``[TM, 1, TK]`` operand and the unit dim is dropped
+    afterwards.
+    """
+    gm = g * msk[..., None]
+    a = jax.lax.dot_general(
+        gm, g, dimension_numbers=(((1,), (1,)), ((0,), (0,))),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
+    b = jax.lax.dot_general(
+        (v * msk)[:, None, :], g,
+        dimension_numbers=(((2,), (1,)), ((0,), (0,))),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
+    return a, b[:, 0, :]
+
+
 def _fused_herm_kernel(diag_ref, g_ref, val_ref, mask_ref,
                        a_ref, b_ref, acc_a, acc_b, *, n_ktiles: int):
     """One (row-tile, k-tile) grid step."""
@@ -42,23 +66,9 @@ def _fused_herm_kernel(diag_ref, g_ref, val_ref, mask_ref,
         acc_a[...] = jnp.zeros_like(acc_a)
         acc_b[...] = jnp.zeros_like(acc_b)
 
-    g = g_ref[...]                       # [TM, TK, F]
-    v = val_ref[...]                     # [TM, TK]
-    msk = mask_ref[...]                  # [TM, TK]
-    gm = g * msk[..., None]
-
-    # A[u] += (g_m[u]^T @ g[u]) : TM batched [F,TK]x[TK,F] MXU matmuls.
-    acc_a[...] += jax.lax.dot_general(
-        gm, g,
-        dimension_numbers=(((1,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32,
-    )
-    # B[u] += val[u] @ g[u] : TM batched [1,TK]x[TK,F] matmuls.
-    acc_b[...] += jax.lax.dot_general(
-        v * msk, g,
-        dimension_numbers=(((1,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32,
-    )
+    da, db = _herm_tile(g_ref[...], val_ref[...], mask_ref[...])
+    acc_a[...] += da
+    acc_b[...] += db
 
     @pl.when(k == n_ktiles - 1)
     def _epilogue():
@@ -126,15 +136,8 @@ def fused_herm_pallas(
 # ---------------------------------------------------------------------------
 
 def _herm_onebin_kernel(g_ref, val_ref, mask_ref, a_ref, b_ref):
-    g = g_ref[...]
-    msk = mask_ref[...]
-    gm = g * msk[..., None]
-    a_ref[...] = jax.lax.dot_general(
-        gm, g, dimension_numbers=(((1,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32)
-    b_ref[...] = jax.lax.dot_general(
-        val_ref[...] * msk, g, dimension_numbers=(((1,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32)
+    a_ref[...], b_ref[...] = _herm_tile(g_ref[...], val_ref[...],
+                                        mask_ref[...])
 
 
 def herm_hbm_accum(

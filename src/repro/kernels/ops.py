@@ -5,12 +5,11 @@ calls.  They handle:
 
 - the theta gather (XLA DMA-gather == the paper's texture-cached read),
 - padding m / K / F up to tile multiples (F to the MXU lane width),
-- kernel-vs-oracle dispatch (``use_kernel=False`` or non-TPU backends fall
-  back to the jnp oracle; on CPU the kernel runs in interpret mode inside
-  tests only — production entry points use the oracle on CPU so jit costs
-  stay sane).  ``mode="kernel"`` off-TPU no longer crashes: the pallas
-  calls go through ``repro.compat.pallas_call``, which degrades to the
-  interpreter when no Mosaic compiler is present.
+- kernel-vs-oracle dispatch by ``mode``: ``"ref"`` (the default) is the
+  jnp oracle in ``kernels.ref``; ``"kernel"`` runs the Pallas kernels,
+  compiled by Mosaic on TPU and run by the Pallas interpreter on the CPU
+  backend (``repro.compat.pallas_call``; any other backend raises);
+  ``"kernel_interpret"`` asks for the interpreter on any backend.
 """
 from __future__ import annotations
 

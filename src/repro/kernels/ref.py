@@ -29,9 +29,13 @@ def herm_ref(
     """
     F = g.shape[-1]
     gm = g * mask[..., None]
-    A = jnp.einsum("ukf,ukg->ufg", gm, g, preferred_element_type=jnp.float32)
+    # HIGHEST: an f32 reference; the TPU default would run one bf16 pass
+    hi = jax.lax.Precision.HIGHEST
+    A = jnp.einsum("ukf,ukg->ufg", gm, g, precision=hi,
+                   preferred_element_type=jnp.float32)
     A = A + diag[:, None, None] * jnp.eye(F, dtype=A.dtype)[None, :, :]
-    B = jnp.einsum("uk,ukf->uf", val * mask, g, preferred_element_type=jnp.float32)
+    B = jnp.einsum("uk,ukf->uf", val * mask, g, precision=hi,
+                   preferred_element_type=jnp.float32)
     return A, B
 
 

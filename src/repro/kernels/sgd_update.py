@@ -3,10 +3,12 @@
 CuMF_SGD keeps a (user-block, item-block) tile's factor slices resident
 while a thread block sweeps its samples.  The TPU analogue:
 
-- ``x [mb, f]`` and ``theta [nb, f]`` live in VMEM scratch across the
-  entire ELL-slot grid dimension and are written back to HBM exactly
-  once per tile (the same register-file re-homing as the hermitian
-  kernel's accumulator);
+- ``x`` and ``theta`` live in VMEM scratch, transposed (``[f, mb]``,
+  ``[f, nb]``: users and items on lanes), across the entire ELL-slot grid
+  dimension and are written back to HBM exactly once per tile (the same
+  register-file re-homing as the hermitian kernel's accumulator); each
+  slot streams in as ``[1, 1, mb]`` rows, so every block matches its array
+  in the last two dimensions as Mosaic requires;
 - the grid walks the K padded ELL slots ("arbitrary" semantics — the
   factor carry serializes them); one grid step updates all mb user rows
   concurrently, which is the batch of batch-Hogwild;
@@ -18,9 +20,8 @@ while a thread block sweeps its samples.  The TPU analogue:
   (``ref.sgd_block_ref``; summing instead diverges on power-law items).
 
 The public wrapper pads mb/nb/f/K to tile multiples and dispatches
-ref | kernel | kernel_interpret like the other ops; compilation is
-routed through ``compat.pallas_call`` so CPU hosts degrade to the
-interpreter.
+ref | kernel | kernel_interpret like the other ops; ``compat.pallas_call``
+compiles on TPU and interprets on CPU.
 """
 from __future__ import annotations
 
@@ -32,13 +33,19 @@ from jax.experimental import pallas as pl
 
 from repro import compat
 from repro.kernels import ref as kref
+from repro.kernels.budgets import SGD_VMEM_LIMIT
 from repro.kernels.ops import _pad_axis, _round_up
 
 
 def _sgd_tile_kernel(lr_ref, idx_ref, val_ref, mask_ref, x0_ref, t0_ref,
                      x_out, t_out, acc_x, acc_t, *, lam: float,
                      n_slots: int):
-    """One ELL-slot grid step over a full tile."""
+    """One ELL-slot grid step over a full tile.
+
+    Factors are held transposed (``x^T [f, mb]``, ``theta^T [f, nb]``), so a
+    slot's per-user vectors ``[1, mb]`` broadcast over sublanes and every
+    matmul keeps a non-contracting dimension on both sides.
+    """
     k = pl.program_id(0)
 
     @pl.when(k == 0)
@@ -46,30 +53,33 @@ def _sgd_tile_kernel(lr_ref, idx_ref, val_ref, mask_ref, x0_ref, t0_ref,
         acc_x[...] = x0_ref[...]
         acc_t[...] = t0_ref[...]
 
-    x = acc_x[...]                            # [mb, f]
-    th = acc_t[...]                           # [nb, f]
-    lr = lr_ref[0, 0]
-    iv = idx_ref[...]                         # [mb, 1]
-    msk = mask_ref[...][:, 0]                 # [mb]
-    nb = th.shape[0]
-    # one-hot item selector for this slot: [mb, nb]
-    lanes = jax.lax.broadcasted_iota(jnp.int32, (iv.shape[0], nb), 1)
-    onehot = (lanes == iv).astype(jnp.float32) * msk[:, None]
-    tv = jax.lax.dot_general(                 # gather: [mb, f]
-        onehot, th, dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    e = (val_ref[...][:, 0] - jnp.sum(x * tv, axis=-1)) * msk
-    dx = msk[:, None] * (e[:, None] * tv - lam * x)
+    x = acc_x[...]                            # [f, mb]
+    th = acc_t[...]                           # [f, nb]
+    lr = lr_ref[...]                          # [1, 1]
+    iv = idx_ref[0]                           # [1, mb]
+    msk = mask_ref[0]                         # [1, mb]
+    nb, mb = th.shape[1], x.shape[1]
+    hi = jax.lax.Precision.HIGHEST
+    # one-hot item selector of this slot, items on sublanes: [nb, mb]
+    items = jax.lax.broadcasted_iota(jnp.int32, (nb, mb), 0)
+    onehot = jnp.where(items == iv, msk, 0.0)
+    tv = jax.lax.dot_general(                 # gather: [f, mb]
+        th, onehot, dimension_numbers=(((1,), (0,)), ((), ())),
+        precision=hi, preferred_element_type=jnp.float32)
+    e = (val_ref[0] - jnp.sum(x * tv, axis=0, keepdims=True)) * msk
+    dx = msk * (e * tv - lam * x)
     acc_x[...] = x + lr * dx
     # theta side: mean of the colliding per-sample grads (see ref oracle),
     # both the grad sum and the collision count via one-hot MXU matmuls
-    num = jax.lax.dot_general(                # scatter-sum: [nb, f]
-        onehot, e[:, None] * x,
-        dimension_numbers=(((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    hits = jnp.sum(onehot, axis=0)            # [nb]
-    dt = num / jnp.maximum(hits, 1.0)[:, None] \
-        - lam * th * (hits > 0).astype(jnp.float32)[:, None]
+    num = jax.lax.dot_general(                # scatter-sum: [f, nb]
+        e * x, onehot, dimension_numbers=(((1,), (1,)), ((), ())),
+        precision=hi, preferred_element_type=jnp.float32)
+    hits = jax.lax.dot_general(               # per-item hit count: [1, nb]
+        jnp.ones((8, mb), jnp.float32), onehot,
+        dimension_numbers=(((1,), (1,)), ((), ())),
+        precision=hi, preferred_element_type=jnp.float32)[0:1]
+    dt = num / jnp.maximum(hits, 1.0) \
+        - lam * th * (hits > 0).astype(jnp.float32)
     acc_t[...] = th + lr * dt
 
     @pl.when(k == n_slots - 1)
@@ -89,38 +99,48 @@ def sgd_tile_pallas(
     lam: float,
     interpret: bool = False,
 ) -> tuple[jax.Array, jax.Array]:
-    """Batch-Hogwild tile sweep; see module doc.  Shapes must be pre-padded."""
+    """Batch-Hogwild tile sweep; see module doc.  Shapes must be pre-padded.
+
+    The kernel sees the factors transposed and the ELL slots as
+    ``[K, 1, mb]`` rows, so each slot's block equals the array in its last
+    two dimensions; the transposes here are plain XLA ops.
+    """
     mb, K = idx.shape
     nb, f = theta.shape
     kernel = functools.partial(_sgd_tile_kernel, lam=lam, n_slots=K)
-    return compat.pallas_call(
+    slots = [a.T[:, None, :] for a in (idx, val, mask)]
+    xt, tt = compat.pallas_call(
         kernel,
         grid=(K,),
         in_specs=[
-            pl.BlockSpec((1, 1), lambda k: (0, 0)),       # lr
-            pl.BlockSpec((mb, 1), lambda k: (0, k)),      # idx slot
-            pl.BlockSpec((mb, 1), lambda k: (0, k)),      # val slot
-            pl.BlockSpec((mb, 1), lambda k: (0, k)),      # mask slot
-            pl.BlockSpec((mb, f), lambda k: (0, 0)),      # x0
-            pl.BlockSpec((nb, f), lambda k: (0, 0)),      # theta0
+            pl.BlockSpec((1, 1), lambda k: (0, 0)),         # lr
+            pl.BlockSpec((1, 1, mb), lambda k: (k, 0, 0)),  # idx slot
+            pl.BlockSpec((1, 1, mb), lambda k: (k, 0, 0)),  # val slot
+            pl.BlockSpec((1, 1, mb), lambda k: (k, 0, 0)),  # mask slot
+            pl.BlockSpec((f, mb), lambda k: (0, 0)),        # x0^T
+            pl.BlockSpec((f, nb), lambda k: (0, 0)),        # theta0^T
         ],
         out_specs=(
-            pl.BlockSpec((mb, f), lambda k: (0, 0)),
-            pl.BlockSpec((nb, f), lambda k: (0, 0)),
+            pl.BlockSpec((f, mb), lambda k: (0, 0)),
+            pl.BlockSpec((f, nb), lambda k: (0, 0)),
         ),
         out_shape=(
-            jax.ShapeDtypeStruct((mb, f), jnp.float32),
-            jax.ShapeDtypeStruct((nb, f), jnp.float32),
+            jax.ShapeDtypeStruct((f, mb), jnp.float32),
+            jax.ShapeDtypeStruct((f, nb), jnp.float32),
         ),
         scratch_shapes=[
-            compat.vmem((mb, f), jnp.float32),   # resident x — the tile carry
-            compat.vmem((nb, f), jnp.float32),   # resident theta
+            compat.vmem((f, mb), jnp.float32),   # resident x^T — the tile carry
+            compat.vmem((f, nb), jnp.float32),   # resident theta^T
         ],
         compiler_params=compat.tpu_compiler_params(
             dimension_semantics=("arbitrary",),
+            # the [nb, mb] one-hot and its temporaries outgrow the default
+            # 16 MiB scoped VMEM at mb = nb = 1024 (v5e has 128 MiB)
+            vmem_limit_bytes=SGD_VMEM_LIMIT,
         ),
         interpret=interpret,
-    )(lr, idx, val, mask, x, theta)
+    )(lr, *slots, x.T, theta.T)
+    return xt.T, tt.T
 
 
 @functools.partial(

@@ -293,7 +293,7 @@ def required_capacity_bytes(store, sched: IterationSchedule, f: int,
     x_half = theta_bytes + bufs * x_payload + x_scratch
     # accumulate-Theta half: resident A/B/c shard + per-batch R^T rows of
     # the owned theta shard + the batch's (replicated) X slice
-    q, n, K_loc = store.rt_parts.idx.shape
+    q, n = store.q, store.n
     acc_bytes = n * (f * f + f + 1) * 4 // p
     if binned:
         from repro.outofcore.store import binned_nbytes
@@ -305,6 +305,7 @@ def required_capacity_bytes(store, sched: IterationSchedule, f: int,
         batch_trip = sum(st.rows * (st.K * 8 + 4) for st in stacked)
         t_payload = batch_trip // p + (sched.m_pad // q) * f * 4
     else:
+        K_loc = store.rt_parts.idx.shape[-1]
         t_payload = n * (K_loc * 8 + 4) // p + (sched.m_pad // q) * f * 4
     t_half = acc_bytes + bufs * t_payload + n * f * 4 // p
     return max(x_half, t_half)
@@ -379,7 +380,7 @@ def predicted_stream_stats(store, sched: IterationSchedule, f: int) -> dict:
             x_bytes.append(w.rows * per_row_bytes)
             x_slots.append(w.rows * per_row_slots)
             x_nnz.append(int(cnt_rows[w.row_start:w.row_stop].sum()))
-    q, n, K_t = store.rt_parts.idx.shape
+    n = store.n
     stacked = getattr(store, "rt_stacked", None)
     t_bytes, t_slots, t_nnz = [], [], []
     if binned:
@@ -391,7 +392,7 @@ def predicted_stream_stats(store, sched: IterationSchedule, f: int) -> dict:
                 shard_bytes[b.index] + (b.row_stop - b.row_start) * f * 4
                 for b in w.batches))
             t_slots.append(sum(shard_slots[b.index] for b in w.batches))
-            t_nnz.append(sum(int(store.rt_parts.cnt[b.index].sum())
+            t_nnz.append(sum(store.rt_binned[b.index].nnz
                              for b in w.batches))
     elif stacked is not None:
         # batch-uniform stacks: every batch streams the same per-bin shapes
@@ -406,6 +407,7 @@ def predicted_stream_stats(store, sched: IterationSchedule, f: int) -> dict:
             t_nnz.append(sum(int(store.rt_parts.cnt[b.index].sum())
                              for b in w.batches))
     else:
+        K_t = store.rt_parts.idx.shape[-1]
         batch_trip = n * (K_t * 8 + 4)        # one R^T shard's triplet
         for w in sched.waves:
             t_bytes.append(sum(

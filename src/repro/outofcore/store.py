@@ -7,7 +7,8 @@ the shapes the wave driver streams:
   ``sparse.padded.row_slice`` for the solve-X half.
 - ``RatingStore.rt_parts`` — R^T column-partitioned into the plan's q
   user-batches (``partition_padded``), one ``[n, K_loc]`` shard per batch
-  with batch-local user coordinates, for the accumulate-Theta half.
+  with batch-local user coordinates, for the accumulate-Theta half (absent
+  on a binned p = 1 store, which keeps R^T binned only).
 
 With ``n_bins > 1`` both orientations additionally carry degree-binned
 shards (``r_binned``, one ``BinnedELL`` per R^T user-batch in
@@ -35,9 +36,9 @@ from typing import Tuple
 import numpy as np
 
 from repro.sparse.padded import (BinnedELL, BinShardStack, PaddedELL,
-                                 bin_padded, csr_from_coo, pad_csr_fast,
-                                 pad_rows, partition_padded, row_slice,
-                                 stack_binned_parts)
+                                 bin_padded, bin_rows, csr_from_coo,
+                                 pad_csr_fast, pad_rows, partition_padded,
+                                 row_slice, stack_binned_parts)
 
 Triplet = Tuple[np.ndarray, np.ndarray, np.ndarray]
 
@@ -156,43 +157,44 @@ class RatingStore:
         # user coordinates re-based to the batch (eq. 5-7 partitioning, the
         # q axis instead of p).
         items, users, vals = self.r.transpose_coo()
-        ptr, cc, vv = csr_from_coo(items, users, vals, self.n)
-        rt = pad_csr_fast(ptr, cc, vv, n_cols=self.m_pad,
-                          k_multiple=k_multiple)
-        self.rt_parts = partition_padded(rt, q, k_multiple=k_multiple)
+        assert self.n % p == 0, f"n={self.n} not divisible by p={p}"
         # p > 1 (mesh streaming): R also column-partitioned into the p theta
         # shards (shard-local item coordinates) so solve-X waves can be cut
         # straight into the shard_map layout — the real eq. 5-7 p axis.
-        assert self.n % p == 0, f"n={self.n} not divisible by p={p}"
         self.r_model_parts = (partition_padded(self.r, p,
                                                k_multiple=k_multiple)
                               if p > 1 else None)
         # n_bins > 1: degree-binned shards.  p = 1: both orientations, each
-        # R^T shard re-binned independently (its item degrees are
-        # batch-local).  p > 1 (mesh): the theta half gets batch-uniform
+        # R^T shard binned independently (its item degrees are batch-local)
+        # straight from the shard's CSR — the uniform [q, n, K_loc] stack
+        # (``rt_parts``) pads every item to the top in-batch degree, tens of
+        # GB at Netflix scale under item skew, so a binned p = 1 store never
+        # builds it.  p > 1 (mesh): the theta half gets batch-uniform
         # stacked bins instead — caps chosen globally over all q batches,
         # per-batch membership carried by the stack's ``items`` map — while
         # the solve-X half keeps the uniform mesh layout (r_model_parts).
+        self.rt_parts = None
         self.r_binned = None
         self.rt_binned = None
         self.rt_stacked = None
+        if n_bins > 1 and p == 1:
+            self.r_binned = bin_padded(self.r, n_bins, k_multiple=k_multiple)
+            mq = self.m_pad // q
+            batch = users // mq
+            self.rt_binned = tuple(
+                bin_rows(*csr_from_coo(items[batch == j],
+                                       users[batch == j] - j * mq,
+                                       vals[batch == j], self.n),
+                         n_cols=mq, n_bins=n_bins, k_multiple=k_multiple)
+                for j in range(q))
+            return
+        ptr, cc, vv = csr_from_coo(items, users, vals, self.n)
+        rt = pad_csr_fast(ptr, cc, vv, n_cols=self.m_pad,
+                          k_multiple=k_multiple)
+        self.rt_parts = partition_padded(rt, q, k_multiple=k_multiple)
         if n_bins > 1:
-            if p == 1:
-                self.r_binned = bin_padded(self.r, n_bins,
-                                           k_multiple=k_multiple)
-                self.rt_binned = tuple(
-                    bin_padded(self._rt_shard(j), n_bins,
-                               k_multiple=k_multiple)
-                    for j in range(q))
-            else:
-                self.rt_stacked = stack_binned_parts(
-                    self.rt_parts, n_bins, k_multiple=k_multiple, p=p)
-
-    def _rt_shard(self, j: int) -> PaddedELL:
-        """R^T shard of user-batch ``j`` as a standalone PaddedELL view."""
-        return PaddedELL(idx=self.rt_parts.idx[j], val=self.rt_parts.val[j],
-                         cnt=self.rt_parts.cnt[j],
-                         n_cols=self.m_pad // self.q)
+            self.rt_stacked = stack_binned_parts(
+                self.rt_parts, n_bins, k_multiple=k_multiple, p=p)
 
     @property
     def nnz(self) -> int:
@@ -270,9 +272,10 @@ class RatingStore:
 
     @property
     def host_nbytes(self) -> int:
-        total = int(self.r.idx.nbytes + self.r.val.nbytes + self.r.cnt.nbytes
-                    + self.rt_parts.idx.nbytes + self.rt_parts.val.nbytes
-                    + self.rt_parts.cnt.nbytes)
+        total = int(self.r.idx.nbytes + self.r.val.nbytes + self.r.cnt.nbytes)
+        if self.rt_parts is not None:
+            total += int(self.rt_parts.idx.nbytes + self.rt_parts.val.nbytes
+                         + self.rt_parts.cnt.nbytes)
         if self.r_model_parts is not None:
             total += int(self.r_model_parts.idx.nbytes
                          + self.r_model_parts.val.nbytes
@@ -324,7 +327,11 @@ class RatingStore:
         """R^T shard of user-batch ``j`` (batch-local user indices).
 
         Returns host views into the precomputed shard stack (no per-wave
-        copy — the driver only reads them to stage device transfers)."""
+        copy — the driver only reads them to stage device transfers).
+        A binned p = 1 store has no uniform stack; stream
+        ``theta_batch_binned`` there."""
+        assert self.rt_parts is not None, \
+            "binned p = 1 RatingStore keeps R^T binned only"
         assert 0 <= j < self.q, (j, self.q)
         return (self.rt_parts.idx[j].astype(np.int32, copy=False),
                 self.rt_parts.val[j].astype(np.float32, copy=False),

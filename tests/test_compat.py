@@ -1,8 +1,8 @@
-"""Unit tests for the JAX version-compat layer (repro.compat).
+"""Unit tests for the JAX compat layer (repro.compat).
 
-Every shim is exercised on BOTH branches: the one the installed JAX
-actually takes, and the other one simulated by monkeypatching the
-module-level attribute the shim resolves at call time.
+Each surface runs on the installed JAX, and its argument translation is
+checked against a fake installed by monkeypatching the module-level
+attribute the shim resolves at call time.
 """
 import enum
 import types
@@ -46,33 +46,10 @@ def test_make_mesh_passes_axis_types_when_supported(monkeypatch):
                              (FakeAxisType.Auto, FakeAxisType.Auto))
 
 
-def test_make_mesh_drops_axis_types_when_absent(monkeypatch):
-    """Simulate old JAX: no AxisType, make_mesh without the kwarg."""
-    calls = {}
-
-    def fake_make_mesh(axis_shapes, axis_names, *, devices=None):
-        calls["args"] = (axis_shapes, axis_names, devices)
-        return "mesh"
-
-    monkeypatch.setattr(compat, "AxisType", None)
-    monkeypatch.setattr(compat, "_JAX_MAKE_MESH", fake_make_mesh)
-    out = compat.make_mesh((8,), ("data",), axis_types="auto")
-    assert out == "mesh"
-    assert calls["args"] == ((8,), ("data",), None)
-
-
 def test_make_mesh_rejects_bogus_axis_types():
     """Validation must not depend on which JAX branch is installed."""
     with pytest.raises(ValueError, match="axis_types"):
         compat.make_mesh((1,), ("data",), axis_types="bogus")
-
-
-def test_make_mesh_mesh_utils_fallback(monkeypatch):
-    """Pre-jax.make_mesh branch: plain Mesh over a device grid."""
-    monkeypatch.setattr(compat, "_JAX_MAKE_MESH", None)
-    mesh = compat.make_mesh((1,), ("data",))
-    assert isinstance(mesh, jax.sharding.Mesh)
-    assert mesh.axis_names == ("data",)
 
 
 # ---------------------------------------------------------------------------
@@ -112,27 +89,6 @@ def test_shard_map_new_api_translation(monkeypatch):
     assert calls["kw"]["check_vma"] is True
 
 
-def test_shard_map_legacy_translation(monkeypatch):
-    """axis_names (manual) inverts to auto=, check_vma maps to check_rep=."""
-    calls = {}
-
-    def fake_legacy(f, *, mesh, in_specs, out_specs, check_rep, auto):
-        calls["kw"] = dict(check_rep=check_rep, auto=auto)
-        return f
-
-    mesh = types.SimpleNamespace(axis_names=("pod", "data", "model"))
-    monkeypatch.setattr(compat, "_NEW_SHARD_MAP", None)
-    monkeypatch.setattr(compat, "_LEGACY_SHARD_MAP", fake_legacy)
-    compat.shard_map(lambda v: v, mesh=mesh, in_specs=0, out_specs=0,
-                     axis_names={"model"}, check_vma=False)
-    assert calls["kw"] == {"check_rep": False,
-                           "auto": frozenset({"pod", "data"})}
-    # fully-manual default: auto is empty
-    compat.shard_map(lambda v: v, mesh=mesh, in_specs=0, out_specs=0,
-                     check_vma=False)
-    assert calls["kw"]["auto"] == frozenset()
-
-
 # ---------------------------------------------------------------------------
 # pallas compiler params / pallas_call
 # ---------------------------------------------------------------------------
@@ -140,11 +96,7 @@ def test_shard_map_legacy_translation(monkeypatch):
 def test_tpu_compiler_params_resolves_installed_name():
     params = compat.tpu_compiler_params(
         dimension_semantics=("parallel", "arbitrary"))
-    if compat.has_pallas_tpu():
-        assert params is not None
-        assert tuple(params.dimension_semantics) == ("parallel", "arbitrary")
-    else:
-        assert params is None
+    assert tuple(params.dimension_semantics) == ("parallel", "arbitrary")
 
 
 def test_tpu_compiler_params_new_name(monkeypatch):
@@ -154,26 +106,13 @@ def test_tpu_compiler_params_new_name(monkeypatch):
 
     fake = types.SimpleNamespace(CompilerParams=FakeParams)
     monkeypatch.setattr(compat, "_pltpu", fake)
-    p = compat.tpu_compiler_params(dimension_semantics=("parallel",),
-                                   bogus_future_kwarg=1)
+    p = compat.tpu_compiler_params(dimension_semantics=("parallel",))
     assert isinstance(p, FakeParams)
     assert p.dimension_semantics == ("parallel",)
-
-
-def test_tpu_compiler_params_old_name(monkeypatch):
-    class FakeTPUParams:
-        def __init__(self, dimension_semantics=None):
-            self.dimension_semantics = dimension_semantics
-
-    fake = types.SimpleNamespace(TPUCompilerParams=FakeTPUParams)
-    monkeypatch.setattr(compat, "_pltpu", fake)
-    p = compat.tpu_compiler_params(dimension_semantics=("arbitrary",))
-    assert isinstance(p, FakeTPUParams)
-
-
-def test_tpu_compiler_params_no_backend(monkeypatch):
-    monkeypatch.setattr(compat, "_pltpu", None)
-    assert compat.tpu_compiler_params(dimension_semantics=()) is None
+    # a misspelt parameter is an error, never silently dropped
+    with pytest.raises(TypeError):
+        compat.tpu_compiler_params(dimension_semantics=("parallel",),
+                                   bogus_kwarg=1)
 
 
 def test_pallas_call_degrades_to_interpret_off_tpu(monkeypatch):
@@ -205,24 +144,21 @@ def test_pallas_call_compiles_on_tpu(monkeypatch):
     assert calls["interpret"] is True
 
 
-def test_vmem_degrades_without_pltpu(monkeypatch):
-    """No TPU pallas backend -> a generic interpret-capable scratch ref."""
-    monkeypatch.setattr(compat, "_pltpu", None)
-    from jax.experimental import pallas as pl
+def test_pallas_call_refuses_other_backends(monkeypatch):
+    """Off CPU and TPU nothing falls back to the interpreter by itself."""
+    calls = {}
 
-    ref = compat.vmem((8,), jnp.float32)
+    def fake_pallas_call(kernel, *, interpret, **kwargs):
+        calls["interpret"] = interpret
+        return kernel
 
-    def k(x_ref, o_ref, s):
-        s[...] = x_ref[...]
-        o_ref[...] = s[...] * 2
-
-    out = pl.pallas_call(
-        k, out_shape=jax.ShapeDtypeStruct((8,), jnp.float32),
-        scratch_shapes=[ref], interpret=True)(jnp.arange(8.0))
-    np.testing.assert_allclose(out, np.arange(8.0) * 2)
-
-
-def test_jax_version_tuple():
-    v = compat.jax_version()
-    assert len(v) == 3 and all(isinstance(p, int) for p in v)
-    assert v >= (0, 4, 37), "supported JAX floor is 0.4.37"
+    monkeypatch.setattr(compat, "_PALLAS_CALL", fake_pallas_call)
+    monkeypatch.setattr(compat, "_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="gpu"):
+        compat.interpret_default()
+    with pytest.raises(RuntimeError, match="gpu"):
+        compat.pallas_call(lambda: None, out_shape=None)
+    assert "interpret" not in calls
+    # asking for the interpreter explicitly is still allowed
+    compat.pallas_call(lambda: None, out_shape=None, interpret=True)
+    assert calls["interpret"] is True

@@ -19,6 +19,7 @@ from repro.outofcore import (RatingStore, SimulatedFailure, TileStore,
                              run_streaming_sgd)
 from repro.sgd import SgdConfig, block_ell, sgd_train
 from repro.sparse import synth
+from repro.sparse.padded import PaddedELL, bin_padded
 
 SPEC = synth.SynthSpec("oc", 96, 40, 1500, 8, 0.05)
 
@@ -424,6 +425,30 @@ def test_rating_store_binned_invariants():
     assert store_u.r_binned is None
     with pytest.raises(AssertionError):
         store_u.x_slice_binned(0, npp)
+
+
+def test_binned_store_bins_rt_from_csr_like_the_uniform_shards():
+    """A binned p = 1 store bins each batch's R^T straight from CSR and
+    keeps no uniform R^T stack; its bins equal re-binning the uniform
+    store's shards, slot for slot."""
+    r, _, _, _ = _problem()
+    store_u = RatingStore(r, q=4)
+    store_b = RatingStore(r, q=4, n_bins=4)
+    assert store_b.rt_parts is None
+    with pytest.raises(AssertionError):
+        store_b.theta_batch_triplet(0)
+    for j, got in enumerate(store_b.rt_binned):
+        want = bin_padded(
+            PaddedELL(idx=store_u.rt_parts.idx[j], val=store_u.rt_parts.val[j],
+                      cnt=store_u.rt_parts.cnt[j],
+                      n_cols=store_u.m_pad // 4), 4)
+        assert got.m == want.m and got.n_cols == want.n_cols
+        for gb, wb, gr, wr in zip(got.bins, want.bins, got.rows, want.rows):
+            np.testing.assert_array_equal(gr, wr)
+            np.testing.assert_array_equal(gb.idx, wb.idx)
+            np.testing.assert_array_equal(gb.val, wb.val)
+            np.testing.assert_array_equal(gb.cnt, wb.cnt)
+        assert len(got.bins) == len(want.bins)
 
 
 def test_binned_store_with_model_shards_builds_stacks():
