@@ -78,6 +78,7 @@ import argparse
 import os
 import time
 
+import jax
 import jax.numpy as jnp
 
 from repro.checkpoint import CheckpointManager
@@ -91,8 +92,6 @@ def _build_mesh(args):
     """--mesh DATA,MODEL -> (Mesh, p); (None, 1) when not requested."""
     if not getattr(args, "mesh", None):
         return None, 1
-    import jax
-
     from repro.launch.mesh import make_mesh
 
     d, p = (int(x) for x in args.mesh.split(","))
@@ -393,7 +392,8 @@ def main():
     tracer = None
     if args.trace:
         from repro.obs import Tracer, set_tracer
-        tracer = Tracer()
+        # spans also land in a jax.profiler trace as host annotations
+        tracer = Tracer(annotate=jax.profiler.TraceAnnotation)
         set_tracer(tracer)      # the drivers pick it up via current_tracer
 
     if args.full:

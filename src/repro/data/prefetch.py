@@ -14,14 +14,17 @@ blocked forever on a full queue.  ``close()`` wakes a blocked worker, drains
 the queue, and joins the thread; it is idempotent and safe after normal
 exhaustion.
 
-Observability (``tracer=`` / ``registry=``): the worker thread records one
-``prefetch_load`` span per item around the ``put`` transform (the actual
-load + device_put work, on its own named thread track), and the consumer
+Observability (``tracer=`` / ``registry=``): the worker thread records two
+spans per item on its own named thread track: one ``prefetch_slice`` span
+around the ``next()`` of the source iterator (the host slicing and layout
+work of the caller's generator) and one ``prefetch_load`` span around the
+``put`` transform (the counters and host->device uploads); the consumer
 records one ``prefetch`` span per ``__next__`` around the queue wait — the
 time compute actually stalled on streaming.  A well-hidden pipeline shows
-long ``prefetch_load`` spans and near-zero ``prefetch`` spans; the inverse
-means the budget or depth is wrong.  The registry additionally counts
-``prefetch/items`` and samples queue depth at each hand-off.
+long ``prefetch_slice``/``prefetch_load`` spans and near-zero ``prefetch``
+spans; the inverse means the budget or depth is wrong.  The registry
+additionally counts ``prefetch/items`` and samples queue depth at each
+hand-off.
 """
 from __future__ import annotations
 
@@ -63,7 +66,14 @@ class Prefetcher:
 
     def _worker(self):
         try:
-            for item in self._it:
+            it = iter(self._it)
+            while True:
+                try:
+                    with phase("prefetch.slice", cat="prefetch_slice",
+                               tracer=self._tracer, registry=self._registry):
+                        item = next(it)           # the generator's host work
+                except StopIteration:
+                    break
                 if self._stop.is_set():
                     return
                 with phase("prefetch.load", cat="prefetch_load",

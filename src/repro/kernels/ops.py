@@ -5,6 +5,10 @@ calls.  They handle:
 
 - the theta gather (XLA DMA-gather == the paper's texture-cached read),
 - padding m / K / F up to tile multiples (F to the MXU lane width),
+- ``jax.named_scope`` names for the ALS stages (``als.herm`` with its
+  ``als.gather`` inside, ``als.solve``): HLO metadata only, so device
+  profiles can split device time by stage; dispatch and numerics are the
+  same without them,
 - kernel-vs-oracle dispatch by ``mode``: ``"ref"`` (the default) is the
   jnp oracle in ``kernels.ref``; ``"kernel"`` runs the Pallas kernels,
   compiled by Mosaic on TPU and run by the Pallas interpreter on the CPU
@@ -69,29 +73,31 @@ def fused_herm(
     matrices are psum-reduced first and the guard is applied post-reduction
     (a locally-empty row may be nonempty globally).
     """
-    m, K = idx.shape
-    f = theta.shape[1]
-    mask = kref.mask_from_cnt(cnt, K, theta.dtype)
-    diag = lam * cnt.astype(jnp.float32)
-    if diag_fallback:
-        diag = jnp.where(cnt > 0, diag, 1.0)
-    g = jnp.take(theta, idx, axis=0)          # [m, K, f] texture-gather analogue
+    with jax.named_scope("als.herm"):
+        m, K = idx.shape
+        f = theta.shape[1]
+        mask = kref.mask_from_cnt(cnt, K, theta.dtype)
+        diag = lam * cnt.astype(jnp.float32)
+        if diag_fallback:
+            diag = jnp.where(cnt > 0, diag, 1.0)
+        with jax.named_scope("als.gather"):   # texture-gather analogue
+            g = jnp.take(theta, idx, axis=0)  # [m, K, f]
 
-    if mode == "ref":
-        A, B = kref.herm_ref(g, val, mask, diag)
-        return A, B
+        if mode == "ref":
+            A, B = kref.herm_ref(g, val, mask, diag)
+            return A, B
 
-    F = _round_up(f, f_mult)
-    Kp = _round_up(K, tk)
-    mp = _round_up(m, tm)
-    g = _pad_axis(_pad_axis(_pad_axis(g, 2, F), 1, Kp), 0, mp)
-    val_p = _pad_axis(_pad_axis(val, 1, Kp), 0, mp)
-    mask_p = _pad_axis(_pad_axis(mask, 1, Kp), 0, mp)
-    diag_p = _pad_axis(diag, 0, mp)
-    A, B = fused_herm_pallas(
-        g, val_p, mask_p, diag_p, tm=tm, tk=tk,
-        interpret=(mode == "kernel_interpret"))
-    return A[:m, :f, :f], B[:m, :f]
+        F = _round_up(f, f_mult)
+        Kp = _round_up(K, tk)
+        mp = _round_up(m, tm)
+        g = _pad_axis(_pad_axis(_pad_axis(g, 2, F), 1, Kp), 0, mp)
+        val_p = _pad_axis(_pad_axis(val, 1, Kp), 0, mp)
+        mask_p = _pad_axis(_pad_axis(mask, 1, Kp), 0, mp)
+        diag_p = _pad_axis(diag, 0, mp)
+        A, B = fused_herm_pallas(
+            g, val_p, mask_p, diag_p, tm=tm, tk=tk,
+            interpret=(mode == "kernel_interpret"))
+        return A[:m, :f, :f], B[:m, :f]
 
 
 @functools.partial(jax.jit, static_argnames=("mode", "tb"))
@@ -103,20 +109,21 @@ def batch_solve(
     tb: int = 8,
 ) -> jax.Array:
     """x_u = A_u^{-1} B_u (batched Cholesky solve)."""
-    if mode == "ref":
-        return kref.batch_solve_ref(A, B)
-    m, f, _ = A.shape
-    mp = _round_up(m, tb)
-    eye_pad = jnp.eye(f, dtype=A.dtype)[None]
-    A_p = _pad_axis(A, 0, mp)
-    # padded batch entries get I so the factorization stays nonsingular
-    if mp != m:
-        padmask = (jnp.arange(mp) < m).astype(A.dtype)[:, None, None]
-        A_p = A_p * padmask + (1.0 - padmask) * eye_pad
-    B_p = _pad_axis(B, 0, mp)
-    x = batch_solve_pallas(A_p, B_p, tb=tb,
-                           interpret=(mode == "kernel_interpret"))
-    return x[:m]
+    with jax.named_scope("als.solve"):
+        if mode == "ref":
+            return kref.batch_solve_ref(A, B)
+        m, f, _ = A.shape
+        mp = _round_up(m, tb)
+        eye_pad = jnp.eye(f, dtype=A.dtype)[None]
+        A_p = _pad_axis(A, 0, mp)
+        # padded batch entries get I so the factorization stays nonsingular
+        if mp != m:
+            padmask = (jnp.arange(mp) < m).astype(A.dtype)[:, None, None]
+            A_p = A_p * padmask + (1.0 - padmask) * eye_pad
+        B_p = _pad_axis(B, 0, mp)
+        x = batch_solve_pallas(A_p, B_p, tb=tb,
+                               interpret=(mode == "kernel_interpret"))
+        return x[:m]
 
 
 def als_update_factor(

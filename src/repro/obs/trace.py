@@ -2,8 +2,9 @@
 
 The streaming stack's cost story is the paper's cost story — eq. 5-8 price
 *bytes*, and the overlap argument (§4.4: "hide load time behind compute")
-is a claim about *time*.  ``MemoryMeter`` already audits the bytes; this
-module audits the time: every hot phase (prefetch wait, wave solve, staged
+is a claim about *time*.  ``MemoryMeter`` simulates the bytes (a model of
+what each wave holds, not a reading of the device); this module measures
+the time: every hot phase (host slicing, prefetch wait, wave solve, staged
 reduction, checkpoint commit) runs inside a span, and the spans export to
 Chrome-trace JSON (``obs.export``) so a run opens directly in Perfetto.
 
@@ -26,6 +27,12 @@ prefetch worker's load spans interleave correctly with the consumer's
 solve spans in the exported timeline (two tracks, overlapping — the
 overlap IS the paper's preload win, made visible).
 
+A :class:`Tracer` built with ``annotate=`` (a context-manager factory such
+as ``jax.profiler.TraceAnnotation``, passed in by the caller so this module
+stays stdlib-only) also enters ``annotate(name)`` around every span and
+phase on the thread that runs it, so the spans land in a device profile on
+the profiler's own clock, next to the device operations.
+
 Category vocabulary (the span/metric catalog in OBSERVABILITY.md):
 
 ==================  =====================================================
@@ -37,15 +44,19 @@ category            what runs under it
 ``solve``           one wave's compute+writeback — exactly one span per
                     wave consumed, so ``count(cat="solve") == waves_run``
 ``prefetch``        consumer-side queue wait (pipeline stall time)
+``prefetch_slice``  worker-side host slicing: one ``next()`` of the
+                    wave generator (overlapped time)
 ``prefetch_load``   worker-side host->device load (overlapped time)
 ``reduce``          topology-aware reduction + post-reduce shard solves
 ``checkpoint``      one per-wave checkpoint commit (snapshot + enqueue)
 ``serve``           serving-engine prefill / decode steps
+``compile``         one JAX trace, lowering or backend compile
+                    (``jax.trace:``/``jax.lower:``/``jax.compile:``
+                    ``<function>``), recorded by :func:`compile_listener`
 ==================  =====================================================
 """
 from __future__ import annotations
 
-import functools
 import os
 import threading
 import time
@@ -115,7 +126,7 @@ NULL_TRACER = NullTracer()
 
 
 class _Span:
-    __slots__ = ("_tracer", "_name", "_cat", "_args", "_t0")
+    __slots__ = ("_tracer", "_name", "_cat", "_args", "_t0", "_ann")
 
     def __init__(self, tracer, name, cat, args):
         self._tracer = tracer
@@ -124,13 +135,26 @@ class _Span:
         self._args = args
 
     def __enter__(self):
+        self._ann = _enter_annotation(self._tracer, self._name)
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
-        self._tracer.record(self._name, self._cat, self._t0,
-                            time.perf_counter(), self._args)
+        t1 = time.perf_counter()
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        self._tracer.record(self._name, self._cat, self._t0, t1, self._args)
         return False
+
+
+def _enter_annotation(tracer, name: str):
+    """The tracer's ``annotate(name)``, entered; None without the hook."""
+    make = tracer.annotate if tracer is not None and tracer.enabled else None
+    if make is None:
+        return None
+    ann = make(name)
+    ann.__enter__()
+    return ann
 
 
 class Tracer:
@@ -140,11 +164,17 @@ class Tracer:
     recorded at *exit* (one append per span), so the lock is never held
     across user code.  Timestamps are ``time.perf_counter()`` relative to
     the tracer's construction (``epoch``), exported as microseconds.
+
+    ``annotate``, when given, is entered as ``annotate(name)`` around each
+    :meth:`span` and :class:`phase` (not around pre-timed :meth:`record`
+    calls): pass ``jax.profiler.TraceAnnotation`` to put every span into
+    a device profile as a host annotation of the same name.
     """
 
     enabled = True
 
-    def __init__(self):
+    def __init__(self, annotate=None):
+        self.annotate = annotate
         self.epoch = time.perf_counter()
         self.events: list[SpanEvent] = []
         self.thread_names: dict[int, str] = {}
@@ -205,20 +235,6 @@ def current_tracer() -> NullTracer | Tracer:
     return _CURRENT
 
 
-def traced(name: Optional[str] = None, cat: str = ""):
-    """Decorator form: run the wrapped function inside a span on the
-    *current* tracer (resolved per call, so enabling tracing later works)."""
-    def deco(fn):
-        label = name or fn.__qualname__
-
-        @functools.wraps(fn)
-        def wrapper(*a, **kw):
-            with current_tracer().span(label, cat=cat):
-                return fn(*a, **kw)
-        return wrapper
-    return deco
-
-
 class phase:
     """Span + always-on metrics in one context manager.
 
@@ -228,9 +244,13 @@ class phase:
     recording.  Either sink may be None.  This is the only sanctioned way
     to time code under ``src/repro/`` outside ``obs/`` (reprolint rule
     ``obs-routing`` enforces it).
+
+    A body that ends in ``StopIteration`` records nothing: timing a
+    ``next()`` that finds its iterator exhausted is no phase of work.
     """
 
-    __slots__ = ("_tracer", "_registry", "_name", "_cat", "_args", "_t0")
+    __slots__ = ("_tracer", "_registry", "_name", "_cat", "_args", "_t0",
+                 "_ann")
 
     def __init__(self, name: str, *, cat: str, tracer=None, registry=None,
                  **args):
@@ -241,11 +261,16 @@ class phase:
         self._args = args
 
     def __enter__(self):
+        self._ann = _enter_annotation(self._tracer, self._name)
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         t1 = time.perf_counter()
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        if exc[0] is StopIteration:
+            return False
         reg = self._registry
         if reg is not None:
             reg.add_phase(self._cat, t1 - self._t0)
@@ -253,6 +278,48 @@ class phase:
         if tr is not None and tr.enabled:
             tr.record(self._name, self._cat, self._t0, t1, self._args)
         return False
+
+
+#: JAX's ``jax.monitoring`` duration events (``jax/_src/dispatch.py``,
+#: JAX 0.9.0) and the span name prefix of each: one jaxpr trace, then the
+#: lowering and the backend compile (or persistent-cache load) after it
+JAX_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+JAX_COMPILE_EVENTS = {
+    JAX_TRACE_EVENT: "jax.trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jax.lower",
+    "/jax/core/compile/backend_compile_duration": "jax.compile",
+}
+
+
+def compile_listener(registry, tracer=None):
+    """A ``jax.monitoring`` duration listener that books JAX's traces and
+    compiles into ``registry`` (register it with
+    ``jax.monitoring.register_event_duration_secs_listener``).
+
+    Each trace event adds 1 to ``jax/traces`` and ``jax/traces/<fun_name>``
+    and its seconds to ``jax/trace_seconds``; the lowering and backend
+    compile (or persistent-cache load) events add theirs to
+    ``jax/compile_seconds``.  With a recording ``tracer`` each event is
+    also a ``compile`` span ``jax.trace:<fun_name>``, ``jax.lower:<fun_name>``
+    or ``jax.compile:<fun_name>`` that ends at the callback and lasts the
+    event's duration, on the thread that compiled.  Nothing runs when
+    nothing compiles.
+    """
+    def on_duration(event: str, duration: float, **kwargs) -> None:
+        kind = JAX_COMPILE_EVENTS.get(event)
+        if kind is None:
+            return
+        fun = kwargs.get("fun_name", "?")
+        if event == JAX_TRACE_EVENT:
+            registry.counter("jax/traces").inc()
+            registry.counter(f"jax/traces/{fun}").inc()
+            registry.counter("jax/trace_seconds").inc(duration)
+        else:
+            registry.counter("jax/compile_seconds").inc(duration)
+        if tracer is not None and tracer.enabled:
+            t1 = time.perf_counter()
+            tracer.record(f"{kind}:{fun}", "compile", t1 - duration, t1)
+    return on_duration
 
 
 def process_id() -> int:

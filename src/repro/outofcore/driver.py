@@ -43,6 +43,7 @@ instead of one model-shard's simulated view:
 """
 from __future__ import annotations
 
+import contextlib
 from typing import Callable, List, Optional
 
 import jax
@@ -56,7 +57,7 @@ from repro.data.prefetch import Prefetcher
 from repro.kernels.budgets import BUDGETS, footprint_bytes
 from repro.obs.ledger import Ledger
 from repro.obs.metrics import MetricsRegistry
-from repro.obs.trace import current_tracer, phase
+from repro.obs.trace import compile_listener, current_tracer, phase
 from repro.outofcore.runtime import (MemoryMeter, SimulatedFailure,
                                      StreamTelemetry, WaveCheckpointer)
 from repro.outofcore.schedule import (IterationSchedule,
@@ -111,6 +112,19 @@ def _mesh_axes(mesh) -> tuple[int, int, object]:
     return mesh.shape["data"], p, col_dim
 
 
+@contextlib.contextmanager
+def _counting_compiles(registry, tracer):
+    """JAX's traces and compiles booked into ``registry`` (and ``tracer``)
+    while the block runs: ``obs.compile_listener``, registered with
+    ``jax.monitoring`` for the block only."""
+    listener = compile_listener(registry, tracer)
+    jax.monitoring.register_event_duration_secs_listener(listener)
+    try:
+        yield
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listener)
+
+
 def run_streaming_als(
     ratings: RatingStore,
     sched: IterationSchedule,
@@ -140,12 +154,16 @@ def run_streaming_als(
 
     Observability: every hot phase runs inside an ``obs`` span — the whole
     run (``driver``), each iteration/half, one ``solve`` span per wave,
-    ``reduce`` for the mesh epilogue, ``checkpoint`` per commit — and all
-    counting/timing goes through ``registry`` (an ``obs.MetricsRegistry``;
-    one is created when not passed).  ``tracer`` defaults to the
-    process-wide tracer (``obs.set_tracer`` / ``--trace``); with the
-    default ``NULL_TRACER`` the spans are no-ops and only the cheap
-    per-wave metrics remain.  The returned telemetry is
+    ``reduce`` for the mesh epilogue, ``checkpoint`` per commit, and on the
+    prefetch worker ``prefetch_slice`` / ``prefetch_load`` per wave — and
+    all counting/timing goes through ``registry`` (an
+    ``obs.MetricsRegistry``; one is created when not passed), which also
+    counts JAX's traces and compiles during the run (``jax/traces``,
+    ``jax/traces/<function>``, ``jax/trace_seconds``,
+    ``jax/compile_seconds``; ``obs.compile_listener``).  ``tracer``
+    defaults to the process-wide tracer (``obs.set_tracer`` /
+    ``--trace``); with the default ``NULL_TRACER`` the spans are no-ops
+    and only the cheap per-wave metrics remain.  The returned telemetry is
     ``StreamTelemetry.from_registry`` — same fields as ever, plus the
     ``phase_seconds`` breakdown, which each history record also carries as
     its per-iteration delta.
@@ -759,8 +777,9 @@ def run_streaming_als(
     # ------------------------------------------------------------------
     history: List[dict] = []
     it0 = start_step // wpi
-    with phase("als.stream", cat="driver", tracer=tracer, registry=reg,
-               iterations=cfg.iters, waves=W, topology=topo_desc):
+    with _counting_compiles(reg, tracer), \
+            phase("als.stream", cat="driver", tracer=tracer, registry=reg,
+                  iterations=cfg.iters, waves=W, topology=topo_desc):
         for it in range(it0, cfg.iters):
             resume_here = it == it0
             r = start_step % wpi if resume_here else 0
