@@ -13,7 +13,8 @@ call-site hunt.
   ``pltpu.VMEM``.
 - :func:`pallas_call`: ``pl.pallas_call`` that compiles on TPU, runs the
   Pallas interpreter on the CPU backend (where the tests run), and refuses
-  every other backend rather than silently interpreting there.
+  every other backend rather than silently interpreting there;
+  :func:`on_tpu` for code that picks a kernel by backend.
 
 Resolution goes through module-level attributes looked up at call time, so
 tests can monkeypatch them.
@@ -81,6 +82,11 @@ def vmem(shape, dtype):
 
 def _backend() -> str:
     return jax.default_backend()
+
+
+def on_tpu() -> bool:
+    """Whether JAX's default backend is a TPU."""
+    return _backend() == "tpu"
 
 
 def interpret_default() -> bool:

@@ -26,10 +26,9 @@ class AlsConfig:
     lam: float                # weighted-lambda regularization strength
     iters: int = 10           # full ALS iterations (each = update-X + update-Theta)
     batch_rows: int = 0       # q-batch size; 0 = solve all rows at once
-    mode: str = "ref"         # kernel dispatch: ref | kernel | kernel_interpret
+    mode: str = "ref"         # Hermitian: ref | kernel | kernel_interpret
     tm: int = 8
     tk: int = 128
-    tb: int = 8
     f_mult: int = 128
     seed: int = 0
     init_scale: float = 0.3   # paper initializes factors U[0, 1]; we scale down
@@ -74,7 +73,7 @@ def _update_factor(theta, idx, val, cnt, cfg: AlsConfig) -> jax.Array:
     """Solve every row of one factor given the other side fixed."""
     solve = functools.partial(
         kops.als_update_factor, lam=cfg.lam, mode=cfg.mode,
-        tm=cfg.tm, tk=cfg.tk, tb=cfg.tb, f_mult=cfg.f_mult)
+        tm=cfg.tm, tk=cfg.tk, f_mult=cfg.f_mult)
     m = idx.shape[0]
     if cfg.batch_rows and cfg.batch_rows < m:
         return _map_row_blocks(
@@ -122,13 +121,23 @@ def solve_accumulated(A, B, cnt_total, cfg: AlsConfig) -> jax.Array:
     f = A.shape[-1]
     empty = (cnt_total <= 0).astype(A.dtype)
     A = A + empty[:, None, None] * jnp.eye(f, dtype=A.dtype)[None, :, :]
-    solve = functools.partial(kops.batch_solve, mode=cfg.mode, tb=cfg.tb)
+    solve = functools.partial(kops.batch_solve, mode=cfg.mode)
     m = A.shape[0]
     if cfg.batch_rows and cfg.batch_rows < m:
         return _map_row_blocks(
             lambda ab: solve(ab[0], ab[1]), (A, B), cfg.batch_rows,
             pad_vals=(jnp.eye(f, dtype=A.dtype), None))
     return solve(A, B)
+
+
+def solved_systems(m: int, cfg: AlsConfig) -> int:
+    """Systems a solve of ``m`` rows factors: whole row blocks of
+    ``cfg.batch_rows`` when it maps them (padded with identity systems),
+    each widened to the solve's own step (``kops.solve_width``)."""
+    if cfg.batch_rows and cfg.batch_rows < m:
+        return (-(-m // cfg.batch_rows)
+                * kops.solve_width(cfg.batch_rows, cfg.f, cfg.mode))
+    return kops.solve_width(m, cfg.f, cfg.mode)
 
 
 def als_iteration(state: AlsState, r, rt, cfg: AlsConfig) -> AlsState:

@@ -50,7 +50,7 @@ def su_als_update(
     col_axes: tuple[str, ...] = ("model",),   # cuMF p axes, fast -> slow
     scheme: str = "two_phase",                # "one_phase" | "two_phase"
     mode: str = "ref",
-    tm: int = 8, tk: int = 128, tb: int = 8, f_mult: int = 128,
+    tm: int = 8, tk: int = 128, f_mult: int = 128,
     row_block: int = 0,
 ) -> jax.Array:
     """Runs inside shard_map.  Returns x_loc [m_loc, f] (replicated over col_axes).
@@ -68,7 +68,7 @@ def su_als_update(
             i, v, c = args
             return su_als_update(
                 theta_loc, i, v, c, lam, col_axes=col_axes, scheme=scheme,
-                mode=mode, tm=tm, tk=tk, tb=tb, f_mult=f_mult, row_block=0)
+                mode=mode, tm=tm, tk=tk, f_mult=f_mult, row_block=0)
 
         out = lax.map(one, (blk(idx_loc), blk(val_loc), blk(cnt_loc)))
         return out.reshape(m_loc, -1)
@@ -102,7 +102,7 @@ def su_als_update(
     A_r = A_r + empty[:, None, None] * jnp.eye(f, dtype=A_r.dtype)
 
     # (3) solve owned slice — Alg. 3 line 17, p-way parallel batch_solve
-    x_slice = kops.batch_solve(A_r, B_r, mode=mode, tb=tb)
+    x_slice = kops.batch_solve(A_r, B_r, mode=mode)
 
     # (4) collect solved slices — Alg. 3 line 19
     if scheme == "one_phase" or len(col_axes) == 1:
@@ -120,7 +120,7 @@ def make_su_als_fns(
     *,
     scheme: str = "two_phase",
     mode: str = "ref",
-    tm: int = 8, tk: int = 128, tb: int = 8, f_mult: int = 128,
+    tm: int = 8, tk: int = 128, f_mult: int = 128,
     row_block: int = 0,
 ):
     """Build (update_x, update_theta, iteration) jitted on ``mesh``.
@@ -137,7 +137,7 @@ def make_su_als_fns(
     # fast axis first (intra-pod "model"), then slow ("pod")
     update = functools.partial(
         su_als_update, lam=lam, col_axes=col_axes, scheme=scheme,
-        mode=mode, tm=tm, tk=tk, tb=tb, f_mult=f_mult, row_block=row_block)
+        mode=mode, tm=tm, tk=tk, f_mult=f_mult, row_block=row_block)
 
     cols_spec = col_axes if len(col_axes) == 1 else (col_axes[::-1],)
     # column-block dim of R shards over (pod, model): pod-major ordering
@@ -192,7 +192,7 @@ def make_wave_update_fn(
     *,
     scheme: str = "two_phase",
     mode: str = "ref",
-    tm: int = 8, tk: int = 128, tb: int = 8, f_mult: int = 128,
+    tm: int = 8, tk: int = 128, f_mult: int = 128,
     row_block: int = 0,
 ):
     """Per-slice update entry point for the out-of-core wave driver.
@@ -207,7 +207,7 @@ def make_wave_update_fn(
     """
     update_x, _, _ = make_su_als_fns(
         mesh, lam, scheme=scheme, mode=mode,
-        tm=tm, tk=tk, tb=tb, f_mult=f_mult, row_block=row_block)
+        tm=tm, tk=tk, f_mult=f_mult, row_block=row_block)
     col_axes, col_dim = _col_axes(mesh)
     rows_sh = NamedSharding(mesh, P("data", col_dim))
     fixed_sh = NamedSharding(mesh, P(col_dim, None))

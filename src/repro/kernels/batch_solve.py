@@ -1,20 +1,23 @@
 """Pallas TPU kernel: batched SPD Cholesky solve (cuMF's batch_solve phase).
 
-cuMF delegates ``A_u x_u = B_u`` to cuBLAS batched routines.  On TPU we give
-the phase its own in-VMEM kernel: each grid step loads a batch of TB
-(F x F) Hermitian systems, runs an unblocked right-looking Cholesky, then a
-forward and a backward triangular solve, all without leaving VMEM (an F=128
-fp32 tile is 64 KB — 0.4% of VMEM).
+cuMF delegates ``A_u x_u = B_u`` to cuBLAS batched routines.  ALS solves
+tens of thousands of small (f x f) systems at once, so on the TPU the
+systems go on the lanes: the kernel sees ``A`` as ``[f, f, m]`` (column,
+row, system) and ``B`` as ``[f, m]`` (row, system).  A grid step holds
+``T`` systems (a multiple of 128) in VMEM; the row index sits on sublanes
+and the column index on the leading axis of a ``[f, f, T]`` scratch, where
+an unblocked right-looking Cholesky runs in place.  Step j reads column j
+(a leading-axis index) and its diagonal (one sublane), scales the column
+by the square root of the diagonal, and subtracts its outer product from
+the trailing columns, one ``[f, T]`` multiply-subtract per column.  Forward
+and back substitution follow in the same layout.  Every pick is a
+leading-axis index or a single-sublane read, so each operation is
+full-width f32 work on the vector unit, with no lane reductions and no
+padding of f.
 
-Dynamic scalar indexing on the lane dimension is not TPU-friendly, so every
-row/column pick is a masked reduction against a 2-D ``broadcasted_iota``
-and every triangular constraint a ``jnp.where`` mask — the standard trick
-for in-kernel factorizations.  Vectors keep a unit dimension so that they
-stay in the layout the reduction produced: a column of a ``[TB, F, F]``
-block is ``[TB, F, 1]`` (F on sublanes), a row is ``[TB, 1, F]`` (F on
-lanes).  Where a step needs the other orientation the vector is moved
-through the diagonal (``_col_to_row`` / ``_row_to_col``), which adds only
-exact zeros.
+The wrapper ``kernels.ops.batch_solve`` moves ``A`` and ``B`` into this
+layout, pads the systems to a multiple of ``T`` with identity systems
+(x = 0), and moves the solution back.
 """
 from __future__ import annotations
 
@@ -23,112 +26,101 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro import compat
+from repro.kernels.budgets import BUDGETS, SOLVE_VMEM_LIMIT, footprint_bytes
+
+LANES = 128
+#: a column of a step, ``[f, T]`` f32, stays within this many vregs
+#: (8 x 128 words each) so it is held in registers through its updates
+COL_VREGS = 16
 
 
-def _iotas(F: int):
-    """(sublane index [F, 1], lane index [1, F], diagonal mask [F, F])."""
-    sub = jax.lax.broadcasted_iota(jnp.int32, (F, 1), 0)
-    lane = jax.lax.broadcasted_iota(jnp.int32, (1, F), 1)
-    diag = (jax.lax.broadcasted_iota(jnp.int32, (F, F), 0)
-            == jax.lax.broadcasted_iota(jnp.int32, (F, F), 1))
-    return sub, lane, diag
+def lanes_per_step(f: int, m: int) -> int:
+    """Systems per grid step for width ``f`` and ``m`` systems: 128 lanes
+    times the largest power of two up to 8 that keeps a column within
+    ``COL_VREGS`` vregs and the step within the kernel's VMEM budget, and
+    no more lanes than ``m`` needs.  (Past f ~ 104 even 128 lanes exceed
+    the budget: the driver's ``vmem/batch_solve_pallas`` record says so.)"""
+    budget = BUDGETS["batch_solve_pallas"].vmem_limit
+    k = 1
+    while (2 * k <= 8 and -(-f // 8) * 2 * k <= COL_VREGS
+           and footprint_bytes("batch_solve_pallas", f=f,
+                               T=2 * k * LANES) <= budget):
+        k *= 2
+    return min(k * LANES, -(-m // LANES) * LANES)
 
 
-def _col_to_row(v, diag):
-    """[TB, F, 1] -> [TB, 1, F]."""
-    return jnp.sum(jnp.where(diag, v, 0.0), axis=1, keepdims=True)
+def _batch_solve_kernel(a_ref, b_ref, x_ref, l_ref, y_ref):
+    """One step: ``l_ref`` [f, f, T] (column, row, system); ``y_ref``
+    [f, T]."""
+    f = b_ref.shape[0]
+    l_ref[...] = a_ref[...]
+    rows = jax.lax.broadcasted_iota(jnp.int32, b_ref.shape, 0)
 
+    def factor(j, carry):
+        d = l_ref[j, pl.ds(j, 1), :]                          # A_jj, [1, T]
+        col = jnp.where(rows >= j, l_ref[j] / jnp.sqrt(d), 0.0)
+        l_ref[j] = col                                        # L[:, j]
 
-def _row_to_col(v, diag):
-    """[TB, 1, F] -> [TB, F, 1]."""
-    return jnp.sum(jnp.where(diag, v, 0.0), axis=2, keepdims=True)
+        def update(k, c):
+            l_ref[k] = l_ref[k] - col * l_ref[j, pl.ds(k, 1), :]
+            return c
 
+        jax.lax.fori_loop(j + 1, f, update, 0)
+        return carry
 
-def _cholesky_inplace(A: jax.Array) -> jax.Array:
-    """Right-looking Cholesky of a batch [TB, F, F]; returns lower L."""
-    F = A.shape[-1]
-    sub, lane, diag = _iotas(F)
+    jax.lax.fori_loop(0, f, factor, 0)
 
-    def body(j, carry):
-        M, L = carry
-        colj = jnp.sum(jnp.where(lane == j, M, 0.0), axis=2,
-                       keepdims=True)                         # M[:, :, j]
-        dj = jnp.sum(jnp.where(sub == j, colj, 0.0), axis=1,
-                     keepdims=True)                           # M[:, j, j]
-        dj = jnp.maximum(dj, 1e-20)
-        c = jnp.where(sub >= j, colj * jax.lax.rsqrt(dj), 0.0)
-        L = L + jnp.where(lane == j, c, 0.0)                  # L[:, :, j] = c
-        ct = jnp.where(sub > j, c, 0.0)                       # strict trailing part
-        M = M - ct * _col_to_row(ct, diag)
-        return (M, L)
+    # L y = b, column by column; L's column j is zero above row j
+    y_ref[...] = b_ref[...]
 
-    _, L = jax.lax.fori_loop(0, F, body, (A, jnp.zeros_like(A)))
-    return L
+    def forward(j, carry):
+        yj = y_ref[pl.ds(j, 1), :] / l_ref[j, pl.ds(j, 1), :]
+        y_ref[...] = y_ref[...] - l_ref[j] * yj
+        y_ref[pl.ds(j, 1), :] = yj
+        return carry
 
+    jax.lax.fori_loop(0, f, forward, 0)
 
-def _trsv_lower(L: jax.Array, b: jax.Array) -> jax.Array:
-    """Solve L y = b (forward substitution); b, y are rows [TB, 1, F]."""
-    F = L.shape[-1]
-    sub, lane, _ = _iotas(F)
+    # L^T x = y from the last row up; rows of x not yet solved are zero
+    x_ref[...] = jnp.zeros_like(x_ref)
 
-    def body(j, y):
-        lrow = jnp.sum(jnp.where(sub == j, L, 0.0), axis=1,
-                       keepdims=True)                         # L[j, :]
-        s = jnp.sum(jnp.where(lane < j, lrow * y, 0.0), axis=2, keepdims=True)
-        bj = jnp.sum(jnp.where(lane == j, b, 0.0), axis=2, keepdims=True)
-        ljj = jnp.sum(jnp.where(lane == j, lrow, 0.0), axis=2, keepdims=True)
-        yj = (bj - s) / jnp.maximum(ljj, 1e-20)
-        return y + jnp.where(lane == j, yj, 0.0)
+    def backward(t, carry):
+        j = f - 1 - t
+        s = jnp.sum(l_ref[j] * x_ref[...], axis=0, keepdims=True)
+        x_ref[pl.ds(j, 1), :] = ((y_ref[pl.ds(j, 1), :] - s)
+                                 / l_ref[j, pl.ds(j, 1), :])
+        return carry
 
-    return jax.lax.fori_loop(0, F, body, jnp.zeros_like(b))
-
-
-def _trsv_upper_t(L: jax.Array, y: jax.Array) -> jax.Array:
-    """Solve L^T x = y (back substitution on the transposed factor); y is a
-    row [TB, 1, F], x is built as a column [TB, F, 1]."""
-    F = L.shape[-1]
-    sub, lane, diag = _iotas(F)
-
-    def body(t, x):
-        j = F - 1 - t
-        lcol = jnp.sum(jnp.where(lane == j, L, 0.0), axis=2,
-                       keepdims=True)                         # L[:, j] == L^T[j, :]
-        s = jnp.sum(jnp.where(sub > j, lcol * x, 0.0), axis=1, keepdims=True)
-        yj = jnp.sum(jnp.where(lane == j, y, 0.0), axis=2, keepdims=True)
-        ljj = jnp.sum(jnp.where(sub == j, lcol, 0.0), axis=1, keepdims=True)
-        xj = (yj - s) / jnp.maximum(ljj, 1e-20)
-        return x + jnp.where(sub == j, xj, 0.0)
-
-    return jax.lax.fori_loop(0, F, body, _row_to_col(jnp.zeros_like(y), diag))
-
-
-def _batch_solve_kernel(a_ref, b_ref, x_ref):
-    A = a_ref[...].astype(jnp.float32)        # [TB, F, F]
-    b = b_ref[...].astype(jnp.float32)        # [TB, F]
-    _, _, diag = _iotas(A.shape[-1])
-    L = _cholesky_inplace(A)
-    y = _trsv_lower(L, b[:, None, :])
-    x_ref[...] = _col_to_row(_trsv_upper_t(L, y), diag)[:, 0, :]
+    jax.lax.fori_loop(0, f, backward, 0)
 
 
 def batch_solve_pallas(
-    A: jax.Array,      # [m, F, F] SPD
-    B: jax.Array,      # [m, F]
+    At: jax.Array,     # [f, f, m] SPD systems, column-row-system
+    Bt: jax.Array,     # [f, m]    right-hand sides, row-system
     *,
-    tb: int = 8,
     interpret: bool = False,
 ) -> jax.Array:
-    """x_u = A_u^{-1} B_u for every u, one VMEM-resident batch per grid step."""
-    m, F, _ = A.shape
-    assert m % tb == 0, (m, tb)
+    """``[f, m]`` solutions of every system; m a multiple of
+    ``lanes_per_step(f, m)``."""
+    f, _, m = At.shape
+    T = lanes_per_step(f, m)
+    assert m % T == 0, (m, T)
     return compat.pallas_call(
         _batch_solve_kernel,
-        grid=(m // tb,),
+        grid=(m // T,),
         in_specs=[
-            pl.BlockSpec((tb, F, F), lambda i: (i, 0, 0)),
-            pl.BlockSpec((tb, F), lambda i: (i, 0)),
+            pl.BlockSpec((f, f, T), lambda i: (0, 0, i)),
+            pl.BlockSpec((f, T), lambda i: (0, i)),
         ],
-        out_specs=pl.BlockSpec((tb, F), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((m, F), jnp.float32),
+        out_specs=pl.BlockSpec((f, T), lambda i: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((f, m), jnp.float32),
+        scratch_shapes=[
+            compat.vmem((f, f, T), jnp.float32),   # A, factored in place
+            compat.vmem((f, T), jnp.float32),      # y of L y = b
+        ],
+        compiler_params=compat.tpu_compiler_params(
+            dimension_semantics=("parallel",),
+            vmem_limit_bytes=SOLVE_VMEM_LIMIT,
+        ),
         interpret=interpret,
-    )(A, B)
+    )(At, Bt)

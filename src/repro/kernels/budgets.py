@@ -24,9 +24,11 @@ this repo streams f32.
 Budgets are per *wrapper function* (the enclosing ``def`` of the
 ``pallas_call``).  ``dim_bounds`` are the worst-case tile sizes the
 wrapper is allowed to be called with; the public wrappers enforce them by
-construction (tm/tk/tb defaults, ``f_mult=128`` padding) except for the
+construction (tm/tk defaults, ``f_mult=128`` padding) except for the
 SGD tile sizes mb/nb, which ``sgd.blocking`` keeps at or below the bound
-for every grid the repo builds (g >= 2 over the bench shapes).
+for every grid the repo builds (g >= 2 over the bench shapes), and the
+solve's systems per step T, which ``batch_solve.lanes_per_step`` picks
+from f against this limit (T = 128 at f = 100, 1024 at f = 10).
 
 Degree-binned dispatch (``BinnedELL`` bins, per-tile-K SGD groups) needs
 no budget entries of its own: each per-bin call is the same wrapper at a
@@ -42,17 +44,24 @@ are set against, with headroom for interpreter/layout slack):
   out 516 KiB, doubled, + 516 KiB scratch ~= 2.53 MiB  -> limit 4 MiB.
 - ``herm_hbm_accum``     (tm=8, tk=128, F=128): ~2.03 MiB (no scratch —
   that is the point of the Fig. 7 ablation)          -> limit 4 MiB.
-- ``batch_solve_pallas`` (tb=8, F=128): ~1.02 MiB     -> limit 2 MiB.
+- ``batch_solve_pallas`` (f=100, T=128): streamed in 4.93 MiB + out
+  50 KiB, doubled, + 4.93 MiB scratch ~= 14.9 MiB   -> limit 16 MiB
+  (the systems on the lanes: an ``[f, f, T]`` block of A, its in-place
+  factor, ``[f, T]`` blocks of B, y and x; f = 10 at T = 1024 reads
+  1.4 MiB; past f ~ 104 even T = 128 exceeds the limit).
 - ``sgd_tile_pallas``    (mb=nb=1024, f=128): streamed in ~1.01 MiB +
   out 1 MiB, doubled, + 1 MiB scratch ~= 5.02 MiB     -> limit 8 MiB
   (the factors stream transposed, ``[f, mb]`` / ``[f, nb]``, and each ELL
   slot as a ``[1, 1, mb]`` row — the same element counts).
 
 All well under the 16 MiB default scoped VMEM, with room for the
-compiler's own temporaries — except the SGD tile's in-kernel ``[nb, mb]``
-one-hot, which is no block and which the model does not see: that kernel
-asks Mosaic for ``SGD_VMEM_LIMIT``.  A new kernel (or a tile-size bump)
-that blows its limit fails the lint job before it ever reaches hardware;
+compiler's own temporaries — except two kernels that ask Mosaic for more
+scoped VMEM: the SGD tile, whose in-kernel ``[nb, mb]`` one-hot is no
+block and which the model does not see (``SGD_VMEM_LIMIT``), and the
+batched solve, whose blocks at f = 100 come near the 16 MiB default once
+Mosaic pads the rows to a multiple of 8 (``SOLVE_VMEM_LIMIT``).  A new
+kernel (or a tile-size bump) that blows its limit fails the lint job
+before it ever reaches hardware;
 ``tests/test_tpu_compile.py`` compiles every kernel for a described v5e,
 which also catches what this model cannot.
 """
@@ -69,6 +78,11 @@ VMEM_BYTES = 16 * (1 << 20)
 #: below does not see them (19.4 MiB at mb = nb = 1024, F = 128 when
 #: compiled for a v5e, against the 16 MiB default scoped limit)
 SGD_VMEM_LIMIT = 32 * (1 << 20)
+
+#: scoped-VMEM limit the batched solve asks Mosaic for: at f = 100 its
+#: blocks, rows padded to 104, take 15.5 MiB of the 16 MiB default before
+#: Mosaic's own temporaries
+SOLVE_VMEM_LIMIT = 32 * (1 << 20)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -88,8 +102,9 @@ def footprint_bytes(name: str, **dims) -> int:
     plan-vs-actual ledger can record budget-vs-launched.
 
     Dims are the symbolic names of ``BUDGETS[name].dim_bounds`` (``F`` is
-    the f_mult-padded latent dim; the SGD kernel's ``K`` sizes the grid,
-    not a block, and is not needed).  Block sums mirror the wrappers'
+    the f_mult-padded latent dim of the Hermitian kernels, ``f`` the
+    unpadded one of the solve and SGD kernels; the SGD kernel's ``K``
+    sizes the grid, not a block, and is not needed).  Block sums mirror the wrappers'
     BlockSpecs exactly: idx/val/mask/cnt stream as separate f32-width
     blocks, factor/accumulator tiles as [**, F] / [**, F, F] blocks.
     """
@@ -105,10 +120,11 @@ def footprint_bytes(name: str, **dims) -> int:
         out = tm * F * F + tm * F                   # A, B (HBM round-trip)
         return 4 * 2 * (inb + out)
     if name == "batch_solve_pallas":
-        tb, F = dims["tb"], dims["F"]
-        inb = tb * F * F + tb * F                   # A batch, B batch
-        out = tb * F                                # solved rows
-        return 4 * 2 * (inb + out)
+        f, T = dims["f"], dims["T"]
+        inb = f * f * T + f * T                     # A, B (systems on lanes)
+        out = f * T                                 # x
+        scratch = f * f * T + f * T                 # factor of A, y
+        return 4 * (2 * (inb + out) + scratch)
     if name == "sgd_tile_pallas":
         mb, nb, f = dims["mb"], dims["nb"], dims["f"]
         fac = (mb + nb) * f                         # x^T + theta^T blocks
@@ -134,9 +150,11 @@ BUDGETS: dict[str, KernelBudget] = {
              "round-trips HBM so no scratch term",
     ),
     "batch_solve_pallas": KernelBudget(
-        vmem_limit=2 * (1 << 20),
-        dim_bounds={"tb": 8, "F": 128},
-        note="batched Cholesky solve; one [tb, F, F] system batch resident",
+        vmem_limit=16 * (1 << 20),
+        dim_bounds={"f": 100, "T": 128},
+        note="batched Cholesky solve, systems on the lanes: one [f, f, T] "
+             "block of A and its in-place factor resident; T is picked by "
+             "batch_solve.lanes_per_step against this limit",
     ),
     "sgd_tile_pallas": KernelBudget(
         vmem_limit=8 * (1 << 20),

@@ -4,16 +4,22 @@
 calls.  They handle:
 
 - the theta gather (XLA DMA-gather == the paper's texture-cached read),
-- padding m / K / F up to tile multiples (F to the MXU lane width),
+- padding m / K / F up to tile multiples (F to the MXU lane width) for
+  the Hermitian, and the solve's move to its systems-on-lanes layout,
 - ``jax.named_scope`` names for the ALS stages (``als.herm`` with its
   ``als.gather`` inside, ``als.solve``): HLO metadata only, so device
   profiles can split device time by stage; dispatch and numerics are the
   same without them,
-- kernel-vs-oracle dispatch by ``mode``: ``"ref"`` (the default) is the
-  jnp oracle in ``kernels.ref``; ``"kernel"`` runs the Pallas kernels,
-  compiled by Mosaic on TPU and run by the Pallas interpreter on the CPU
-  backend (``repro.compat.pallas_call``; any other backend raises);
-  ``"kernel_interpret"`` asks for the interpreter on any backend.
+- kernel-vs-oracle dispatch.  ``mode`` selects the Hermitian's
+  implementation: ``"ref"`` (the default) is the jnp oracle in
+  ``kernels.ref``; ``"kernel"`` runs the Pallas kernel, compiled by Mosaic
+  on TPU and run by the Pallas interpreter on the CPU backend
+  (``repro.compat.pallas_call``; any other backend raises);
+  ``"kernel_interpret"`` asks for the interpreter on any backend.  The
+  solve is decided by the backend alone: on a TPU it runs the Pallas
+  kernel whatever ``mode`` is, elsewhere the oracle
+  ``kref.batch_solve_ref``; ``"kernel_interpret"`` runs the kernel in the
+  interpreter on any backend.
 """
 from __future__ import annotations
 
@@ -23,8 +29,9 @@ from typing import Literal
 import jax
 import jax.numpy as jnp
 
+from repro import compat
 from repro.kernels import ref as kref
-from repro.kernels.batch_solve import batch_solve_pallas
+from repro.kernels.batch_solve import batch_solve_pallas, lanes_per_step
 from repro.kernels.hermitian import fused_herm_pallas
 
 Mode = Literal["kernel", "kernel_interpret", "ref"]
@@ -100,30 +107,45 @@ def fused_herm(
         return A[:m, :f, :f], B[:m, :f]
 
 
-@functools.partial(jax.jit, static_argnames=("mode", "tb"))
+def _solve_kernel(mode: Mode) -> bool:
+    """Whether ``batch_solve`` runs the Pallas kernel (else the oracle)."""
+    return mode == "kernel_interpret" or compat.on_tpu()
+
+
+def solve_width(m: int, f: int, mode: Mode = "ref") -> int:
+    """Systems ``batch_solve`` factors for ``m`` systems of width ``f``:
+    ``m`` rounded up to the kernel's systems per step where the kernel
+    runs, else ``m``."""
+    if not _solve_kernel(mode):
+        return m
+    return _round_up(m, lanes_per_step(f, m))
+
+
+@functools.partial(jax.jit, static_argnames=("mode",))
 def batch_solve(
     A: jax.Array,  # [m, f, f]
     B: jax.Array,  # [m, f]
     *,
     mode: Mode = "ref",
-    tb: int = 8,
 ) -> jax.Array:
-    """x_u = A_u^{-1} B_u (batched Cholesky solve)."""
+    """x_u = A_u^{-1} B_u (batched Cholesky solve); see the module doc for
+    which implementation runs."""
     with jax.named_scope("als.solve"):
-        if mode == "ref":
+        if not _solve_kernel(mode):
             return kref.batch_solve_ref(A, B)
         m, f, _ = A.shape
-        mp = _round_up(m, tb)
-        eye_pad = jnp.eye(f, dtype=A.dtype)[None]
-        A_p = _pad_axis(A, 0, mp)
-        # padded batch entries get I so the factorization stays nonsingular
+        mp = solve_width(m, f, mode)
+        # column-row-system layout; padded systems are I x = 0
+        At = jnp.transpose(A, (2, 1, 0))
+        Bt = B.T
         if mp != m:
-            padmask = (jnp.arange(mp) < m).astype(A.dtype)[:, None, None]
-            A_p = A_p * padmask + (1.0 - padmask) * eye_pad
-        B_p = _pad_axis(B, 0, mp)
-        x = batch_solve_pallas(A_p, B_p, tb=tb,
+            eye = jnp.broadcast_to(jnp.eye(f, dtype=A.dtype)[:, :, None],
+                                   (f, f, mp - m))
+            At = jnp.concatenate([At, eye], axis=2)
+            Bt = _pad_axis(Bt, 1, mp)
+        x = batch_solve_pallas(At, Bt,
                                interpret=(mode == "kernel_interpret"))
-        return x[:m]
+        return x.T[:m]
 
 
 def als_update_factor(
@@ -136,10 +158,9 @@ def als_update_factor(
     mode: Mode = "ref",
     tm: int = 8,
     tk: int = 128,
-    tb: int = 8,
     f_mult: int = 128,
 ) -> jax.Array:
     """One half-iteration: given fixed theta, solve all rows of X (paper Alg. 1/2)."""
     A, B = fused_herm(theta, idx, val, cnt, lam,
                       mode=mode, tm=tm, tk=tk, f_mult=f_mult)
-    return batch_solve(A, B, mode=mode, tb=tb)
+    return batch_solve(A, B, mode=mode)

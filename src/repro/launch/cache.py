@@ -32,10 +32,14 @@ def compile_cache_dir() -> str:
 def enable_compile_cache() -> str:
     """Turn the persistent compilation cache on; returns its directory.
 
-    With ``JAX_COMPILATION_CACHE_DIR`` set this leaves everything to JAX and
-    sets nothing.
+    With ``JAX_COMPILATION_CACHE_DIR`` set this leaves the directory to JAX.
+    Every program is kept, however fast it compiled: JAX by default keeps
+    only those that took a second or more, and the row-block ``lax.map``
+    that ``core.als`` lowers afresh on every call (~0.8 s to compile with
+    the Pallas solve on a v5e) would then compile again on every call.
     """
     path = compile_cache_dir()
     if not os.environ.get(ENV_VAR):
         jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     return path

@@ -54,6 +54,8 @@ from repro.checkpoint import CheckpointManager
 from repro.core import als as als_mod
 from repro.core.objective import rmse_padded
 from repro.data.prefetch import Prefetcher
+from repro.kernels import ops as kops
+from repro.kernels.batch_solve import lanes_per_step
 from repro.kernels.budgets import BUDGETS, footprint_bytes
 from repro.obs.ledger import Ledger
 from repro.obs.metrics import MetricsRegistry
@@ -231,7 +233,7 @@ def run_streaming_als(
         topo_traffic = dreduce.reduce_traffic(n * (f * f + f + 1) * 4, topo)
         wave_update = make_wave_update_fn(
             mesh, cfg.lam, mode=cfg.mode,
-            tm=cfg.tm, tk=cfg.tk, tb=cfg.tb, f_mult=cfg.f_mult)
+            tm=cfg.tm, tk=cfg.tk, f_mult=cfg.f_mult)
         wave_herm = make_wave_herm_fn(
             mesh, cfg.lam, mode=cfg.mode,
             tm=cfg.tm, tk=cfg.tk, f_mult=cfg.f_mult)
@@ -282,6 +284,12 @@ def run_streaming_als(
             return tree
         ckpt.save(step, tree_fn)
 
+    def _count_solve(rows: int, factored: int):
+        """``rows`` real systems solved; ``factored`` systems the solve
+        factored for them (row-block and lane padding included)."""
+        reg.counter("solve_systems").inc(rows)
+        reg.counter("solve_padded_systems").inc(factored)
+
     # ------------------------------------------------------------------
     # solve-X half: stream R row slices, solve rows, write back.
     # ------------------------------------------------------------------
@@ -319,6 +327,8 @@ def run_streaming_als(
                         rows = np.asarray(
                             update_rows_fn(theta_dev, idx, val, cnt))
                         meter.free("x_scratch")
+                        m_w = idx.shape[0]
+                        _count_solve(m_w, als_mod.solved_systems(m_w, cfg))
                         factors.write_slice("x", wave.row_start,
                                             wave.row_stop, rows)
                     meter.free(f"xwave{wave.index}")
@@ -387,6 +397,7 @@ def run_streaming_als(
                             factors.write_slice(
                                 "theta", 0, n,
                                 np.asarray(solve_acc_fn(A, B, c)))
+                            _count_solve(n, als_mod.solved_systems(n, cfg))
                             meter.free("theta_out")
                     reg.counter("waves_run").inc()
                     reg.counter("batches_loaded").inc(len(payload))
@@ -433,6 +444,10 @@ def run_streaming_als(
                         rows = np.asarray(
                             als_mod.update_rows_binned(theta_dev, bsl, cfg))
                         meter.free("x_scratch")
+                        for b in bsl.bins:
+                            if b.m:
+                                _count_solve(
+                                    b.m, als_mod.solved_systems(b.m, cfg))
                         factors.write_slice("x", wave.row_start,
                                             wave.row_stop, rows)
                     meter.free(f"xwave{wave.index}")
@@ -497,6 +512,7 @@ def run_streaming_als(
                             factors.write_slice(
                                 "theta", 0, n,
                                 np.asarray(solve_acc_fn(A, B, c)))
+                            _count_solve(n, als_mod.solved_systems(n, cfg))
                             meter.free("theta_out")
                     reg.counter("waves_run").inc()
                     reg.counter("batches_loaded").inc(len(payload))
@@ -552,6 +568,10 @@ def run_streaming_als(
                         rows = np.asarray(
                             custom_update(theta_dev, idx, val, cnt))
                         meter.free("x_scratch")
+                        # each of the n_data * p devices solves its slice
+                        shards = n_data * p
+                        _count_solve(wave.rows, shards * kops.solve_width(
+                            full_rows // shards, f, cfg.mode))
                         factors.write_slice("x", wave.row_start,
                                             wave.row_stop, rows[:wave.rows])
                     meter.free(f"xwave{wave.index}")
@@ -742,6 +762,7 @@ def run_streaming_als(
             th_k = solve_acc_fn(jnp.asarray(A[lo:hi], jnp.float32),
                                 jnp.asarray(B[lo:hi], jnp.float32),
                                 jnp.asarray(c[lo:hi], jnp.float32))
+            _count_solve(npp, als_mod.solved_systems(npp, cfg))
             factors.write_shard("theta", k, p, np.asarray(th_k))
         meter.free("theta_out")
 
@@ -883,7 +904,8 @@ def run_streaming_als(
                unit="bytes", check="le", mode=cfg.mode)
     led.record("vmem/batch_solve_pallas",
                BUDGETS["batch_solve_pallas"].vmem_limit,
-               footprint_bytes("batch_solve_pallas", tb=cfg.tb, F=F),
+               footprint_bytes("batch_solve_pallas", f=f,
+                               T=lanes_per_step(f, max(m_pad, n))),
                unit="bytes", check="le", mode=cfg.mode)
 
     return factors, history, StreamTelemetry.from_registry(

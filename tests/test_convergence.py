@@ -72,7 +72,7 @@ def test_kernel_path_converges_same(problem):
     rt = als_mod.ell_triplet(r_tr_T)
     kw = dict(f=spec.f, lam=spec.lam, iters=2)
     c_ref = als_mod.AlsConfig(mode="ref", **kw)
-    c_kern = als_mod.AlsConfig(mode="kernel_interpret", tm=8, tk=8, tb=8,
+    c_kern = als_mod.AlsConfig(mode="kernel_interpret", tm=8, tk=8,
                                f_mult=8, **kw)
     s0 = als_mod.als_init(r_tr.m, r_tr_T.m, c_ref)
     sr = als_mod.als_iteration(s0, r, rt, c_ref)

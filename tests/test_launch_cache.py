@@ -22,7 +22,8 @@ def test_compile_cache_honours_env_var(monkeypatch, tmp_path):
     monkeypatch.setenv(cache.ENV_VAR, str(tmp_path))
     assert cache.compile_cache_dir() == str(tmp_path)
     assert cache.enable_compile_cache() == str(tmp_path)
-    assert calls == []          # JAX reads the variable itself
+    # JAX reads the variable itself; every program is kept
+    assert calls == [("jax_persistent_cache_min_compile_time_secs", 0)]
 
 
 def test_compile_cache_fixed_repo_path_when_unset(monkeypatch):
@@ -32,4 +33,5 @@ def test_compile_cache_fixed_repo_path_when_unset(monkeypatch):
     second = cache.enable_compile_cache()
     assert first == second == os.path.join(cache.REPO_ROOT, ".jax_cache")
     assert os.path.isfile(os.path.join(cache.REPO_ROOT, "pyproject.toml"))
-    assert calls == [("jax_compilation_cache_dir", first)] * 2
+    assert calls == [("jax_compilation_cache_dir", first),
+                     ("jax_persistent_cache_min_compile_time_secs", 0)] * 2

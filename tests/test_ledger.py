@@ -292,7 +292,7 @@ class TestVmemFootprints:
         cases = {
             "fused_herm_pallas": (dict(tm=8, tk=128, F=128), 2.53),
             "herm_hbm_accum": (dict(tm=8, tk=128, F=128), 2.023),
-            "batch_solve_pallas": (dict(tb=8, F=128), 1.02),
+            "batch_solve_pallas": (dict(f=100, T=128), 14.89),
             "sgd_tile_pallas": (dict(mb=1024, nb=1024, f=128), 5.02),
         }
         for name, (dims, want_mib) in cases.items():

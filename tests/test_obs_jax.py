@@ -192,6 +192,6 @@ class TestNamedScopes:
         assert "als.solve" not in herm
         A = jnp.broadcast_to(jnp.eye(8, dtype=jnp.float32), (16, 8, 8))
         B = jnp.ones((16, 8), jnp.float32)
-        solve = kops.batch_solve.lower(A, B, mode=mode,
-                                       tb=8).as_text(debug_info=True)
+        solve = kops.batch_solve.lower(
+            A, B, mode=mode).as_text(debug_info=True)
         assert "als.solve" in solve and "als.herm" not in solve

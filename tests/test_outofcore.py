@@ -157,6 +157,28 @@ def test_streaming_ragged_last_wave():
     assert tel.peak_bytes <= tel.capacity_bytes
 
 
+@pytest.mark.parametrize("mode,padded", [("ref", 192),
+                                         ("kernel_interpret", 768)])
+def test_streaming_counts_solved_systems(mode, padded):
+    """``solve_systems`` counts the rows solved (96 users + 40 items);
+    ``solve_padded_systems`` the systems factored: two waves of 48 rows
+    and the 40 items, each in row blocks of 32 (64 systems), and on the
+    kernel path each block widened to the kernel's 128 lanes."""
+    from repro.obs.metrics import MetricsRegistry
+
+    r, _, _, _ = _problem()
+    cfg = als_mod.AlsConfig(f=SPEC.f, lam=SPEC.lam, iters=1, mode=mode,
+                            batch_rows=32)
+    store = RatingStore(r, q=4)
+    sched = build_schedule(_forced_plan(r, q=4, n_data=2, store=store),
+                           SPEC.m, SPEC.n, n_data=2)
+    assert [w.rows for w in sched.waves] == [48, 48]
+    reg = MetricsRegistry()
+    run_streaming_als(store, sched, cfg, registry=reg)
+    assert reg.counter("solve_systems").value == SPEC.m + SPEC.n
+    assert reg.counter("solve_padded_systems").value == padded
+
+
 # ---------------------------------------------------------------------------
 # End-to-end multi-wave runs (slow lane)
 # ---------------------------------------------------------------------------

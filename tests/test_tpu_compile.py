@@ -5,8 +5,9 @@ by ``jax.experimental.topologies`` and compiles it with the TPU compiler
 installed alongside JAX.  That refuses what the interpreter accepts and
 Mosaic cannot lower — a block off the (8, 128) tiling, a matmul without a
 non-contracting dimension, more scoped VMEM than a kernel may use — before
-any chip time is spent.  Widths are the real ones: F = 128 lanes, and
-f = 100 through ``ops.als_update_factor`` for the padding path.
+any chip time is spent.  Widths are the real ones: F = 128 lanes, f = 100
+through ``ops.als_update_factor`` for the padding path, and the batched
+solve at its unpadded f = 100 and f = 10 through ``ops.batch_solve``.
 
 The topology is described inside a module-scoped fixture, never at import:
 only one process at a time may load the TPU library, and every test worker
@@ -19,7 +20,6 @@ from jax.sharding import SingleDeviceSharding
 
 from repro import compat
 from repro.kernels import ops
-from repro.kernels.batch_solve import batch_solve_pallas
 from repro.kernels.hermitian import fused_herm_pallas, herm_hbm_accum
 from repro.kernels.sgd_update import sgd_tile_pallas
 
@@ -58,6 +58,10 @@ def tpu_compile(monkeypatch, one_chip):
     was_on = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
+    # the ops' jitted wrappers pick a path by backend while tracing: no
+    # trace cached under the CPU backend may serve here, nor one of these
+    # after the test
+    jax.clear_caches()
 
     def compile_(fn, *shapes):
         args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
@@ -71,6 +75,7 @@ def tpu_compile(monkeypatch, one_chip):
     finally:
         jax.config.update("jax_enable_compilation_cache", was_on)
         compilation_cache.reset_cache()
+        jax.clear_caches()
 
 
 f32, i32 = jnp.float32, jnp.int32
@@ -88,9 +93,13 @@ def test_herm_hbm_accum_compiles_for_v5e(tpu_compile):
                 ((m, K), f32), ((m,), f32))
 
 
-def test_batch_solve_compiles_for_v5e(tpu_compile):
-    m = 64
-    tpu_compile(batch_solve_pallas, ((m, F, F), f32), ((m, F), f32))
+@pytest.mark.parametrize("f,m", [(100, 16384), (100, 15200), (10, 16384)])
+def test_batch_solve_compiles_for_v5e(tpu_compile, f, m):
+    """The systems-minor solve at a full row block, a ragged bin, and the
+    SparkALS width: the kernel replaces XLA's Cholesky custom calls."""
+    compiled = tpu_compile(ops.batch_solve, ((m, f, f), f32), ((m, f), f32))
+    text = compiled.as_text()
+    assert "Cholesky" not in text and "InvertDiagBlocks" not in text
 
 
 def test_sgd_tile_compiles_for_v5e(tpu_compile):
