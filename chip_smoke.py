@@ -114,7 +114,7 @@ def build_plan(r, q: int, p: int, n_data: int, n_bins: int, hbm: int):
     plan = plan_for(M, N, r.nnz, F, p=p, q=q, n_data=n_data, hbm_bytes=hbm,
                     buffers=PREFETCH_DEPTH + 2,
                     acc_bytes=streaming_acc_bytes(N, F),
-                    bin_fills=store.bin_fill_pairs())
+                    bin_fills=store.bin_fill_pairs(), block_rows=BATCH_ROWS)
     sched = build_schedule(plan, M, N, n_data=n_data)
     print(f"store: q={q} p={p} n_bins={n_bins} fill_r={store.fill_r:.3f} "
           f"fill_rt={store.fill_rt:.3f} host {store.host_nbytes / GB:.2f} GB "
@@ -143,17 +143,20 @@ def placement(store, sched, hbm: int) -> None:
     import jax.numpy as jnp
 
     from repro.core import als as als_mod
+    from repro.core.partition import streaming_acc_bytes
 
     F_pad = -(-F // 128) * 128
     x_bins = [store.x_slice_binned(w.row_start, w.row_stop)
               for w in sched.waves]
     xr, xk = _largest_bin(x_bins)
     tr, tk = _largest_bin(store.rt_binned)
+    tb = min(BATCH_ROWS, -(-tr // 128) * 128)     # one R^T row block
+    tr_pad = -(-tr // tb) * tb
     print(f"reckoned: factors {(M + N) * F * 4 / GB:.3f} GB; Hermitian block "
           f"[{BATCH_ROWS}, {F_pad}, {F_pad}] {BATCH_ROWS * F_pad ** 2 * 4 / GB:.3f}"
-          f" GB; theta accumulator [{N}, {F_pad}, {F_pad}] "
-          f"{N * F_pad ** 2 * 4 / GB:.3f} GB; largest R^T bin gather "
-          f"[{tr}, {tk}, {F_pad}] {tr * tk * F_pad * 4 / GB:.3f} GB",
+          f" GB; theta accumulator [{F * F}, {N}] "
+          f"{streaming_acc_bytes(N, F) / GB:.3f} GB; largest R^T bin block "
+          f"gather [{tb}, {tk}, {F_pad}] {tb * tk * F_pad * 4 / GB:.3f} GB",
           flush=True)
     S = jax.ShapeDtypeStruct
     i32, f32 = jnp.int32, jnp.float32
@@ -166,15 +169,18 @@ def placement(store, sched, hbm: int) -> None:
                     th, i, v, c, cfg),
                 (S((N, F), f32), S((xr, xk), i32), S((xr, xk), f32),
                  S((xr,), i32))),
-            f"R^T bin [{tr}, {tk}]": (
-                lambda x, i, v, c, cfg=cfg: als_mod.partial_herm(
-                    x, i, v, c, cfg),
-                (S((sched.m_pad // store.q, F), f32), S((tr, tk), i32),
-                 S((tr, tk), f32), S((tr,), i32))),
+            f"R^T bin [{tk}, {tr_pad}] in blocks of {tb}": (
+                lambda a, b, c, x, i, v, k, cfg=cfg: als_mod.accumulate_bin(
+                    a, b, c, x, als_mod.LanesBin(
+                        idx=i, val=v, cnt=k, cols=None, start=0, block=tb,
+                        rows=tr, nnz=0), cfg),
+                (S((F * F, N), f32), S((F, N), f32), S((N,), f32),
+                 S((sched.m_pad // store.q, F), f32), S((tk, tr_pad), i32),
+                 S((tk, tr_pad), f32), S((tr_pad,), i32))),
             f"theta solve [{N}]": (
                 lambda a, b, c, cfg=cfg: als_mod.solve_accumulated(
                     a, b, c, cfg),
-                (S((N, F, F), f32), S((N, F), f32), S((N,), f32))),
+                (S((F * F, N), f32), S((F, N), f32), S((N,), f32))),
         }
         for name, (fn, shapes) in progs.items():
             ma = jax.jit(fn).lower(*shapes).compile().memory_analysis()

@@ -47,23 +47,17 @@ def als_init(m: int, n: int, cfg: AlsConfig) -> AlsState:
     return AlsState(x=x, theta=theta, iteration=jnp.int32(0))
 
 
-def _map_row_blocks(solve_block, arrays, batch_rows: int, pad_vals=None):
-    """Row-block scaffolding shared by the q-batched solves: pad the leading
-    axis up to a multiple of ``batch_rows`` (with zeros, or a broadcast
-    ``pad_vals[i]`` per array — e.g. I for Hermitians so padded solves stay
-    nonsingular), ``lax.map`` over the blocks, unpad the result."""
+def _map_row_blocks(solve_block, arrays, batch_rows: int):
+    """Row-block scaffolding of the q-batched solves: pad the leading axis
+    with zeros up to a multiple of ``batch_rows``, ``lax.map`` over the
+    blocks, unpad the result."""
     m = arrays[0].shape[0]
     nb = -(-m // batch_rows)
     pad = nb * batch_rows - m
     blocked = []
-    for i, a in enumerate(arrays):
-        pv = None if pad_vals is None else pad_vals[i]
+    for a in arrays:
         if pad:
-            if pv is None:
-                a = jnp.pad(a, ((0, pad),) + ((0, 0),) * (a.ndim - 1))
-            else:
-                a = jnp.concatenate(
-                    [a, jnp.broadcast_to(pv, (pad,) + a.shape[1:])])
+            a = jnp.pad(a, ((0, pad),) + ((0, 0),) * (a.ndim - 1))
         blocked.append(a.reshape((nb, batch_rows) + a.shape[1:]))
     out = jax.lax.map(solve_block, tuple(blocked))
     return out.reshape((nb * batch_rows,) + out.shape[2:])[:m]
@@ -98,36 +92,38 @@ def partial_herm(x_batch, idx_loc, val_loc, cnt_loc, cfg: AlsConfig):
     """Per-batch partial Hermitian (out-of-core wave driver, accumulate side).
 
     ``idx_loc`` indexes into ``x_batch`` (batch-local user coordinates, the
-    output of ``partition_padded`` on R^T).  Returns (A_j, B_j) partial sums;
-    summing over all q batches reproduces the full-gather Hermitian because
-    the weighted-lambda diagonal ``lam * cnt_loc`` also telescopes to
-    ``lam * cnt_total`` — the same partial-sum scheme SU-ALS reduces over the
-    "model" axis (eq. 5-7), serialized over waves instead.
+    output of ``partition_padded`` on R^T).  Returns (A_j [f*f, n],
+    B_j [f, n]) partial sums with the systems on the lanes; summing over
+    all q batches reproduces the full-gather Hermitian because the
+    weighted-lambda diagonal ``lam * cnt_loc`` also telescopes to
+    ``lam * cnt_total`` — the same partial-sum scheme SU-ALS reduces over
+    the "model" axis (eq. 5-7), serialized over waves instead.
     """
-    return kops.fused_herm(
-        x_batch, idx_loc, val_loc, cnt_loc, cfg.lam,
-        mode=cfg.mode, tm=cfg.tm, tk=cfg.tk, f_mult=cfg.f_mult,
-        diag_fallback=False)
+    return kops.herm_lanes(
+        x_batch, jnp.asarray(idx_loc).T, jnp.asarray(val_loc).T, cnt_loc,
+        cfg.lam, mode=cfg.mode, tm=cfg.tm, tk=cfg.tk, f_mult=cfg.f_mult)
 
 
 def solve_accumulated(A, B, cnt_total, cfg: AlsConfig) -> jax.Array:
-    """Solve a factor from accumulated partial Hermitians.
+    """Solve a factor from accumulated partial Hermitians held with the
+    systems on the lanes (``A [f*f, n]``, ``B [f, n]``, ``cnt_total
+    [n]``); returns ``[n, f]``.
 
     Applies the globally-empty-row guard post-accumulation (a row empty in
     every batch gets A = I, B = 0 -> x = 0, matching ``fused_herm``'s
-    ``diag_fallback``) then runs the batched Cholesky solve, in row blocks of
-    ``cfg.batch_rows`` when set so the solve working set stays bounded.
+    ``diag_fallback``) and hands the systems to the lanes solve as
+    ``[f, f, n]``, with no transpose, in one program per shape.
     """
-    f = A.shape[-1]
+    return _solve_accumulated(A, B, cnt_total, mode=cfg.mode)
+
+
+@functools.partial(jax.jit, static_argnames=("mode",))
+def _solve_accumulated(A, B, cnt_total, *, mode):
+    f, n = B.shape
     empty = (cnt_total <= 0).astype(A.dtype)
-    A = A + empty[:, None, None] * jnp.eye(f, dtype=A.dtype)[None, :, :]
-    solve = functools.partial(kops.batch_solve, mode=cfg.mode)
-    m = A.shape[0]
-    if cfg.batch_rows and cfg.batch_rows < m:
-        return _map_row_blocks(
-            lambda ab: solve(ab[0], ab[1]), (A, B), cfg.batch_rows,
-            pad_vals=(jnp.eye(f, dtype=A.dtype), None))
-    return solve(A, B)
+    A = A.reshape(f, f, n)
+    A = A + jnp.eye(f, dtype=A.dtype)[:, :, None] * empty[None, None, :]
+    return kops.batch_solve_lanes(A, B, mode=mode).T
 
 
 def solved_systems(m: int, cfg: AlsConfig) -> int:
@@ -208,23 +204,149 @@ def update_rows_binned(fixed, binned, cfg: AlsConfig) -> jax.Array:
     return update_factor_binned(fixed, binned, cfg)
 
 
-def partial_herm_binned(x_batch, binned_loc, cfg: AlsConfig):
-    """Binned per-batch partial Hermitian (accumulate side): run
-    :func:`partial_herm` once per bin of the batch-local R^T shard and
-    scatter-add into full-size (A_j, B_j), so the caller's per-batch
-    ``A += A_j`` accumulation is layout-blind."""
-    n, f = binned_loc.m, cfg.f
-    A = jnp.zeros((n, f, f), jnp.float32)
-    B = jnp.zeros((n, f), jnp.float32)
-    for b, rows in zip(binned_loc.bins, binned_loc.rows):
+class LanesBin(NamedTuple):
+    """One degree bin of an R^T shard in the accumulate update's layout:
+    slots on the sublanes and rows on the lanes, rows padded to whole row
+    blocks with ``cnt = 0`` (zero partials)."""
+
+    idx: np.ndarray    # [K, rows_pad] int32, batch-local X rows
+    val: np.ndarray    # [K, rows_pad] float32
+    cnt: np.ndarray    # [rows_pad] int32
+    #: [rows_pad] int32 accumulator column of each row (padding rows past
+    #: the last column, dropped), or None where the rows are the columns
+    #: ``[start, start + rows_pad)`` in order
+    cols: Optional[np.ndarray]
+    start: int
+    block: int         # rows per block, a multiple of 128
+    rows: int          # real rows
+    nnz: int           # real ratings
+
+    @property
+    def slots(self) -> int:
+        """Slots the update works through, padding included."""
+        return int(self.idx.size)
+
+    def to_device(self) -> "LanesBin":
+        """The same bin with its arrays uploaded."""
+        return self._replace(
+            idx=jnp.asarray(self.idx), val=jnp.asarray(self.val),
+            cnt=jnp.asarray(self.cnt),
+            cols=None if self.cols is None else jnp.asarray(self.cols))
+
+
+class AccLayout(NamedTuple):
+    """Where each item's system lies in the lanes accumulators: the bins
+    of one R^T shard end to end, each padded to whole row blocks, so that
+    that shard's bins add into contiguous runs of columns.  Padding
+    columns count no rating and solve to 0."""
+
+    width: int           # accumulator columns
+    col_of: np.ndarray   # [n] int32 column of each item
+
+
+def _block(rows: int, block_rows: int) -> int:
+    """Rows per block of a bin of ``rows`` rows: ``block_rows`` (0: the
+    whole bin), no wider than the bin, rounded up to 128 lanes."""
+    wide = -(-rows // 128) * 128
+    return -(-min(block_rows or wide, wide) // 128) * 128
+
+
+def acc_layout(binned, block_rows: int) -> AccLayout:
+    """The :class:`AccLayout` of an R^T ``BinnedELL``'s bins."""
+    col_of = np.zeros(binned.m, np.int32)
+    width = 0
+    for b, rows in zip(binned.bins, binned.rows):
         if b.m == 0:
             continue
-        idx, val, cnt = ell_triplet(b)
-        Ab, Bb = partial_herm(x_batch, idx, val, cnt, cfg)
-        r = jnp.asarray(np.asarray(rows), jnp.int32)
-        A = A.at[r].add(Ab)
-        B = B.at[r].add(Bb)
-    return A, B
+        col_of[rows] = width + np.arange(b.m, dtype=np.int32)
+        block = _block(b.m, block_rows)
+        width += -(-b.m // block) * block
+    return AccLayout(width=width, col_of=col_of)
+
+
+def lanes_bins(binned, block_rows: int, layout: AccLayout) -> list:
+    """The nonempty bins of a batch-local R^T ``BinnedELL`` as
+    :class:`LanesBin` tuples, in blocks of ``block_rows`` rows, with their
+    columns in ``layout``: a run of columns where the bin's rows lie
+    there in order (every bin of the shard the layout was made from),
+    else one column per row."""
+    out = []
+    for b, rows in zip(binned.bins, binned.rows):
+        if b.m == 0:
+            continue
+        block = _block(b.m, block_rows)
+        pad = -(-b.m // block) * block - b.m
+        cols = layout.col_of[rows]
+        start = int(cols[0])
+        if not (np.all(np.diff(cols) == 1)
+                and start + b.m + pad <= layout.width):
+            cols = np.concatenate([cols, layout.width + np.arange(
+                pad, dtype=np.int32)]).astype(np.int32)
+            start = 0
+        else:
+            cols = None
+        out.append(LanesBin(
+            idx=np.ascontiguousarray(np.pad(b.idx, ((0, pad), (0, 0))).T,
+                                     np.int32),
+            val=np.ascontiguousarray(np.pad(b.val, ((0, pad), (0, 0))).T,
+                                     np.float32),
+            cnt=np.pad(b.cnt, (0, pad)).astype(np.int32), cols=cols,
+            start=start, block=block, rows=b.m, nnz=b.nnz))
+    return out
+
+
+def accumulate_bin(A, B, c, x_batch, lb: LanesBin, cfg: AlsConfig):
+    """Add one bin's partial Hermitians into the accumulators ``A [f*f,
+    w]``, ``B [f, w]``, ``c [w]`` (systems on the lanes), which it
+    consumes: one program per bin shape that walks the bin in row blocks
+    and adds each block's partials in place, into a run of columns or
+    scattered to the bin's ``cols``.  ``lb``'s arrays may be on the host
+    or the device.
+
+    ``A`` is flat because XLA scatters along the lanes by laying the
+    scattered array out with the columns major: ``[f*f, w]`` so laid out
+    is ``[w, f*f]`` in (8, 128) tiles (1.23 GB at 2.4M columns, f = 10),
+    where ``[f, f, w]`` would be ``[w, f, f]`` in tiles (19.7 GB).  A run
+    of columns is added in place, with no such copy."""
+    return _accumulate_bin(
+        A, B, c, x_batch, lb.idx, lb.val, lb.cnt, lb.cols, lb.start,
+        lam=cfg.lam, block=lb.block, mode=cfg.mode, tm=cfg.tm, tk=cfg.tk,
+        f_mult=cfg.f_mult)
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("lam", "block", "mode", "tm", "tk", "f_mult"),
+    donate_argnums=(0, 1, 2))
+def _accumulate_bin(A, B, c, x_batch, idx, val, cnt, cols, start, *, lam,
+                    block, mode, tm, tk, f_mult):
+    with jax.named_scope("als.accumulate"):
+        def one_block(i, acc):
+            A, B, c = acc
+            lo = i * block
+            ib, vb = (jax.lax.dynamic_slice_in_dim(a, lo, block, axis=1)
+                      for a in (idx, val))
+            cb = jax.lax.dynamic_slice_in_dim(cnt, lo, block)
+            Ab, Bb = kops.herm_lanes(x_batch, ib, vb, cb, lam, mode=mode,
+                                     tm=tm, tk=tk, f_mult=f_mult)
+            parts = (Ab, Bb, cb.astype(c.dtype))
+            if cols is None:      # a run of columns: in place, no scatter
+                at = start + lo
+
+                def add_run(a, p):
+                    ax = a.ndim - 1
+                    run = jax.lax.dynamic_slice_in_dim(a, at, block, ax)
+                    return jax.lax.dynamic_update_slice_in_dim(
+                        a, run + p, at, ax)
+
+                return tuple(map(add_run, (A, B, c), parts))
+            jb = jax.lax.dynamic_slice_in_dim(cols, lo, block)
+            kw = dict(unique_indices=True, mode="drop")
+            return (A.at[:, jb].add(Ab, **kw), B.at[:, jb].add(Bb, **kw),
+                    c.at[jb].add(parts[2], **kw))
+
+        return jax.lax.fori_loop(0, idx.shape[1] // block, one_block,
+                                 (A, B, c))
 
 
 def rmse_binned(x, theta, binned) -> float:

@@ -37,15 +37,35 @@ class PartitionPlan:
                 f"total={self.bytes_per_device / GiB:.3f}GiB fits={self.fits} [{t}]")
 
 
+def _round_up(x: int, mult: int) -> int:
+    return -(-x // mult) * mult
+
+
+def lanes_bytes(shape, dtype_bytes: int = 4) -> int:
+    """Device bytes of an array of ``shape`` in the TPU's (8, 128) tiles
+    of its last two axes (a 1-D array: whole 128-element rows)."""
+    if len(shape) == 1:
+        return _round_up(shape[0], 128) * dtype_bytes
+    *lead, r, c = shape
+    out = _round_up(r, 8) * _round_up(c, 128) * dtype_bytes
+    for d in lead:
+        out *= d
+    return out
+
+
 def streaming_acc_bytes(n: int, f: int, dtype_bytes: int = 4) -> int:
-    """Resident accumulate-Theta state of a streaming run: the A [n, f, f],
-    B [n, f], c [n] Hermitian accumulators (GLOBAL size — the planner
-    divides by p, each model shard owning only its theta rows' systems)."""
-    return n * (f * f + f + 1) * dtype_bytes
+    """Resident accumulate-Theta state of a streaming run as it is laid
+    out: ``A [f*f, n]``, ``B [f, n]``, ``c [n]``, the systems on the lanes
+    and every array in whole tiles, plus the copy of ``A`` with the items
+    major (``[n, f*f]`` in tiles) that a per-bin scatter-add works in, as
+    the batches after the first do (GLOBAL size — the planner divides by p, each model shard owning only
+    its theta rows' systems)."""
+    return sum(lanes_bytes(s, dtype_bytes)
+               for s in ((f * f, n), (f, n), (n,), (n, f * f)))
 
 
 def _bytes_per_device(m, n, nnz, f, p, q, fill=1.5, dtype_bytes=4, eps=512 << 20,
-                      buffers=1, acc_bytes=0):
+                      buffers=1, acc_bytes=0, block_rows=0):
     terms = {
         "X_batch": m * f * dtype_bytes // q,
         "Theta_shard": n * f * dtype_bytes // p,
@@ -56,6 +76,14 @@ def _bytes_per_device(m, n, nnz, f, p, q, fill=1.5, dtype_bytes=4, eps=512 << 20
         "B_batch": m * f * dtype_bytes // q,
         "eps": eps,
     }
+    if block_rows:
+        # Hermitians formed one row block at a time, in whole tiles: the
+        # X half's [rows, f, f] or the Theta half's [f, f, rows], the larger
+        rows = min(block_rows, -(-m // q))
+        terms["A_batch"] = max(lanes_bytes((rows, f, f), dtype_bytes),
+                               lanes_bytes((f, f, rows), dtype_bytes))
+        terms["B_batch"] = max(lanes_bytes((rows, f), dtype_bytes),
+                               lanes_bytes((f, rows), dtype_bytes))
     if acc_bytes:
         # streaming accumulate-Theta residents, p-sharded like Theta: each
         # model shard holds only its own theta rows' accumulated systems
@@ -122,6 +150,7 @@ def plan_for(
     degrees=None,
     tune_cache=None,
     k_multiple: int = 8,
+    block_rows: int = 0,
 ) -> PartitionPlan:
     """Cost a *given* (p, q) choice — the forced-plan entry point.
 
@@ -133,7 +162,10 @@ def plan_for(
     ``depth + 1`` (§4.4 preload).  ``acc_bytes`` prices the streaming
     accumulate-Theta residents (``streaming_acc_bytes(n, f)``) as their own
     p-sharded term — each model shard owns 1/p of the accumulated systems —
-    instead of overloading the flat ``eps`` headroom.
+    instead of overloading the flat ``eps`` headroom.  ``block_rows`` (the
+    solver's ``batch_rows``) prices the Hermitian working set as one row
+    block of systems, as both halves form them, rather than a whole
+    batch of them.
 
     ``bin_fills`` prices a degree-binned layout: per-bin ``(padded_slots,
     nnz)`` pairs (e.g. ``RatingStore.bin_fill_pairs()``) whose aggregate
@@ -164,7 +196,8 @@ def plan_for(
         true_nnz = sum(int(z) for _, z in bin_fills)
         fill = slots / max(true_nnz, 1)
     total, terms = _bytes_per_device(
-        m, n, nnz, f, p, q, fill, dtype_bytes, eps, buffers, acc_bytes)
+        m, n, nnz, f, p, q, fill, dtype_bytes, eps, buffers, acc_bytes,
+        block_rows)
     return PartitionPlan(p, q, total, terms, total < hbm_bytes, -(-q // n_data))
 
 

@@ -1,7 +1,10 @@
 """Jitted public wrappers around the Pallas kernels (+ shape plumbing).
 
 ``fused_herm`` / ``batch_solve`` are the two ops the rest of the framework
-calls.  They handle:
+calls; ``herm_lanes`` / ``batch_solve_lanes`` are the same two ops with the
+systems on the lanes (``[f*f, m]`` or ``[f, f, m]``, and ``[f, m]``), the
+layouts of the accumulate-Theta half and of the solve kernel.  They
+handle:
 
 - the theta gather (XLA DMA-gather == the paper's texture-cached read),
 - padding m / K / F up to tile multiples (F to the MXU lane width) for
@@ -107,6 +110,54 @@ def fused_herm(
         return A[:m, :f, :f], B[:m, :f]
 
 
+#: widest f whose lanes Hermitian runs on the vector unit: an f x f outer
+#: product fills at most (f / 128)^2 of the MXU, and HIGHEST pays six bf16
+#: passes for it, so at small f the rows go on the lanes and each entry is
+#: one exact f32 multiply-add
+VPU_HERM_MAX_F = 32
+
+
+def herm_lanes(
+    theta: jax.Array,   # [n, f] feature matrix (the fixed side)
+    idx: jax.Array,     # [K, m] padded column indices, slots x rows
+    val: jax.Array,     # [K, m] padded rating values
+    cnt: jax.Array,     # [m]    true nnz per row
+    lam: float,
+    *,
+    mode: Mode = "ref",
+    tm: int = 8,
+    tk: int = 128,
+    f_mult: int = 128,
+) -> tuple[jax.Array, jax.Array]:
+    """Partial ``(A [f*f, m], B [f, m])`` of paper eq. (3), rows on the
+    lanes: ``A[:, u]`` is row u's ``sum_k theta_k theta_k^T + lam n_u I``,
+    flattened, with no guard for empty rows (a partial of a sum over
+    batches).
+
+    The arithmetic follows f: up to :data:`VPU_HERM_MAX_F` (in mode
+    ``"ref"``) each slot's outer product is a broadcast multiply-add over
+    ``[f, f, m]``; wider, ``fused_herm``'s product (the MXU, or the Pallas
+    kernel of ``mode``) followed by the transpose into this layout.  Both
+    are float32 throughout.
+    """
+    K, m = idx.shape
+    f = theta.shape[1]
+    if mode != "ref" or f > VPU_HERM_MAX_F:
+        A, B = fused_herm(theta, idx.T, val.T, cnt, lam, mode=mode, tm=tm,
+                          tk=tk, f_mult=f_mult, diag_fallback=False)
+        return A.reshape(m, f * f).T, B.T
+    with jax.named_scope("als.herm"):
+        mask = kref.mask_from_cnt(cnt, K, theta.dtype).T       # [K, m]
+        with jax.named_scope("als.gather"):
+            g = jnp.transpose(jnp.take(theta, idx, axis=0), (0, 2, 1))
+        gm = g * mask[:, None, :]                               # [K, f, m]
+        A = jnp.sum(gm[:, :, None, :] * g[:, None, :, :], axis=0)
+        diag = lam * cnt.astype(jnp.float32)
+        A = A + jnp.eye(f, dtype=A.dtype)[:, :, None] * diag[None, None, :]
+        B = jnp.sum((val * mask)[:, None, :] * g, axis=0)
+        return A.reshape(f * f, m), B
+
+
 def _solve_kernel(mode: Mode) -> bool:
     """Whether ``batch_solve`` runs the Pallas kernel (else the oracle)."""
     return mode == "kernel_interpret" or compat.on_tpu()
@@ -121,6 +172,33 @@ def solve_width(m: int, f: int, mode: Mode = "ref") -> int:
     return _round_up(m, lanes_per_step(f, m))
 
 
+def _solve_lanes(At: jax.Array, Bt: jax.Array, mode: Mode) -> jax.Array:
+    f, _, m = At.shape
+    if not _solve_kernel(mode):
+        return kref.batch_solve_ref(jnp.transpose(At, (2, 1, 0)), Bt.T).T
+    mp = solve_width(m, f, mode)
+    if mp != m:       # padded systems are I x = 0
+        eye = jnp.broadcast_to(jnp.eye(f, dtype=At.dtype)[:, :, None],
+                               (f, f, mp - m))
+        At = jnp.concatenate([At, eye], axis=2)
+        Bt = _pad_axis(Bt, 1, mp)
+    x = batch_solve_pallas(At, Bt, interpret=(mode == "kernel_interpret"))
+    return x[:, :m]
+
+
+def batch_solve_lanes(
+    At: jax.Array,  # [f, f, m] column, row, system
+    Bt: jax.Array,  # [f, m]    row, system
+    *,
+    mode: Mode = "ref",
+) -> jax.Array:
+    """``[f, m]`` solutions of SPD systems held with the systems on the
+    lanes, the layout the kernel computes in; see the module doc for which
+    implementation runs.  Traced inside its callers' programs."""
+    with jax.named_scope("als.solve"):
+        return _solve_lanes(At, Bt, mode)
+
+
 @functools.partial(jax.jit, static_argnames=("mode",))
 def batch_solve(
     A: jax.Array,  # [m, f, f]
@@ -128,24 +206,10 @@ def batch_solve(
     *,
     mode: Mode = "ref",
 ) -> jax.Array:
-    """x_u = A_u^{-1} B_u (batched Cholesky solve); see the module doc for
-    which implementation runs."""
+    """x_u = A_u^{-1} B_u (batched Cholesky solve): the lanes solve between
+    the transposes into and out of its layout."""
     with jax.named_scope("als.solve"):
-        if not _solve_kernel(mode):
-            return kref.batch_solve_ref(A, B)
-        m, f, _ = A.shape
-        mp = solve_width(m, f, mode)
-        # column-row-system layout; padded systems are I x = 0
-        At = jnp.transpose(A, (2, 1, 0))
-        Bt = B.T
-        if mp != m:
-            eye = jnp.broadcast_to(jnp.eye(f, dtype=A.dtype)[:, :, None],
-                                   (f, f, mp - m))
-            At = jnp.concatenate([At, eye], axis=2)
-            Bt = _pad_axis(Bt, 1, mp)
-        x = batch_solve_pallas(At, Bt,
-                               interpret=(mode == "kernel_interpret"))
-        return x.T[:m]
+        return _solve_lanes(jnp.transpose(A, (2, 1, 0)), B.T, mode).T
 
 
 def als_update_factor(

@@ -6,10 +6,13 @@ Per iteration the driver runs the two halves of the schedule:
   buffered host->device through ``data.prefetch.Prefetcher`` while the
   current wave solves its X rows (``core.als.update_rows``); solved slices
   are written straight back to the host ``FactorStore``.
-- **accumulate-Theta**: A/B Hermitian accumulators resident; each wave
-  streams its batches' R^T column shards together with the freshly solved X
-  slices (``core.als.partial_herm``), and after the last wave the
-  accumulated systems are solved (``core.als.solve_accumulated``).
+- **accumulate-Theta**: A/B Hermitian accumulators resident, with the
+  systems on the lanes (``A [f*f, n]``, ``B [f, n]``, the solve kernel's
+  layout but flat); each wave streams its batches' R^T column shards
+  together with the freshly solved X slices, each bin added by one
+  donated program (``core.als.accumulate_bin``; ``core.als.partial_herm``
+  on the uniform layout), and after the last wave the accumulated
+  systems are solved as they lie (``core.als.solve_accumulated``).
 
 Every wave completion checkpoints the full resumable state (factors +
 accumulators + global step) through ``checkpoint.CheckpointManager``, so a
@@ -72,32 +75,27 @@ __all__ = ["MemoryMeter", "SimulatedFailure", "StreamTelemetry",
            "run_streaming_als"]
 
 
-def _binned_cnt_rows(binned) -> np.ndarray:
-    """Full-length [m] float32 per-row counts of a BinnedELL (bins hold
-    disjoint row subsets, so plain assignment reassembles the vector)."""
-    out = np.zeros(binned.m, np.float32)
-    for b, r in zip(binned.bins, binned.rows):
-        if b.m:
-            out[r] = b.cnt
-    return out
-
-
 def _zeros_ckpt_tree(m_pad: int, n: int, f: int, n_dev: int = 0) -> dict:
     """Checkpoint structure.  The acc leaves are committed EMPTY (zero rows)
     by solve-X-half saves — restore never reads them there, and shipping
     full-sized zeros would dominate the per-wave checkpoint I/O — and are
     replaced with the live accumulators by mid-accumulate-half saves: the
-    serial f32 partial sums, or, on the mesh path (``n_dev`` > 0), the
+    serial f32 partial sums (systems on the lanes: ``A [f*f, n]``,
+    ``B [f, n]``, ``c [n]``), or, on the mesh path (``n_dev`` > 0), the
     PER-DATA-SHARD float64 partials so a resume replays the topology-aware
     reduction bit-exactly from the same summands.
     """
     acc_dt = np.float64 if n_dev else np.float32
     lead = (n_dev,) if n_dev else ()
+    # the layout each path resumes from: [n, f, f] per data shard on the
+    # mesh, the systems on the lanes ([f*f, n]) on one device
+    a0, b0 = (lead + (0, f, f), lead + (0, f)) if n_dev else \
+        ((f * f, 0), (f, 0))
     return {
         "x": np.zeros((m_pad, f), np.float32),
         "theta": np.zeros((n, f), np.float32),
-        "a_acc": np.zeros(lead + (0, f, f), acc_dt),
-        "b_acc": np.zeros(lead + (0, f), acc_dt),
+        "a_acc": np.zeros(a0, acc_dt),
+        "b_acc": np.zeros(b0, acc_dt),
         "c_acc": np.zeros(lead + (0,), acc_dt),
     }
 
@@ -343,17 +341,33 @@ def run_streaming_als(
     # accumulate-Theta half: stream R^T shards + X slices, accumulate,
     # solve after the last wave.
     # ------------------------------------------------------------------
+    def _acc_init(acc0, width: int):
+        """The single-device accumulators, systems on the lanes in
+        ``width`` columns: zeros, or the ones a mid-half checkpoint
+        restored."""
+        if acc0 is not None:
+            return tuple(jnp.asarray(a, jnp.float32) for a in acc0)
+        return (jnp.zeros((f * f, width), jnp.float32),
+                jnp.zeros((f, width), jnp.float32),
+                jnp.zeros((width,), jnp.float32))
+
+    def _solve_theta(A, B, c, col_of=None):
+        """Solve the accumulated systems and write Theta back; ``col_of``
+        is each item's column when they are not in item order."""
+        meter.alloc("theta_out", n * f * 4)
+        with phase("als.solve_accumulated", cat="solve_accumulated",
+                   tracer=tracer, registry=reg, systems=A.shape[-1], f=f):
+            theta = solve_acc_fn(A, B, c)
+            if col_of is not None:
+                theta = jnp.take(theta, col_of, axis=0)
+            factors.write_slice("theta", 0, n, np.asarray(theta))
+        _count_solve(n, kops.solve_width(A.shape[-1], f, cfg.mode))
+        meter.free("theta_out")
+
     def _theta_half(it: int, first_wave: int, acc0=None):
         acc_bytes = n * (f * f + f + 1) * 4
         meter.alloc("acc", acc_bytes)
-        if acc0 is not None:
-            A = jnp.asarray(acc0[0], jnp.float32)
-            B = jnp.asarray(acc0[1], jnp.float32)
-            c = jnp.asarray(acc0[2], jnp.float32)
-        else:
-            A = jnp.zeros((n, f, f), jnp.float32)
-            B = jnp.zeros((n, f), jnp.float32)
-            c = jnp.zeros((n,), jnp.float32)
+        A, B, c = _acc_init(acc0, n)
 
         def gen():
             for wave in sched.waves[first_wave:]:
@@ -393,12 +407,7 @@ def run_streaming_als(
                             c = c + cnt.astype(jnp.float32)
                         meter.free(f"twave{wave.index}")
                         if last:
-                            meter.alloc("theta_out", n * f * 4)
-                            factors.write_slice(
-                                "theta", 0, n,
-                                np.asarray(solve_acc_fn(A, B, c)))
-                            _count_solve(n, als_mod.solved_systems(n, cfg))
-                            meter.free("theta_out")
+                            _solve_theta(A, B, c)
                     reg.counter("waves_run").inc()
                     reg.counter("batches_loaded").inc(len(payload))
                     reg.counter("bytes_streamed").inc(nb)
@@ -461,19 +470,16 @@ def run_streaming_als(
     def _theta_half_binned(it: int, first_wave: int, acc0=None):
         acc_bytes = n * (f * f + f + 1) * 4
         meter.alloc("acc", acc_bytes)
-        if acc0 is not None:
-            A = jnp.asarray(acc0[0], jnp.float32)
-            B = jnp.asarray(acc0[1], jnp.float32)
-            c = jnp.asarray(acc0[2], jnp.float32)
-        else:
-            A = jnp.zeros((n, f, f), jnp.float32)
-            B = jnp.zeros((n, f), jnp.float32)
-            c = jnp.zeros((n,), jnp.float32)
+        # the first R^T shard's bins lie end to end in the accumulators,
+        # so they add into runs of columns; the other shards scatter
+        layout = ratings.theta_acc_layout(cfg.batch_rows)
+        A, B, c = _acc_init(acc0, layout.width)
 
         def gen():
             for wave in sched.waves[first_wave:]:
                 payload = [
                     (b, ratings.theta_batch_binned(b.index),
+                     ratings.theta_batch_lanes(b.index, cfg.batch_rows),
                      factors.read_slice("x", b.row_start, b.row_stop))
                     for b in wave.batches]
                 yield wave, payload
@@ -481,15 +487,16 @@ def run_streaming_als(
         def put(item):
             wave, payload = item
             nb = sum(binned_nbytes(bell) + x.nbytes
-                     for _, bell, x in payload)
+                     for _, bell, _lb, x in payload)
             meter.alloc(f"twave{wave.index}", nb // len(payload))
-            slots = sum(int(bell.padded_slots) for _, bell, _x in payload)
-            nz = sum(int(bell.nnz) for _, bell, _x in payload)
+            slots = sum(int(bell.padded_slots) for _, bell, _lb, _x in payload)
+            nz = sum(int(bell.nnz) for _, bell, _lb, _x in payload)
             reg.counter("padded_slots").inc(slots)
             reg.counter("nnz_streamed").inc(nz)
             reg.counter("t_padded_slots").inc(slots)
             reg.counter("t_nnz_streamed").inc(nz)
-            dev = [(b, bell, jnp.asarray(x)) for b, bell, x in payload]
+            dev = [([lb.to_device() for lb in lbs], jnp.asarray(x))
+                   for _b, _bell, lbs, x in payload]
             return wave, dev, nb
 
         try:
@@ -500,20 +507,21 @@ def run_streaming_als(
                     with phase("als.wave_theta", cat="solve", tracer=tracer,
                                registry=reg, wave=wave.index,
                                iteration=it + 1, bytes=nb, bins=n_bins):
-                        for _, bell, x_dev in payload:
-                            Aj, Bj = als_mod.partial_herm_binned(
-                                x_dev, bell, cfg)
-                            A = A + Aj
-                            B = B + Bj
-                            c = c + jnp.asarray(_binned_cnt_rows(bell))
+                        for lbs, x_dev in payload:
+                            for k, lb in enumerate(lbs):
+                                with phase("als.accumulate",
+                                           cat="accumulate", tracer=tracer,
+                                           registry=reg, bin=k, rows=lb.rows,
+                                           slots=lb.slots, nnz=lb.nnz,
+                                           x_rows=x_dev.shape[0], n=n, f=f):
+                                    A, B, c = als_mod.accumulate_bin(
+                                        A, B, c, x_dev, lb, cfg)
+                                reg.counter("accumulate_slots").inc(lb.slots)
+                                reg.counter("accumulate_ratings").inc(lb.nnz)
                         meter.free(f"twave{wave.index}")
                         if last:
-                            meter.alloc("theta_out", n * f * 4)
-                            factors.write_slice(
-                                "theta", 0, n,
-                                np.asarray(solve_acc_fn(A, B, c)))
-                            _count_solve(n, als_mod.solved_systems(n, cfg))
-                            meter.free("theta_out")
+                            _solve_theta(A, B, c,
+                                         jnp.asarray(layout.col_of))
                     reg.counter("waves_run").inc()
                     reg.counter("batches_loaded").inc(len(payload))
                     reg.counter("bytes_streamed").inc(nb)
@@ -759,10 +767,13 @@ def run_streaming_als(
         npp = n // p
         for k in range(p):
             lo, hi = k * npp, (k + 1) * npp
-            th_k = solve_acc_fn(jnp.asarray(A[lo:hi], jnp.float32),
-                                jnp.asarray(B[lo:hi], jnp.float32),
-                                jnp.asarray(c[lo:hi], jnp.float32))
-            _count_solve(npp, als_mod.solved_systems(npp, cfg))
+            # the reduced [n, f, f] partials, moved to the lanes solve's
+            # layout
+            th_k = solve_acc_fn(
+                jnp.asarray(A[lo:hi].reshape(npp, f * f).T, jnp.float32),
+                jnp.asarray(B[lo:hi].T, jnp.float32),
+                jnp.asarray(c[lo:hi], jnp.float32))
+            _count_solve(npp, kops.solve_width(npp, f, cfg.mode))
             factors.write_shard("theta", k, p, np.asarray(th_k))
         meter.free("theta_out")
 

@@ -137,6 +137,7 @@ class RatingStore:
     def __init__(self, r: PaddedELL, q: int, k_multiple: int = 8, p: int = 1,
                  n_bins=1, tune_cache=None):
         self.tune = None
+        self._lanes = {}                   # theta_batch_lanes, made once
         if n_bins == "auto":
             from repro.core import autotune as _autotune
             res = _autotune.tune_als_layout(
@@ -345,6 +346,31 @@ class RatingStore:
             "RatingStore was built with n_bins=1; pass n_bins to bin shards"
         assert 0 <= j < self.q, (j, self.q)
         return self.rt_binned[j]
+
+    def theta_acc_layout(self, block_rows: int):
+        """Where each item's system lies in the accumulate-Theta half's
+        lanes accumulators: ``core.als.acc_layout`` of batch 0's bins in
+        row blocks of ``block_rows``, made once per store."""
+        key = ("layout", block_rows)
+        if key not in self._lanes:
+            from repro.core.als import acc_layout
+            self._lanes[key] = acc_layout(self.theta_batch_binned(0),
+                                          block_rows)
+        return self._lanes[key]
+
+    def theta_batch_lanes(self, j: int, block_rows: int) -> list:
+        """The nonempty bins of batch ``j``'s R^T shard in the accumulate
+        update's layout (``core.als.lanes_bins``: slots on the sublanes,
+        items on the lanes, row blocks of ``block_rows``), their columns
+        in :meth:`theta_acc_layout`.  Host arrays, made once per store:
+        the ratings never change between iterations."""
+        key = (j, block_rows)
+        if key not in self._lanes:
+            from repro.core.als import lanes_bins
+            self._lanes[key] = lanes_bins(self.theta_batch_binned(j),
+                                          block_rows,
+                                          self.theta_acc_layout(block_rows))
+        return self._lanes[key]
 
     def theta_wave_stacked(self, batch_indices) -> list:
         """Per-bin stacked theta-half payloads of one mesh wave: for each

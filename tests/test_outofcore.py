@@ -157,13 +157,14 @@ def test_streaming_ragged_last_wave():
     assert tel.peak_bytes <= tel.capacity_bytes
 
 
-@pytest.mark.parametrize("mode,padded", [("ref", 192),
-                                         ("kernel_interpret", 768)])
+@pytest.mark.parametrize("mode,padded", [("ref", 168),
+                                         ("kernel_interpret", 640)])
 def test_streaming_counts_solved_systems(mode, padded):
     """``solve_systems`` counts the rows solved (96 users + 40 items);
-    ``solve_padded_systems`` the systems factored: two waves of 48 rows
-    and the 40 items, each in row blocks of 32 (64 systems), and on the
-    kernel path each block widened to the kernel's 128 lanes."""
+    ``solve_padded_systems`` the systems factored: two waves of 48 rows,
+    each in row blocks of 32 (64 systems), and the 40 items' accumulated
+    systems in one solve (40), and on the kernel path each block and the
+    items widened to the kernel's 128 lanes."""
     from repro.obs.metrics import MetricsRegistry
 
     r, _, _, _ = _problem()

@@ -8,6 +8,9 @@ non-contracting dimension, more scoped VMEM than a kernel may use — before
 any chip time is spent.  Widths are the real ones: F = 128 lanes, f = 100
 through ``ops.als_update_factor`` for the padding path, and the batched
 solve at its unpadded f = 100 and f = 10 through ``ops.batch_solve``.
+The accumulate-Theta programs compile at the SparkALS share's real sizes
+(n = 2.4M items, f = 10), and their arguments and temporaries must fit
+one chip's 16 GB: the out-of-memory failure of that cell, caught here.
 
 The topology is described inside a module-scoped fixture, never at import:
 only one process at a time may load the TPU library, and every test worker
@@ -19,6 +22,8 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro import compat
+from repro.core import als as als_mod
+from repro.core.partition import lanes_bytes
 from repro.kernels import ops
 from repro.kernels.hermitian import fused_herm_pallas, herm_hbm_accum
 from repro.kernels.sgd_update import sgd_tile_pallas
@@ -63,11 +68,11 @@ def tpu_compile(monkeypatch, one_chip):
     # after the test
     jax.clear_caches()
 
-    def compile_(fn, *shapes):
+    def compile_(fn, *shapes, kernel=True):
         args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
                 for s, d in shapes]
         compiled = jax.jit(fn).lower(*args).compile()
-        assert "tpu_custom_call" in compiled.as_text()
+        assert kernel == ("tpu_custom_call" in compiled.as_text())
         return compiled
 
     try:
@@ -116,3 +121,50 @@ def test_als_update_factor_kernel_mode_compiles_at_f100(tpu_compile):
     tpu_compile(lambda th, i, v, c: ops.als_update_factor(
                     th, i, v, c, 0.05, mode="kernel"),
                 ((n, f), f32), ((m, K), i32), ((m, K), f32), ((m,), i32))
+
+
+HBM = 16e9
+#: the SparkALS share (chipbench/configs/sparkals_f10.json)
+SPARK_M, SPARK_N = 1_885_714, 2_400_000
+
+
+def _fits_one_chip(compiled):
+    mem = compiled.memory_analysis()
+    used = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    assert used < HBM, (used, mem)
+    return used
+
+
+@pytest.mark.parametrize("scatter", [False, True], ids=["run", "scatter"])
+def test_accumulate_bin_fits_v5e(tpu_compile, scatter):
+    """One bin's update at n = 2.4M, f = 10, K = 8 in 16,384-row blocks:
+    the gather and the partials are one block's.  Into a run of columns
+    the accumulators are updated in place, in their own layout (no relaid
+    copy of them); scattered, the one copy is the columns-major
+    ``[n, f*f]`` one the scatter-add works in (1.23 GB; ``[f, f, n]``
+    would be relaid as ``[n, f, f]``, 19.7 GB)."""
+    f, K, block = 10, 8, 16384
+    rows = 139 * block                   # the first item bin, ~2.27M rows
+    col = (((rows,), i32),) if scatter else (((), i32),)
+    compiled = tpu_compile(
+        lambda A, B, c, x, i, v, k, j: als_mod._accumulate_bin(
+            A, B, c, x, i, v, k, j if scatter else None,
+            0 if scatter else j, lam=0.05, block=block, mode="ref", tm=8,
+            tk=128, f_mult=128),
+        ((f * f, SPARK_N), f32), ((f, SPARK_N), f32), ((SPARK_N,), f32),
+        ((SPARK_M, f), f32), ((K, rows), i32), ((K, rows), f32),
+        ((rows,), i32), *col, kernel=False)
+    copy = lanes_bytes((SPARK_N, f * f)) if scatter else 0
+    assert compiled.memory_analysis().temp_size_in_bytes < copy + (256 << 20)
+    assert _fits_one_chip(compiled) < 3e9
+
+
+@pytest.mark.parametrize("f,n", [(10, SPARK_N), (100, 17770)])
+def test_solve_accumulated_fits_v5e(tpu_compile, f, n):
+    """The lanes solve of an accumulator as it lies, at the SparkALS and
+    Netflix item counts: the kernel, with no Cholesky custom call."""
+    compiled = tpu_compile(
+        lambda A, B, c: als_mod._solve_accumulated(A, B, c, mode="ref"),
+        ((f * f, n), f32), ((f, n), f32), ((n,), f32))
+    assert "Cholesky" not in compiled.as_text()
+    _fits_one_chip(compiled)
