@@ -146,9 +146,11 @@ def placement(store, sched, hbm: int) -> None:
     from repro.core.partition import streaming_acc_bytes
 
     F_pad = -(-F // 128) * 128
-    x_bins = [store.x_slice_binned(w.row_start, w.row_stop)
-              for w in sched.waves]
-    xr, xk = _largest_bin(x_bins)
+    cuts = [(w.row_start, w.row_stop, BATCH_ROWS) for w in sched.waves]
+    xw = max(store.x_wave_layout(*c).width for c in cuts)
+    xl = max((lb for c in cuts for lb in store.x_wave_lanes(*c)),
+             key=lambda lb: lb.slots)
+    xk, xr_pad = xl.idx.shape
     tr, tk = _largest_bin(store.rt_binned)
     tb = min(BATCH_ROWS, -(-tr // 128) * 128)     # one R^T row block
     tr_pad = -(-tr // tb) * tb
@@ -164,11 +166,18 @@ def placement(store, sched, hbm: int) -> None:
         cfg = als_mod.AlsConfig(f=F, lam=LAM, mode=mode,
                                 batch_rows=BATCH_ROWS)
         progs = {
-            f"solve-X bin [{xr}, {xk}]": (
-                lambda th, i, v, c, cfg=cfg: als_mod.update_rows(
-                    th, i, v, c, cfg),
-                (S((N, F), f32), S((xr, xk), i32), S((xr, xk), f32),
-                 S((xr,), i32))),
+            f"solve-X bin [{xk}, {xr_pad}] in blocks of {xl.block}": (
+                lambda a, b, c, th, i, v, k, cfg=cfg: als_mod.accumulate_bin(
+                    a, b, c, th, als_mod.LanesBin(
+                        idx=i, val=v, cnt=k, cols=None, start=0,
+                        block=xl.block, rows=xl.rows, nnz=0), cfg),
+                (S((F * F, xw), f32), S((F, xw), f32), S((xw,), f32),
+                 S((N, F), f32), S((xk, xr_pad), i32),
+                 S((xk, xr_pad), f32), S((xr_pad,), i32))),
+            f"X solve [{xw}]": (
+                lambda a, b, c, cfg=cfg: als_mod.solve_accumulated(
+                    a, b, c, cfg),
+                (S((F * F, xw), f32), S((F, xw), f32), S((xw,), f32))),
             f"R^T bin [{tk}, {tr_pad}] in blocks of {tb}": (
                 lambda a, b, c, x, i, v, k, cfg=cfg: als_mod.accumulate_bin(
                     a, b, c, x, als_mod.LanesBin(

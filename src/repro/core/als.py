@@ -8,6 +8,7 @@ is a ``lax.map`` over row blocks so memory stays bounded at m_b f^2.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 from typing import NamedTuple, Optional
@@ -114,16 +115,28 @@ def solve_accumulated(A, B, cnt_total, cfg: AlsConfig) -> jax.Array:
     ``diag_fallback``) and hands the systems to the lanes solve as
     ``[f, f, n]``, with no transpose, in one program per shape.
     """
-    return _solve_accumulated(A, B, cnt_total, mode=cfg.mode)
+    return _solve_accumulated(A, B, cnt_total, mode=cfg.mode,
+                              kernels=_kernels())
 
 
-@functools.partial(jax.jit, static_argnames=("mode",))
-def _solve_accumulated(A, B, cnt_total, *, mode):
+@functools.partial(jax.jit, static_argnames=("mode", "kernels"))
+def _solve_accumulated(A, B, cnt_total, *, mode, kernels=()):
     f, n = B.shape
     empty = (cnt_total <= 0).astype(A.dtype)
     A = A.reshape(f, f, n)
     A = A + jnp.eye(f, dtype=A.dtype)[:, :, None] * empty[None, None, :]
-    return kops.batch_solve_lanes(A, B, mode=mode).T
+    return kops.batch_solve(A, B, mode=mode, lanes=True).T
+
+
+def _kernels() -> tuple:
+    """The ``kernels.ops`` entry points that the lanes programs trace, as
+    they stand when a program is called.  A static argument of each
+    program (its cache key, unused in the body): an entry point replaced
+    since the last trace, as a planted fault or another implementation
+    replaces it, is traced afresh instead of served from JAX's cache, as
+    the eager per-bin calls of :func:`update_factor_binned` see it."""
+    return (kops.fused_herm, kops.herm_lanes, kops.batch_solve,
+            kops.batch_solve_lanes)
 
 
 def solved_systems(m: int, cfg: AlsConfig) -> int:
@@ -196,18 +209,11 @@ def update_factor_binned(fixed, binned, cfg: AlsConfig) -> jax.Array:
     return out
 
 
-def update_rows_binned(fixed, binned, cfg: AlsConfig) -> jax.Array:
-    """Binned per-slice update (out-of-core wave driver, solve side): the
-    slice arrives as a BinnedELL with slice-local row indices; results come
-    back in slice row order, exactly like :func:`update_rows` on the
-    uniform layout."""
-    return update_factor_binned(fixed, binned, cfg)
-
-
 class LanesBin(NamedTuple):
-    """One degree bin of an R^T shard in the accumulate update's layout:
-    slots on the sublanes and rows on the lanes, rows padded to whole row
-    blocks with ``cnt = 0`` (zero partials)."""
+    """One degree bin of a binned shard (an R^T batch, or an R wave) in
+    the accumulate update's layout: slots on the sublanes and rows on the
+    lanes, rows padded to whole row blocks with ``cnt = 0`` (zero
+    partials)."""
 
     idx: np.ndarray    # [K, rows_pad] int32, batch-local X rows
     val: np.ndarray    # [K, rows_pad] float32
@@ -235,13 +241,13 @@ class LanesBin(NamedTuple):
 
 
 class AccLayout(NamedTuple):
-    """Where each item's system lies in the lanes accumulators: the bins
-    of one R^T shard end to end, each padded to whole row blocks, so that
+    """Where each row's system lies in the lanes accumulators: the bins
+    of one shard end to end, each padded to whole row blocks, so that
     that shard's bins add into contiguous runs of columns.  Padding
     columns count no rating and solve to 0."""
 
     width: int           # accumulator columns
-    col_of: np.ndarray   # [n] int32 column of each item
+    col_of: np.ndarray   # [n] int32 column of each row
 
 
 def _block(rows: int, block_rows: int) -> int:
@@ -252,7 +258,7 @@ def _block(rows: int, block_rows: int) -> int:
 
 
 def acc_layout(binned, block_rows: int) -> AccLayout:
-    """The :class:`AccLayout` of an R^T ``BinnedELL``'s bins."""
+    """The :class:`AccLayout` of a ``BinnedELL``'s bins."""
     col_of = np.zeros(binned.m, np.int32)
     width = 0
     for b, rows in zip(binned.bins, binned.rows):
@@ -265,11 +271,11 @@ def acc_layout(binned, block_rows: int) -> AccLayout:
 
 
 def lanes_bins(binned, block_rows: int, layout: AccLayout) -> list:
-    """The nonempty bins of a batch-local R^T ``BinnedELL`` as
-    :class:`LanesBin` tuples, in blocks of ``block_rows`` rows, with their
-    columns in ``layout``: a run of columns where the bin's rows lie
-    there in order (every bin of the shard the layout was made from),
-    else one column per row."""
+    """The nonempty bins of a ``BinnedELL`` (a batch-local R^T shard, or
+    a wave-local R slice) as :class:`LanesBin` tuples, in blocks of
+    ``block_rows`` rows, with their columns in ``layout``: a run of
+    columns where the bin's rows lie there in order (every bin of the
+    shard the layout was made from), else one column per row."""
     out = []
     for b, rows in zip(binned.bins, binned.rows):
         if b.m == 0:
@@ -311,15 +317,16 @@ def accumulate_bin(A, B, c, x_batch, lb: LanesBin, cfg: AlsConfig):
     return _accumulate_bin(
         A, B, c, x_batch, lb.idx, lb.val, lb.cnt, lb.cols, lb.start,
         lam=cfg.lam, block=lb.block, mode=cfg.mode, tm=cfg.tm, tk=cfg.tk,
-        f_mult=cfg.f_mult)
+        f_mult=cfg.f_mult, kernels=_kernels())
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=("lam", "block", "mode", "tm", "tk", "f_mult"),
+    static_argnames=("lam", "block", "mode", "tm", "tk", "f_mult",
+                     "kernels"),
     donate_argnums=(0, 1, 2))
 def _accumulate_bin(A, B, c, x_batch, idx, val, cnt, cols, start, *, lam,
-                    block, mode, tm, tk, f_mult):
+                    block, mode, tm, tk, f_mult, kernels=()):
     with jax.named_scope("als.accumulate"):
         def one_block(i, acc):
             A, B, c = acc
@@ -347,6 +354,27 @@ def _accumulate_bin(A, B, c, x_batch, idx, val, cnt, cols, start, *, lam,
 
         return jax.lax.fori_loop(0, idx.shape[1] // block, one_block,
                                  (A, B, c))
+
+
+def solve_rows_lanes(fixed, lbs, col_of, width: int, cfg: AlsConfig,
+                     each_bin=None) -> jax.Array:
+    """Solve the rows of one slice given the ``fixed`` other factor with
+    the accumulate half's programs: the slice's :class:`LanesBin` bins
+    ``lbs`` (``lanes_bins`` in the slice's own ``acc_layout``, ``width``
+    columns) each added by :func:`accumulate_bin` into zero accumulators,
+    the systems solved once by :func:`solve_accumulated`, and the
+    solutions taken back into row order through the layout's ``col_of``.
+    Returns ``[rows, f]``; a row with no rating solves to 0.
+    ``each_bin(k, lb)``, where given, is a context manager entered around
+    bin ``k``'s update."""
+    f = cfg.f
+    acc = (jnp.zeros((f * f, width), jnp.float32),
+           jnp.zeros((f, width), jnp.float32),
+           jnp.zeros((width,), jnp.float32))
+    for k, lb in enumerate(lbs):
+        with each_bin(k, lb) if each_bin else contextlib.nullcontext():
+            acc = accumulate_bin(*acc, fixed, lb, cfg)
+    return jnp.take(solve_accumulated(*acc, cfg), col_of, axis=0)
 
 
 def rmse_binned(x, theta, binned) -> float:

@@ -434,9 +434,12 @@ def tune_als_layout(r, q: int, *, p: int = 1, f: int = 16,
 def _measure_als_candidate(r, q: int, cfg: LayoutConfig, *, f: int,
                            tracer=None, registry=None) -> float:
     """Measured rung: build the candidate store and time ONE real solve-X
-    wave (wave 0's rows through the binned/uniform row update).  All timing
-    flows through the ``obs`` phase clock — the sweep reads the span's own
-    category delta, so no bare timers leak in (obs-routing rule)."""
+    wave as the streaming driver runs it (wave 0's rows: on a binned
+    store its lanes bins uploaded and solved by ``solve_rows_lanes``, the
+    bins made beforehand as the store makes them once; else the uniform
+    row update).  All timing flows through the ``obs`` phase clock — the
+    sweep reads the span's own category delta, so no bare timers leak in
+    (obs-routing rule)."""
     import jax.numpy as jnp
 
     from repro.core import als as als_mod
@@ -450,13 +453,17 @@ def _measure_als_candidate(r, q: int, cfg: LayoutConfig, *, f: int,
     acfg = als_mod.AlsConfig(f=f, lam=0.05, iters=1, mode="ref")
     theta = jnp.zeros((store.n, f), jnp.float32)
     rows_per = store.m_pad // q
+    if cfg.n_bins > 1:
+        cut = (0, rows_per, acfg.batch_rows)
+        layout, lbs = store.x_wave_layout(*cut), store.x_wave_lanes(*cut)
     before = reg.phase_seconds().get("autotune", 0.0)
     with phase("autotune.measure_wave", cat="autotune", tracer=tracer,
                registry=reg, n_bins=cfg.n_bins,
                k_multiple=cfg.k_multiple):
         if cfg.n_bins > 1:
-            bsl = store.x_slice_binned(0, rows_per)
-            np.asarray(als_mod.update_rows_binned(theta, bsl, acfg))
+            np.asarray(als_mod.solve_rows_lanes(
+                theta, [lb.to_device() for lb in lbs],
+                jnp.asarray(layout.col_of), layout.width, acfg))
         else:
             idx, val, cnt = store.x_slice_triplet(0, rows_per)
             np.asarray(als_mod.update_rows(

@@ -199,15 +199,20 @@ def batch_solve_lanes(
         return _solve_lanes(At, Bt, mode)
 
 
-@functools.partial(jax.jit, static_argnames=("mode",))
+@functools.partial(jax.jit, static_argnames=("mode", "lanes"))
 def batch_solve(
-    A: jax.Array,  # [m, f, f]
-    B: jax.Array,  # [m, f]
+    A: jax.Array,  # [m, f, f]; with lanes [f, f, m]
+    B: jax.Array,  # [m, f];    with lanes [f, m]
     *,
     mode: Mode = "ref",
+    lanes: bool = False,
 ) -> jax.Array:
     """x_u = A_u^{-1} B_u (batched Cholesky solve): the lanes solve between
-    the transposes into and out of its layout."""
+    the transposes into and out of its layout, ``[m, f]``; with ``lanes``
+    the systems are already on the lanes and the result stays there,
+    ``[f, m]`` (:func:`batch_solve_lanes`)."""
+    if lanes:
+        return batch_solve_lanes(A, B, mode=mode)
     with jax.named_scope("als.solve"):
         return _solve_lanes(jnp.transpose(A, (2, 1, 0)), B.T, mode).T
 

@@ -4,8 +4,10 @@ Per iteration the driver runs the two halves of the schedule:
 
 - **solve-X**: Theta resident on device; each wave's R row-slice is double-
   buffered host->device through ``data.prefetch.Prefetcher`` while the
-  current wave solves its X rows (``core.als.update_rows``); solved slices
-  are written straight back to the host ``FactorStore``.
+  current wave solves its X rows (``core.als.update_rows``; on a binned
+  store the wave's lanes bins, made once, through the accumulate half's
+  programs, ``core.als.solve_rows_lanes``); solved slices are written
+  straight back to the host ``FactorStore``.
 - **accumulate-Theta**: A/B Hermitian accumulators resident, with the
   systems on the lanes (``A [f*f, n]``, ``B [f, n]``, the solve kernel's
   layout but flat); each wave streams its batches' R^T column shards
@@ -170,9 +172,12 @@ def run_streaming_als(
 
     With a degree-binned ``RatingStore`` (``n_bins > 1``) both halves
     stream bin-wise cuts and dispatch the kernels once per bin at that
-    bin's own K — identical factor trajectory (padding slots are exact
-    zeros), strictly fewer streamed slots/bytes; the ``update_rows_fn`` /
-    ``partial_herm_fn`` hooks are bypassed on this path.  Binned + mesh
+    bin's own K — the same systems (padding slots are exact zeros),
+    strictly fewer streamed slots/bytes; p = 1, both halves add each
+    bin's Hermitians into lanes accumulators and solve them once
+    (``core.als.accumulate_bin`` / ``solve_accumulated``), the bins made
+    once per store; the ``update_rows_fn`` / ``partial_herm_fn`` hooks
+    are bypassed on this path.  Binned + mesh
     (``p > 1``): the accumulate-Theta half streams the store's
     batch-uniform stacked bins (``rt_stacked``) — one ``wave_herm``
     dispatch per bin, partials host-scattered through the ``items`` maps —
@@ -417,10 +422,11 @@ def run_streaming_als(
             meter.free("acc")
 
     # ------------------------------------------------------------------
-    # Binned halves: the same waves cut bin-wise — each wave's rows arrive
-    # as a BinnedELL and the kernels dispatch once per bin at that bin's K.
-    # Padding slots are exact zeros, so the factor trajectory is identical
-    # to the uniform halves'; only the streamed slots/bytes shrink.
+    # Binned halves: the same waves cut bin-wise into lanes bins that the
+    # store makes once, each bin added at its own K into lanes
+    # accumulators, the systems solved once a half.  Padding slots are
+    # exact zeros, so the systems are the uniform halves'; only the
+    # streamed slots/bytes shrink.
     # ------------------------------------------------------------------
     def _x_half_binned(it: int, first_wave: int):
         theta_dev = jnp.asarray(factors.theta)
@@ -429,34 +435,47 @@ def run_streaming_als(
 
         def gen():
             for wave in sched.waves[first_wave:]:
-                yield wave, ratings.x_slice_binned(
-                    wave.row_start, wave.row_stop)
+                cut = (wave.row_start, wave.row_stop, cfg.batch_rows)
+                yield (wave, ratings.x_wave_layout(*cut),
+                       ratings.x_wave_lanes(*cut))
 
         def put(item):
-            wave, bsl = item
-            nb = binned_nbytes(bsl)
+            wave, layout, lbs = item
+            # counted as the wave's bin-wise cut weighs (x_slice_binned:
+            # each bin's rows at its K), which the plan predicts
+            nb = sum(lb.rows * (8 * lb.idx.shape[0] + 4) for lb in lbs)
+            slots = sum(lb.rows * lb.idx.shape[0] for lb in lbs)
+            nz = sum(lb.nnz for lb in lbs)
             meter.alloc(f"xwave{wave.index}", nb // len(wave.batches))
-            reg.counter("padded_slots").inc(int(bsl.padded_slots))
-            reg.counter("nnz_streamed").inc(int(bsl.nnz))
-            reg.counter("x_padded_slots").inc(int(bsl.padded_slots))
-            reg.counter("x_nnz_streamed").inc(int(bsl.nnz))
-            return wave, bsl, nb
+            reg.counter("padded_slots").inc(slots)
+            reg.counter("nnz_streamed").inc(nz)
+            reg.counter("x_padded_slots").inc(slots)
+            reg.counter("x_nnz_streamed").inc(nz)
+            dev = [lb.to_device() for lb in lbs]
+            return wave, (layout.width, jnp.asarray(layout.col_of), dev), nb
+
+        def bin_span(k, lb):
+            reg.counter("x_accumulate_slots").inc(lb.slots)
+            reg.counter("x_accumulate_ratings").inc(lb.nnz)
+            return phase("als.accumulate", cat="accumulate", tracer=tracer,
+                         registry=reg, half="x", bin=k, rows=lb.rows,
+                         slots=lb.slots, nnz=lb.nnz, x_rows=n, n=m_w, f=f)
 
         try:
             with Prefetcher(gen(), depth=prefetch_depth, put=put,
                             tracer=tracer, registry=reg) as pf:
-                for wave, bsl, nb in pf:
+                for wave, (width, col_of, lbs), nb in pf:
+                    m_w = wave.row_stop - wave.row_start
                     with phase("als.wave_x", cat="solve", tracer=tracer,
                                registry=reg, wave=wave.index,
-                               iteration=it + 1, bytes=nb, bins=bsl.n_bins):
+                               iteration=it + 1, bytes=nb, bins=len(lbs)):
                         meter.alloc("x_scratch", scratch)
-                        rows = np.asarray(
-                            als_mod.update_rows_binned(theta_dev, bsl, cfg))
+                        rows = np.asarray(als_mod.solve_rows_lanes(
+                            theta_dev, lbs, col_of, width, cfg,
+                            each_bin=bin_span))
                         meter.free("x_scratch")
-                        for b in bsl.bins:
-                            if b.m:
-                                _count_solve(
-                                    b.m, als_mod.solved_systems(b.m, cfg))
+                        _count_solve(m_w, kops.solve_width(width, f,
+                                                           cfg.mode))
                         factors.write_slice("x", wave.row_start,
                                             wave.row_stop, rows)
                     meter.free(f"xwave{wave.index}")
@@ -511,7 +530,8 @@ def run_streaming_als(
                             for k, lb in enumerate(lbs):
                                 with phase("als.accumulate",
                                            cat="accumulate", tracer=tracer,
-                                           registry=reg, bin=k, rows=lb.rows,
+                                           registry=reg, half="theta",
+                                           bin=k, rows=lb.rows,
                                            slots=lb.slots, nnz=lb.nnz,
                                            x_rows=x_dev.shape[0], n=n, f=f):
                                     A, B, c = als_mod.accumulate_bin(

@@ -137,7 +137,7 @@ class RatingStore:
     def __init__(self, r: PaddedELL, q: int, k_multiple: int = 8, p: int = 1,
                  n_bins=1, tune_cache=None):
         self.tune = None
-        self._lanes = {}                   # theta_batch_lanes, made once
+        self._lanes = {}          # the lanes bins of both halves, made once
         if n_bins == "auto":
             from repro.core import autotune as _autotune
             res = _autotune.tune_als_layout(
@@ -371,6 +371,32 @@ class RatingStore:
                                           block_rows,
                                           self.theta_acc_layout(block_rows))
         return self._lanes[key]
+
+    def _x_wave(self, row_start: int, row_stop: int, block_rows: int):
+        key = ("x", row_start, row_stop, block_rows)
+        if key not in self._lanes:
+            from repro.core.als import acc_layout, lanes_bins
+            bsl = self.x_slice_binned(row_start, row_stop)
+            layout = acc_layout(bsl, block_rows)
+            self._lanes[key] = (layout, lanes_bins(bsl, block_rows, layout))
+        return self._lanes[key]
+
+    def x_wave_layout(self, row_start: int, row_stop: int,
+                      block_rows: int):
+        """Where each row of the solve-X wave ``[row_start, row_stop)``
+        lies in the wave's lanes accumulators: ``core.als.acc_layout`` of
+        the wave's bin-wise cut (``x_slice_binned``) in row blocks of
+        ``block_rows``, made once per store."""
+        return self._x_wave(row_start, row_stop, block_rows)[0]
+
+    def x_wave_lanes(self, row_start: int, row_stop: int,
+                     block_rows: int) -> list:
+        """The nonempty bins of the solve-X wave ``[row_start, row_stop)``
+        in the accumulate update's layout (``core.als.lanes_bins``: slots
+        on the sublanes, the wave's rows on the lanes, every bin a run of
+        columns of :meth:`x_wave_layout`).  Host arrays, made once per
+        store: the ratings never change between iterations."""
+        return self._x_wave(row_start, row_stop, block_rows)[1]
 
     def theta_wave_stacked(self, batch_indices) -> list:
         """Per-bin stacked theta-half payloads of one mesh wave: for each

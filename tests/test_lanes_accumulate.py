@@ -125,11 +125,11 @@ def _plant_half_the_ratings(monkeypatch):
                                    _plant_half_the_ratings],
                          ids=["altered_answers", "half_the_ratings"])
 def test_theta_side_fault_fails_the_limit(monkeypatch, plant):
-    """A fault planted in the accumulate-Theta half's own ops (a wrong
-    lanes solve, a Hermitian of half the ratings) reaches the Theta the
-    iteration writes: its backward error fails the benchmark's 1e-6 while
-    the X half, which the fault does not touch, stays within it.  The
-    jitted update and solve are traced afresh so the plant is seen."""
+    """A fault planted in the lanes ops (a wrong lanes solve, a Hermitian
+    of half the ratings), which both binned halves run, reaches the X and
+    the Theta the iteration writes: both backward errors fail the
+    benchmark's 1e-6.  The jitted update and solve are traced afresh so
+    the plant is seen."""
     store, sched, (rows, cols, vals) = _store(q=1)
     theta0 = np.asarray(als_mod.als_init(M, N, _cfg()).theta)
     jax.clear_caches()
@@ -142,12 +142,12 @@ def test_theta_side_fault_fails_the_limit(monkeypatch, plant):
     users = reference.Csr.from_coo(rows, cols, vals, M)
     items = reference.Csr.from_coo(cols, rows, vals, N)
     x, theta = fac.x[:M], fac.theta
-    a, b = reference.row_systems(theta0, users, np.arange(M), LAM)
-    assert np.all(reference.backward_errors(x, a, b) <= 1e-6)
-    a, b = reference.row_systems(x, items, np.arange(N), LAM)
     # judged as the harness judges: a reading fails unless it is within
     # the limit (a singular system's NaN fails)
-    assert np.sum(~(reference.backward_errors(theta, a, b) <= 1e-6)) > 0
+    for fixed, csr, rr, got in ((theta0, users, np.arange(M), x),
+                                (x, items, np.arange(N), theta)):
+        a, b = reference.row_systems(fixed, csr, rr, LAM)
+        assert np.sum(~(reference.backward_errors(got, a, b) <= 1e-6)) > 0
 
 
 def _old_sums(x_batch, binned):

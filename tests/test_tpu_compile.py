@@ -10,7 +10,8 @@ through ``ops.als_update_factor`` for the padding path, and the batched
 solve at its unpadded f = 100 and f = 10 through ``ops.batch_solve``.
 The accumulate-Theta programs compile at the SparkALS share's real sizes
 (n = 2.4M items, f = 10), and their arguments and temporaries must fit
-one chip's 16 GB: the out-of-memory failure of that cell, caught here.
+one chip's 16 GB: the out-of-memory failure of that cell, caught here;
+so does a solve-X wave through the same programs at both cells' sizes.
 
 The topology is described inside a module-scoped fixture, never at import:
 only one process at a time may load the TPU library, and every test worker
@@ -168,3 +169,26 @@ def test_solve_accumulated_fits_v5e(tpu_compile, f, n):
         ((f * f, n), f32), ((f, n), f32), ((n,), f32))
     assert "Cholesky" not in compiled.as_text()
     _fits_one_chip(compiled)
+
+
+@pytest.mark.parametrize("f,n,m,width,K,rows,block", [
+    (10, SPARK_N, SPARK_M, 1_922_048, 8, 1_638_400, 16_384),
+    (100, 17_770, 48_504, 49_152, 616, 8_832, 8_832)],
+    ids=["sparkals", "netflix"])
+def test_x_wave_fits_v5e(tpu_compile, f, n, m, width, K, rows, block):
+    """A solve-X wave through the lanes programs, as one program, at the
+    cells' user layouts (``acc_layout`` widths of their generated users,
+    seed 3000018001): zero accumulators, the update of the bin with the
+    most slots (SparkALS) or the largest gather (Netflix), the lanes
+    solve and the take back into user order fit one chip."""
+
+    def wave(theta, idx, val, cnt, col_of):
+        lb = als_mod.LanesBin(idx=idx, val=val, cnt=cnt, cols=None,
+                              start=0, block=block, rows=rows, nnz=0)
+        cfg = als_mod.AlsConfig(f=f, lam=0.05, batch_rows=16_384)
+        return als_mod.solve_rows_lanes(theta, [lb], col_of, width, cfg)
+
+    compiled = tpu_compile(wave, ((n, f), f32), ((K, rows), i32),
+                           ((K, rows), f32), ((rows,), i32), ((m,), i32))
+    assert "Cholesky" not in compiled.as_text()
+    assert _fits_one_chip(compiled) < 8e9
