@@ -79,32 +79,6 @@ def _update_factor(theta, idx, val, cnt, cfg: AlsConfig) -> jax.Array:
     return solve(theta, idx, val, cnt)
 
 
-def update_rows(fixed, idx, val, cnt, cfg: AlsConfig) -> jax.Array:
-    """Per-slice update entry point (out-of-core wave driver, solve side).
-
-    Solves the rows of one factor slice given the ``fixed`` other factor —
-    identical math to a full ``_update_factor`` call restricted to the slice,
-    so streaming a factor in row slices reproduces the in-core trajectory.
-    """
-    return _update_factor(fixed, idx, val, cnt, cfg)
-
-
-def partial_herm(x_batch, idx_loc, val_loc, cnt_loc, cfg: AlsConfig):
-    """Per-batch partial Hermitian (out-of-core wave driver, accumulate side).
-
-    ``idx_loc`` indexes into ``x_batch`` (batch-local user coordinates, the
-    output of ``partition_padded`` on R^T).  Returns (A_j [f*f, n],
-    B_j [f, n]) partial sums with the systems on the lanes; summing over
-    all q batches reproduces the full-gather Hermitian because the
-    weighted-lambda diagonal ``lam * cnt_loc`` also telescopes to
-    ``lam * cnt_total`` — the same partial-sum scheme SU-ALS reduces over
-    the "model" axis (eq. 5-7), serialized over waves instead.
-    """
-    return kops.herm_lanes(
-        x_batch, jnp.asarray(idx_loc).T, jnp.asarray(val_loc).T, cnt_loc,
-        cfg.lam, mode=cfg.mode, tm=cfg.tm, tk=cfg.tk, f_mult=cfg.f_mult)
-
-
 def solve_accumulated(A, B, cnt_total, cfg: AlsConfig) -> jax.Array:
     """Solve a factor from accumulated partial Hermitians held with the
     systems on the lanes (``A [f*f, n]``, ``B [f, n]``, ``cnt_total
@@ -137,16 +111,6 @@ def _kernels() -> tuple:
     the eager per-bin calls of :func:`update_factor_binned` see it."""
     return (kops.fused_herm, kops.herm_lanes, kops.batch_solve,
             kops.batch_solve_lanes)
-
-
-def solved_systems(m: int, cfg: AlsConfig) -> int:
-    """Systems a solve of ``m`` rows factors: whole row blocks of
-    ``cfg.batch_rows`` when it maps them (padded with identity systems),
-    each widened to the solve's own step (``kops.solve_width``)."""
-    if cfg.batch_rows and cfg.batch_rows < m:
-        return (-(-m // cfg.batch_rows)
-                * kops.solve_width(cfg.batch_rows, cfg.f, cfg.mode))
-    return kops.solve_width(m, cfg.f, cfg.mode)
 
 
 def als_iteration(state: AlsState, r, rt, cfg: AlsConfig) -> AlsState:
@@ -375,45 +339,3 @@ def solve_rows_lanes(fixed, lbs, col_of, width: int, cfg: AlsConfig,
         with each_bin(k, lb) if each_bin else contextlib.nullcontext():
             acc = accumulate_bin(*acc, fixed, lb, cfg)
     return jnp.take(solve_accumulated(*acc, cfg), col_of, axis=0)
-
-
-def rmse_binned(x, theta, binned) -> float:
-    """RMSE over the nonzeros of a BinnedELL (per-bin SSE, one sqrt)."""
-    from repro.core.objective import _sq_err_padded
-
-    sse, nnz = 0.0, 0
-    for b, rows in zip(binned.bins, binned.rows):
-        if b.m == 0:
-            continue
-        idx, val, cnt = ell_triplet(b)
-        s, k = _sq_err_padded(x[jnp.asarray(np.asarray(rows), jnp.int32)],
-                              theta, idx, val, cnt)
-        sse += float(s)
-        nnz += int(k)
-    return (sse / max(nnz, 1)) ** 0.5
-
-
-def als_train_binned(
-    rb, rtb, cfg: AlsConfig,
-    test: Optional[tuple] = None,
-    callback=None,
-) -> tuple[AlsState, list[dict]]:
-    """In-core training driver over binned layouts: the same alternating
-    schedule as :func:`als_train` with both half-updates dispatched per bin.
-    ``rb`` / ``rtb`` are BinnedELLs of R (rows=users) and R^T (rows=items).
-    """
-    state = als_init(rb.m, rtb.m, cfg)
-    history: list[dict] = []
-    for it in range(cfg.iters):
-        x = update_factor_binned(state.theta, rb, cfg)
-        theta = update_factor_binned(x, rtb, cfg)
-        state = AlsState(x=x, theta=theta, iteration=state.iteration + 1)
-        rec = {"iteration": it + 1}
-        if test is not None:
-            rec["test_rmse"] = float(
-                rmse_padded(state.x, state.theta, test[0], test[1], test[2]))
-        rec["train_rmse"] = rmse_binned(state.x, state.theta, rb)
-        history.append(rec)
-        if callback is not None:
-            callback(state, rec)
-    return state, history

@@ -47,7 +47,7 @@ from repro.sparse.padded import bin_caps, round_k
 TUNECACHE_SCHEMA = "repro.core/tunecache-v1"
 
 #: default ALS sweep ladder: bin counts x bin lane multiples (n_bins = 1 is
-#: the unbinned baseline, where the lane multiple is inert)
+#: one bin per orientation, swept at the base lane multiple only)
 ALS_N_BINS_LADDER: Tuple[int, ...] = (1, 2, 4, 8)
 #: default SGD sweep ladder: (per_tile_k, degree_sort) — sorted-without-
 #: per-tile-K is pointless (sorting only changes which tiles get a small K)
@@ -316,21 +316,19 @@ def predicted_als_bytes(r, q: int, cfg: LayoutConfig, *, p: int = 1,
     if deg_t is None:
         deg_t = _batch_item_degrees(r, q)
     k_loc_t = round_k(int(deg_t.max()) if deg_t.size else 0, km)
-    bin_fills = None
-    # solve-X half
+    # solve-X half: the mesh layout of R at p > 1, else R binned
     if p > 1:
         k_model = _model_shard_k(r, p, km)
         x_bytes = m_pad * p * (k_model * 8 + 4)
         x_slots = m_pad * p * k_model
-    elif cfg.n_bins > 1:
-        x_bytes, x_slots = _binned_rows_bytes(pad_deg, cfg.n_bins, km, r.K)
     else:
-        x_bytes, x_slots = m_pad * (r.K * 8 + 4), m_pad * r.K
-    # accumulate-Theta half (+ the config-independent fresh X slices)
-    if cfg.n_bins > 1 and p > 1:
+        x_bytes, x_slots = _binned_rows_bytes(pad_deg, cfg.n_bins, km, r.K)
+    # accumulate-Theta half (+ the config-independent fresh X slices):
+    # batch-uniform stacks at p > 1, else each R^T batch binned alone
+    if p > 1:
         t_bytes, t_slots, bin_fills = _stacked_bytes(
             deg_t, cfg.n_bins, km, k_loc_t, p)
-    elif cfg.n_bins > 1:
+    else:
         t_bytes = t_slots = 0
         bin_fills = []
         for j in range(q):
@@ -338,9 +336,6 @@ def predicted_als_bytes(r, q: int, cfg: LayoutConfig, *, p: int = 1,
             t_bytes += bj
             t_slots += sj
             bin_fills.append((sj, int(deg_t[j].sum())))
-    else:
-        t_bytes = q * r.n_cols * (k_loc_t * 8 + 4)
-        t_slots = q * r.n_cols * k_loc_t
     t_bytes += m_pad * f * 4
     nnz = int(r.cnt.sum())
     return {"bytes": x_bytes + t_bytes, "x_bytes": x_bytes,
@@ -356,9 +351,8 @@ def predicted_als_bytes(r, q: int, cfg: LayoutConfig, *, p: int = 1,
 def als_ladder(k_multiple: int = 8,
                n_bins_ladder: Sequence[int] = ALS_N_BINS_LADDER
                ) -> list[LayoutConfig]:
-    """Default ALS candidate ladder: the unbinned baseline, then every
-    (n_bins, lane multiple) rung — the lane multiple only matters once
-    binning re-rounds each bin's K, so n_bins = 1 carries just the base."""
+    """Default ALS candidate ladder: one bin (n_bins = 1) at the base lane
+    multiple, then every (n_bins, lane multiple) rung of several bins."""
     out = [LayoutConfig(n_bins=1, k_multiple=k_multiple)]
     for nb in n_bins_ladder:
         if nb <= 1:
@@ -434,10 +428,9 @@ def tune_als_layout(r, q: int, *, p: int = 1, f: int = 16,
 def _measure_als_candidate(r, q: int, cfg: LayoutConfig, *, f: int,
                            tracer=None, registry=None) -> float:
     """Measured rung: build the candidate store and time ONE real solve-X
-    wave as the streaming driver runs it (wave 0's rows: on a binned
-    store its lanes bins uploaded and solved by ``solve_rows_lanes``, the
-    bins made beforehand as the store makes them once; else the uniform
-    row update).  All timing flows through the ``obs`` phase clock — the
+    wave as the streaming driver runs it (wave 0's rows: its lanes bins
+    uploaded and solved by ``solve_rows_lanes``, the bins made beforehand
+    as the store makes them once).  All timing flows through the ``obs`` phase clock — the
     sweep reads the span's own category delta, so no bare timers leak in
     (obs-routing rule)."""
     import jax.numpy as jnp
@@ -452,23 +445,15 @@ def _measure_als_candidate(r, q: int, cfg: LayoutConfig, *, f: int,
                         n_bins=cfg.n_bins)
     acfg = als_mod.AlsConfig(f=f, lam=0.05, iters=1, mode="ref")
     theta = jnp.zeros((store.n, f), jnp.float32)
-    rows_per = store.m_pad // q
-    if cfg.n_bins > 1:
-        cut = (0, rows_per, acfg.batch_rows)
-        layout, lbs = store.x_wave_layout(*cut), store.x_wave_lanes(*cut)
+    cut = (0, store.m_pad // q, acfg.batch_rows)
+    layout, lbs = store.x_wave_layout(*cut), store.x_wave_lanes(*cut)
     before = reg.phase_seconds().get("autotune", 0.0)
     with phase("autotune.measure_wave", cat="autotune", tracer=tracer,
                registry=reg, n_bins=cfg.n_bins,
                k_multiple=cfg.k_multiple):
-        if cfg.n_bins > 1:
-            np.asarray(als_mod.solve_rows_lanes(
-                theta, [lb.to_device() for lb in lbs],
-                jnp.asarray(layout.col_of), layout.width, acfg))
-        else:
-            idx, val, cnt = store.x_slice_triplet(0, rows_per)
-            np.asarray(als_mod.update_rows(
-                theta, jnp.asarray(idx), jnp.asarray(val),
-                jnp.asarray(cnt), acfg))
+        np.asarray(als_mod.solve_rows_lanes(
+            theta, [lb.to_device() for lb in lbs],
+            jnp.asarray(layout.col_of), layout.width, acfg))
     return reg.phase_seconds().get("autotune", 0.0) - before
 
 

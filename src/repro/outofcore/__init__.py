@@ -25,8 +25,8 @@ Paper vocabulary -> implementation map:
 - **q** (X row batches, model parallelism): ``PartitionPlan.q``, made
   explicit as ``core.partition.QBatch`` row ranges.  ``store.RatingStore``
   keeps R row-major for the solve-X half and R^T column-partitioned into the
-  same q user-batches for the accumulate-Theta half — the paper's "keep R
-  and R^T in host memory".
+  same q user-batches for the accumulate-Theta half, both degree-binned —
+  the paper's "keep R and R^T in host memory".
 - **waves** (q batches beyond the device axis, §4.4 elasticity):
   ``schedule.IterationSchedule.waves`` — each wave streams up to ``n_data``
   consecutive q-batches through the (simulated) devices; both iteration
@@ -40,19 +40,19 @@ Paper vocabulary -> implementation map:
 - **checkpoint/restart** (§4.4 fault tolerance): every completed wave
   commits factors (+ Hermitian accumulators mid-half) through
   ``checkpoint.CheckpointManager``; a killed run resumes mid-iteration.
-- **degree-binned layout** (§4.1 binning): ``RatingStore(n_bins > 1)``
-  additionally keeps R and each R^T shard as
-  ``sparse.padded.BinnedELL`` — rows grouped into ~log-spaced degree bins,
-  each padded at its own tight K.  Layout ownership rules:
+- **degree-binned layout** (§4.1 binning): ``RatingStore(n_bins=...)``
+  keeps R and each R^T shard as ``sparse.padded.BinnedELL`` — rows grouped
+  into ~log-spaced degree bins, each padded at its own tight K;
+  ``n_bins = 1`` is one bin per orientation.  Layout ownership rules:
 
-  1. The binned shards are *views of the same nonzeros* as the uniform
-     arrays (which stay resident for eval/compat); masked padding slots
-     are exact zeros, so binned and unbinned runs agree to float roundoff.
+  1. The bins hold the same nonzeros as R (``RatingStore.r``, kept whole
+     for evaluation and pricing); masked padding slots are exact zeros,
+     so runs at any ``n_bins`` agree to float roundoff.
   2. Factors and checkpoints always live in ORIGINAL row order — the bin
      permutation (``perm``/``inv_perm``) never escapes the store; the
-     binned ALS kernels scatter per-bin results back through
-     ``BinnedELL.rows`` (checkpoints are layout-agnostic: a binned run
-     resumes a uniform checkpoint and vice versa).
+     ALS halves take per-bin results back through each layout's columns
+     (checkpoints are layout-agnostic: a run resumes a checkpoint written
+     at another ``n_bins``).
   3. The wave scheduler relies on stable grouping: each bin's original-row
      list ascends, so any wave range ``[start, stop)`` cuts every bin in
      one contiguous span (``bin_spans``) and per-wave byte/slot
@@ -60,15 +60,15 @@ Paper vocabulary -> implementation map:
   4. Planner pricing goes through ``RatingStore.bin_fill_pairs()`` ->
      ``plan_for(bin_fills=...)``; the ledger's ``fill_waste_ratio`` and
      per-component ``fill_bound/*`` records measure the binned layout.
-  5. Binned + mesh (``p > 1``): the theta half streams batch-uniform
-     stacked bins (``RatingStore.rt_stacked``, bin caps chosen globally
-     over all q batches so every batch's bin presents one shape the mesh
-     herm stack can shard; per-batch membership varies and rides in each
-     stack's ``items`` scatter map).  The solve-X half keeps the uniform
-     mesh layout.  Stack padding rows carry cnt = 0 and contribute
-     exact-zero partials, so the f64 accumulators — and therefore
-     checkpoints and the topology reduce — are bit-identical to a
-     uniform run's.
+  5. Mesh (``p > 1``): the theta half streams batch-uniform stacked bins
+     (``RatingStore.rt_stacked``, bin caps chosen globally over all q
+     batches so every batch's bin presents one shape the mesh herm stack
+     can shard; per-batch membership varies and rides in each stack's
+     ``items`` scatter map).  The solve-X half streams R cut into the p
+     theta shards (``r_model_parts``).  Stack padding rows carry cnt = 0
+     and contribute exact-zero partials, so the f64 accumulators — and
+     therefore checkpoints and the topology reduce — hold the same
+     systems at any ``n_bins``.
   6. ``n_bins="auto"`` (and the SGD side's ``per_tile_k="auto"``) route
      through ``repro.core.autotune``: argmin of predicted streamed bytes
      over the config ladder, cached per (shape, skew, topology, backend);

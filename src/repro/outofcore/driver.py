@@ -2,19 +2,22 @@
 
 Per iteration the driver runs the two halves of the schedule:
 
-- **solve-X**: Theta resident on device; each wave's R row-slice is double-
-  buffered host->device through ``data.prefetch.Prefetcher`` while the
-  current wave solves its X rows (``core.als.update_rows``; on a binned
-  store the wave's lanes bins, made once, through the accumulate half's
-  programs, ``core.als.solve_rows_lanes``); solved slices are written
-  straight back to the host ``FactorStore``.
+- **solve-X**: Theta resident on device; each wave's R rows, cut into the
+  store's degree bins in the accumulate update's layout (made once per
+  store), are double-buffered host->device through
+  ``data.prefetch.Prefetcher`` while the current wave solves its X rows
+  through the accumulate half's programs (``core.als.solve_rows_lanes``);
+  solved slices are written straight back to the host ``FactorStore``.
 - **accumulate-Theta**: A/B Hermitian accumulators resident, with the
   systems on the lanes (``A [f*f, n]``, ``B [f, n]``, the solve kernel's
-  layout but flat); each wave streams its batches' R^T column shards
-  together with the freshly solved X slices, each bin added by one
-  donated program (``core.als.accumulate_bin``; ``core.als.partial_herm``
-  on the uniform layout), and after the last wave the accumulated
-  systems are solved as they lie (``core.als.solve_accumulated``).
+  layout but flat); each wave streams its batches' binned R^T column
+  shards together with the freshly solved X slices, each bin added by
+  one donated program (``core.als.accumulate_bin``), and after the last
+  wave the accumulated systems are solved as they lie
+  (``core.als.solve_accumulated``).
+
+``n_bins = 1`` is one degree bin per orientation, streamed by the same
+halves.
 
 Every wave completion checkpoints the full resumable state (factors +
 accumulators + global step) through ``checkpoint.CheckpointManager``, so a
@@ -49,7 +52,7 @@ instead of one model-shard's simulated view:
 from __future__ import annotations
 
 import contextlib
-from typing import Callable, List, Optional
+from typing import List, Optional
 
 import jax
 import jax.numpy as jnp
@@ -70,8 +73,7 @@ from repro.outofcore.runtime import (MemoryMeter, SimulatedFailure,
 from repro.outofcore.schedule import (IterationSchedule,
                                       predicted_stream_stats,
                                       required_capacity_bytes)
-from repro.outofcore.store import (FactorStore, RatingStore, binned_nbytes,
-                                   triplet_nbytes)
+from repro.outofcore.store import FactorStore, RatingStore, binned_nbytes
 
 __all__ = ["MemoryMeter", "SimulatedFailure", "StreamTelemetry",
            "run_streaming_als"]
@@ -139,9 +141,6 @@ def run_streaming_als(
     train_eval=None,                 # (idx, val, cnt) for per-iteration RMSE
     test_eval=None,
     fail_after_waves: Optional[int] = None,
-    update_rows_fn: Optional[Callable] = None,
-    partial_herm_fn: Optional[Callable] = None,
-    solve_acc_fn: Optional[Callable] = None,
     mesh=None,
     topology=None,
     callback=None,
@@ -151,8 +150,7 @@ def run_streaming_als(
     """Run ``cfg.iters`` streaming ALS iterations of ``sched`` over ``ratings``.
 
     Returns (factor store, per-iteration history, telemetry).  With
-    ``ckpt_dir`` set the run resumes from the latest committed wave; the
-    ``*_fn`` hooks default to the in-process ``core.als`` entry points.
+    ``ckpt_dir`` set the run resumes from the latest committed wave.
 
     Observability: every hot phase runs inside an ``obs`` span — the whole
     run (``driver``), each iteration/half, one ``solve`` span per wave,
@@ -170,26 +168,22 @@ def run_streaming_als(
     ``phase_seconds`` breakdown, which each history record also carries as
     its per-iteration delta.
 
-    With a degree-binned ``RatingStore`` (``n_bins > 1``) both halves
-    stream bin-wise cuts and dispatch the kernels once per bin at that
-    bin's own K — the same systems (padding slots are exact zeros),
-    strictly fewer streamed slots/bytes; p = 1, both halves add each
-    bin's Hermitians into lanes accumulators and solve them once
-    (``core.als.accumulate_bin`` / ``solve_accumulated``), the bins made
-    once per store; the ``update_rows_fn`` / ``partial_herm_fn`` hooks
-    are bypassed on this path.  Binned + mesh
-    (``p > 1``): the accumulate-Theta half streams the store's
-    batch-uniform stacked bins (``rt_stacked``) — one ``wave_herm``
-    dispatch per bin, partials host-scattered through the ``items`` maps —
-    while the solve-X half keeps the uniform mesh layout.
+    Both halves stream the store's degree bins (one bin at ``n_bins``
+    1) and dispatch the kernels once per bin at that bin's own K.  On one
+    device (a p = 1 store) both halves add each bin's Hermitians into
+    lanes accumulators and solve them once (``core.als.accumulate_bin`` /
+    ``solve_accumulated``), the bins made once per store.
 
     With ``mesh`` set (axes ``("data", "model")``, sizes matching
-    ``sched.n_data`` and ``sched.p``) every wave executes shard-mapped on
-    the real mesh and theta is handled as p model shards; ``topology`` is
-    the ``distributed.reduce.DeviceTopology`` of the data axis for the
-    accumulate-half reduction (default: fast domains of 2, the paper's
-    2-GPUs-per-PCIe-switch machine).  ``partial_herm_fn`` is unused on the
-    mesh path (the shard-mapped ``make_wave_herm_fn`` replaces it).
+    ``sched.n_data`` and ``sched.p``, a ``p > 1`` store) every wave
+    executes shard-mapped on the real mesh and theta is handled as p model
+    shards: the solve-X half streams the mesh layout of R
+    (``x_slice_mesh_triplet``), the accumulate-Theta half the store's
+    batch-uniform stacked bins (``rt_stacked``) — one ``wave_herm``
+    dispatch per bin, partials host-scattered through the ``items`` maps.
+    ``topology`` is the ``distributed.reduce.DeviceTopology`` of the data
+    axis for the accumulate-half reduction (default: fast domains of 2,
+    the paper's 2-GPUs-per-PCIe-switch machine).
     """
     assert ratings.m_pad == sched.m_pad and ratings.n == sched.n, \
         "RatingStore and IterationSchedule were built for different shapes"
@@ -197,26 +191,10 @@ def run_streaming_als(
     m_pad, n, n_data = sched.m_pad, sched.n, sched.n_data
     W = len(sched.waves)
     wpi = sched.waves_per_iteration            # 2 * W checkpoint steps/iter
-    user_update_fn = update_rows_fn            # explicit hook (mesh override)
-    update_rows_fn = update_rows_fn or (
-        lambda fixed, i, v, c: als_mod.update_rows(fixed, i, v, c, cfg))
-    partial_herm_fn = partial_herm_fn or (
-        lambda xb, i, v, c: als_mod.partial_herm(xb, i, v, c, cfg))
-    solve_acc_fn = solve_acc_fn or (
-        lambda A, B, c: als_mod.solve_accumulated(A, B, c, cfg))
-
-    # degree-binned store: waves stream bin-wise cuts and dispatch the
-    # kernels once per bin at that bin's K.  p = 1 binned runs cut both
-    # halves bin-wise; on a mesh the theta half streams the batch-uniform
-    # stacked bins (rt_stacked) while solve-X keeps the uniform mesh layout.
-    n_bins = getattr(ratings, "n_bins", 1)
-    binned = n_bins > 1
-    stacked = getattr(ratings, "rt_stacked", None)
-    assert not (binned and mesh is None and stacked is not None), \
-        "stacked (p > 1) binned stores require mesh= to stream"
-    assert not (binned and mesh is not None and stacked is None), \
-        "mesh streaming of a binned store needs batch-uniform bins; " \
-        "build the RatingStore with p > 1 so rt_stacked exists"
+    n_bins = ratings.n_bins
+    assert (mesh is None) == (ratings.rt_stacked is None), \
+        "mesh streaming needs the batch-uniform bins (rt_stacked) of a " \
+        "RatingStore built with p > 1; one device streams a p = 1 store"
 
     p = 1
     if mesh is not None:
@@ -289,62 +267,15 @@ def run_streaming_als(
 
     def _count_solve(rows: int, factored: int):
         """``rows`` real systems solved; ``factored`` systems the solve
-        factored for them (row-block and lane padding included)."""
+        factored for them (lane padding included)."""
         reg.counter("solve_systems").inc(rows)
         reg.counter("solve_padded_systems").inc(factored)
 
     # ------------------------------------------------------------------
-    # solve-X half: stream R row slices, solve rows, write back.
-    # ------------------------------------------------------------------
-    def _x_half(it: int, first_wave: int):
-        theta_dev = jnp.asarray(factors.theta)
-        meter.alloc("fixed_theta", factors.theta.nbytes)
-        scratch = (sched.waves[0].rows * (f * f + 2 * f) * 4) // n_data
-
-        def gen():
-            for wave in sched.waves[first_wave:]:
-                yield wave, ratings.x_slice_triplet(
-                    wave.row_start, wave.row_stop)
-
-        def put(item):
-            wave, trip = item
-            nb = triplet_nbytes(trip)
-            # per-device share: each device on the axis takes ONE batch of
-            # the wave (a ragged last wave has fewer batches than n_data)
-            meter.alloc(f"xwave{wave.index}", nb // len(wave.batches))
-            reg.counter("padded_slots").inc(trip[0].size)
-            reg.counter("nnz_streamed").inc(int(trip[2].sum()))
-            reg.counter("x_padded_slots").inc(trip[0].size)
-            reg.counter("x_nnz_streamed").inc(int(trip[2].sum()))
-            dev = tuple(jnp.asarray(a) for a in trip)
-            return wave, dev, nb
-
-        try:
-            with Prefetcher(gen(), depth=prefetch_depth, put=put,
-                            tracer=tracer, registry=reg) as pf:
-                for wave, (idx, val, cnt), nb in pf:
-                    with phase("als.wave_x", cat="solve", tracer=tracer,
-                               registry=reg, wave=wave.index,
-                               iteration=it + 1, bytes=nb):
-                        meter.alloc("x_scratch", scratch)
-                        rows = np.asarray(
-                            update_rows_fn(theta_dev, idx, val, cnt))
-                        meter.free("x_scratch")
-                        m_w = idx.shape[0]
-                        _count_solve(m_w, als_mod.solved_systems(m_w, cfg))
-                        factors.write_slice("x", wave.row_start,
-                                            wave.row_stop, rows)
-                    meter.free(f"xwave{wave.index}")
-                    reg.counter("waves_run").inc()
-                    reg.counter("batches_loaded").inc(len(wave.batches))
-                    reg.counter("bytes_streamed").inc(nb)
-                    _save(it * wpi + wave.index + 1)
-        finally:
-            meter.free("fixed_theta")
-
-    # ------------------------------------------------------------------
-    # accumulate-Theta half: stream R^T shards + X slices, accumulate,
-    # solve after the last wave.
+    # Single-device halves: each wave cut bin-wise into lanes bins that
+    # the store makes once, each bin added at its own K into lanes
+    # accumulators, the systems solved once a wave (solve-X) or once a
+    # half (accumulate-Theta).  Padding slots are exact zeros.
     # ------------------------------------------------------------------
     def _acc_init(acc0, width: int):
         """The single-device accumulators, systems on the lanes in
@@ -356,79 +287,19 @@ def run_streaming_als(
                 jnp.zeros((f, width), jnp.float32),
                 jnp.zeros((width,), jnp.float32))
 
-    def _solve_theta(A, B, c, col_of=None):
+    def _solve_theta(A, B, c, col_of):
         """Solve the accumulated systems and write Theta back; ``col_of``
-        is each item's column when they are not in item order."""
+        is each item's column."""
         meter.alloc("theta_out", n * f * 4)
         with phase("als.solve_accumulated", cat="solve_accumulated",
                    tracer=tracer, registry=reg, systems=A.shape[-1], f=f):
-            theta = solve_acc_fn(A, B, c)
-            if col_of is not None:
-                theta = jnp.take(theta, col_of, axis=0)
+            theta = jnp.take(als_mod.solve_accumulated(A, B, c, cfg),
+                             col_of, axis=0)
             factors.write_slice("theta", 0, n, np.asarray(theta))
         _count_solve(n, kops.solve_width(A.shape[-1], f, cfg.mode))
         meter.free("theta_out")
 
-    def _theta_half(it: int, first_wave: int, acc0=None):
-        acc_bytes = n * (f * f + f + 1) * 4
-        meter.alloc("acc", acc_bytes)
-        A, B, c = _acc_init(acc0, n)
-
-        def gen():
-            for wave in sched.waves[first_wave:]:
-                payload = [
-                    (b, ratings.theta_batch_triplet(b.index),
-                     factors.read_slice("x", b.row_start, b.row_stop))
-                    for b in wave.batches]
-                yield wave, payload
-
-        def put(item):
-            wave, payload = item
-            nb = sum(triplet_nbytes(t) + x.nbytes for _, t, x in payload)
-            # each simulated device holds ONE batch's shard + X slice
-            meter.alloc(f"twave{wave.index}", nb // len(payload))
-            slots = sum(t[0].size for _, t, _x in payload)
-            nz = sum(int(t[2].sum()) for _, t, _x in payload)
-            reg.counter("padded_slots").inc(slots)
-            reg.counter("nnz_streamed").inc(nz)
-            reg.counter("t_padded_slots").inc(slots)
-            reg.counter("t_nnz_streamed").inc(nz)
-            dev = [(b, tuple(jnp.asarray(a) for a in t), jnp.asarray(x))
-                   for b, t, x in payload]
-            return wave, dev, nb
-
-        try:
-            with Prefetcher(gen(), depth=prefetch_depth, put=put,
-                            tracer=tracer, registry=reg) as pf:
-                for wave, payload, nb in pf:
-                    last = wave.index == W - 1
-                    with phase("als.wave_theta", cat="solve", tracer=tracer,
-                               registry=reg, wave=wave.index,
-                               iteration=it + 1, bytes=nb):
-                        for _, (idx, val, cnt), x_dev in payload:
-                            Aj, Bj = partial_herm_fn(x_dev, idx, val, cnt)
-                            A = A + Aj
-                            B = B + Bj
-                            c = c + cnt.astype(jnp.float32)
-                        meter.free(f"twave{wave.index}")
-                        if last:
-                            _solve_theta(A, B, c)
-                    reg.counter("waves_run").inc()
-                    reg.counter("batches_loaded").inc(len(payload))
-                    reg.counter("bytes_streamed").inc(nb)
-                    _save(it * wpi + W + wave.index + 1,
-                          acc=None if last else (A, B, c))
-        finally:
-            meter.free("acc")
-
-    # ------------------------------------------------------------------
-    # Binned halves: the same waves cut bin-wise into lanes bins that the
-    # store makes once, each bin added at its own K into lanes
-    # accumulators, the systems solved once a half.  Padding slots are
-    # exact zeros, so the systems are the uniform halves'; only the
-    # streamed slots/bytes shrink.
-    # ------------------------------------------------------------------
-    def _x_half_binned(it: int, first_wave: int):
+    def _x_half(it: int, first_wave: int):
         theta_dev = jnp.asarray(factors.theta)
         meter.alloc("fixed_theta", factors.theta.nbytes)
         scratch = (sched.waves[0].rows * (f * f + 2 * f) * 4) // n_data
@@ -486,7 +357,7 @@ def run_streaming_als(
         finally:
             meter.free("fixed_theta")
 
-    def _theta_half_binned(it: int, first_wave: int, acc0=None):
+    def _theta_half(it: int, first_wave: int, acc0=None):
         acc_bytes = n * (f * f + f + 1) * 4
         meter.alloc("acc", acc_bytes)
         # the first R^T shard's bins lie end to end in the accumulators,
@@ -559,7 +430,6 @@ def run_streaming_als(
         meter.alloc("fixed_theta", factors.theta.nbytes // p)  # one shard
         full_rows = sched.waves[0].rows          # n_data * rows-per-batch
         scratch = (full_rows * (f * f + 2 * f) * 4) // n_data
-        custom_update = user_update_fn or wave_update
 
         def gen():
             for wave in sched.waves[first_wave:]:
@@ -594,7 +464,7 @@ def run_streaming_als(
                                iteration=it + 1, bytes=nb, mesh=True):
                         meter.alloc("x_scratch", scratch)
                         rows = np.asarray(
-                            custom_update(theta_dev, idx, val, cnt))
+                            wave_update(theta_dev, idx, val, cnt))
                         meter.free("x_scratch")
                         # each of the n_data * p devices solves its slice
                         shards = n_data * p
@@ -611,91 +481,16 @@ def run_streaming_als(
             meter.free("fixed_theta")
 
     def _theta_half_mesh(it: int, first_wave: int, acc0=None):
-        # per-device resident: only the owned model shard's systems
-        acc_shard = n * (f * f + f + 1) * 4 // p
-        meter.alloc("acc", acc_shard)
-        if acc0 is not None:
-            A_dev = np.asarray(acc0[0], np.float64).copy()
-            B_dev = np.asarray(acc0[1], np.float64).copy()
-            c_dev = np.asarray(acc0[2], np.float64).copy()
-        else:
-            A_dev = np.zeros((n_data, n, f, f), np.float64)
-            B_dev = np.zeros((n_data, n, f), np.float64)
-            c_dev = np.zeros((n_data, n), np.float64)
-
-        def gen():
-            for wave in sched.waves[first_wave:]:
-                trips = [ratings.theta_batch_triplet(b.index)
-                         for b in wave.batches]
-                xs = [factors.read_slice("x", b.row_start, b.row_stop)
-                      for b in wave.batches]
-                yield wave, trips, xs
-
-        def put(item):
-            wave, trips, xs = item
-            nbatch = len(trips)
-            trip_nb = sum(triplet_nbytes(t) for t in trips)
-            x_nb = sum(x.nbytes for x in xs)
-            slots = sum(t[0].size for t in trips)
-            nz = sum(int(t[2].sum()) for t in trips)
-            reg.counter("padded_slots").inc(slots)
-            reg.counter("nnz_streamed").inc(nz)
-            reg.counter("t_padded_slots").inc(slots)
-            reg.counter("t_nnz_streamed").inc(nz)
-            # per device: 1/p of one batch's R^T shard (its theta rows) +
-            # the batch's full X slice (replicated over the model axis)
-            meter.alloc(f"twave{wave.index}",
-                        trip_nb // (nbatch * p) + x_nb // nbatch)
-            pad = n_data - nbatch
-            idxT = np.stack([t[0] for t in trips])
-            valT = np.stack([t[1] for t in trips])
-            cntT = np.stack([t[2] for t in trips])
-            x_stack = np.stack(xs)
-            if pad:      # ragged last wave: empty batches contribute A = 0
-                z = ((0, pad),) + ((0, 0),) * 2
-                idxT, valT = np.pad(idxT, z), np.pad(valT, z)
-                cntT = np.pad(cntT, ((0, pad), (0, 0)))
-                x_stack = np.pad(x_stack, z)
-            return wave, (x_stack, idxT, valT, cntT), trip_nb + x_nb
-
-        try:
-            with Prefetcher(gen(), depth=prefetch_depth, put=put,
-                            tracer=tracer, registry=reg) as pf:
-                for wave, (x_stack, idxT, valT, cntT), nb in pf:
-                    with phase("als.wave_theta", cat="solve", tracer=tracer,
-                               registry=reg, wave=wave.index,
-                               iteration=it + 1, bytes=nb, mesh=True):
-                        A_w, B_w = wave_herm(x_stack, idxT, valT, cntT)
-                        # per-DATA-SHARD accumulation (float64: host
-                        # stand-in for the device-resident partials; exact
-                        # for f32 summands, so the final topology reduce is
-                        # order-free)
-                        A_dev += A_w
-                        B_dev += B_w
-                        c_dev += cntT
-                    meter.free(f"twave{wave.index}")
-                    reg.counter("waves_run").inc()
-                    reg.counter("batches_loaded").inc(len(wave.batches))
-                    reg.counter("bytes_streamed").inc(nb)
-                    last = wave.index == W - 1
-                    if last:
-                        # NOT nested in the wave's solve span: the reduce +
-                        # post-reduce shard solves are their own phase
-                        _reduce_and_solve(A_dev, B_dev, c_dev)
-                    _save(it * wpi + W + wave.index + 1,
-                          acc=None if last else (A_dev, B_dev, c_dev))
-        finally:
-            meter.free("acc")
-
-    def _theta_half_mesh_binned(it: int, first_wave: int, acc0=None):
         """Mesh theta half over the batch-uniform stacked bins: one
         ``wave_herm`` dispatch per bin per wave (one compiled shape per
         bin — ``make_wave_herm_fn`` is shape-polymorphic), partials
         host-scattered into the per-data-shard f64 accumulators through
-        each stack's ``items`` map.  Padding rows/batches carry cnt = 0
-        and produce exact-zero partials, so scattering them (``np.add.at``,
-        duplicate-safe) changes nothing; the checkpoint tree is identical
-        to the uniform mesh half's, so kill/resume stays bit-exact and
+        each stack's ``items`` map (float64: a host stand-in for
+        device-resident partial state, exact for f32 summands, so the
+        final topology reduce is order-free).  Padding rows/batches carry
+        cnt = 0 and produce exact-zero partials, so scattering them
+        (``np.add.at``, duplicate-safe) changes nothing; the accumulators
+        are in item order, so kill/resume stays bit-exact and
         layout-agnostic."""
         acc_shard = n * (f * f + f + 1) * 4 // p
         meter.alloc("acc", acc_shard)
@@ -789,19 +584,16 @@ def run_streaming_als(
             lo, hi = k * npp, (k + 1) * npp
             # the reduced [n, f, f] partials, moved to the lanes solve's
             # layout
-            th_k = solve_acc_fn(
+            th_k = als_mod.solve_accumulated(
                 jnp.asarray(A[lo:hi].reshape(npp, f * f).T, jnp.float32),
                 jnp.asarray(B[lo:hi].T, jnp.float32),
-                jnp.asarray(c[lo:hi], jnp.float32))
+                jnp.asarray(c[lo:hi], jnp.float32), cfg)
             _count_solve(npp, kops.solve_width(npp, f, cfg.mode))
             factors.write_shard("theta", k, p, np.asarray(th_k))
         meter.free("theta_out")
 
-    x_half = (_x_half_mesh if mesh is not None
-              else _x_half_binned if binned else _x_half)
-    theta_half = (_theta_half_mesh_binned if mesh is not None and binned
-                  else _theta_half_mesh if mesh is not None
-                  else _theta_half_binned if binned else _theta_half)
+    x_half, theta_half = ((_x_half, _theta_half) if mesh is None
+                          else (_x_half_mesh, _theta_half_mesh))
 
     # ------------------------------------------------------------------
     # Plan side of the ledger: per-wave predictions summed over exactly the

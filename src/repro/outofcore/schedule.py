@@ -25,7 +25,7 @@ iteration runs two halves over the *same* wave list:
   are resident; wave ``w`` streams, per batch ``j``, the R^T column shard of
   user-batch ``j`` plus the freshly solved X slice of batch ``j`` (the
   "factor slices resident" of §4.4), and adds the batch's partial Hermitians.
-  After the last wave the accumulated systems are solved in row blocks.
+  After the last wave the accumulated systems are solved at once.
 
 This is SU-ALS's partial-sum scheme (eq. 5-7) serialized over waves: with
 ``n_data`` simulated devices, each wave models one synchronous step in which
@@ -266,28 +266,25 @@ def required_capacity_bytes(store, sched: IterationSchedule, f: int,
     half stays replicated across the model axis (every shard's partial
     Hermitian reads the whole batch).
 
-    A degree-binned store streams bin-wise cuts: at p = 1 per-wave
-    payloads vary with where each bin's rows fall, so the model bounds
-    every wave by the maximum per-batch payload — still ``le`` vs the
-    meter, and never above the uniform-K model.  At p > 1 the theta half
-    streams the batch-uniform stacks (``rt_stacked``): every batch
-    presents the same per-bin shapes, so its payload is one exact
+    The store streams bin-wise cuts: at p = 1 per-wave payloads vary
+    with where each bin's rows fall, so the model bounds every wave by the
+    maximum per-batch payload — still ``le`` vs the meter.  At p > 1 the
+    theta half streams the batch-uniform stacks (``rt_stacked``): every
+    batch presents the same per-bin shapes, so its payload is one exact
     constant per batch.
     """
     n_data, p = sched.n_data, sched.p
     wave_rows = sched.waves[0].rows
     bufs = prefetch_depth + 2
-    binned = getattr(store, "r_binned", None) is not None
-    stacked = getattr(store, "rt_stacked", None)
     # solve-X half: resident Theta shard + wave triplets + solve scratch
     theta_bytes = store.n * f * 4 // p
-    if binned:
+    if p == 1:
         x_payload = max(
             _binned_span_bytes(store.r_binned, w.row_start, w.row_stop)
             // len(w.batches)
             for w in sched.waves)
     else:
-        K = store.r.K if p == 1 else store.r_model_parts.idx.shape[-1]
+        K = store.r_model_parts.idx.shape[-1]
         x_payload = (wave_rows * (K * 8 + 4)) // n_data
     x_scratch = (wave_rows * (f * f + 2 * f) * 4) // n_data
     x_half = theta_bytes + bufs * x_payload + x_scratch
@@ -295,18 +292,15 @@ def required_capacity_bytes(store, sched: IterationSchedule, f: int,
     # the owned theta shard + the batch's (replicated) X slice
     q, n = store.q, store.n
     acc_bytes = n * (f * f + f + 1) * 4 // p
-    if binned:
+    if p == 1:
         from repro.outofcore.store import binned_nbytes
         t_payload = max(binned_nbytes(b) for b in store.rt_binned) \
             + (sched.m_pad // q) * f * 4
-    elif stacked is not None:
+    else:
         # one batch's per-bin triplets, 1/p on each device (rows_b rows are
         # sharded over the model axis), plus the replicated fresh X slice
-        batch_trip = sum(st.rows * (st.K * 8 + 4) for st in stacked)
+        batch_trip = sum(st.rows * (st.K * 8 + 4) for st in store.rt_stacked)
         t_payload = batch_trip // p + (sched.m_pad // q) * f * 4
-    else:
-        K_loc = store.rt_parts.idx.shape[-1]
-        t_payload = n * (K_loc * 8 + 4) // p + (sched.m_pad // q) * f * 4
     t_half = acc_bytes + bufs * t_payload + n * f * 4 // p
     return max(x_half, t_half)
 
@@ -345,22 +339,19 @@ def predicted_stream_stats(store, sched: IterationSchedule, f: int) -> dict:
     and ``*_nnz`` the true ratings under them, from the host-resident cnt
     arrays.  Per-wave granularity is what keeps the prediction exact under
     ragged last waves and mid-iteration resume: the driver sums exactly
-    the waves it executes.  On a ``p > 1`` schedule the solve-X side uses
-    the mesh triplet layout (``x_slice_mesh_triplet``'s pre-padding
-    shapes).  On a degree-binned store the per-wave numbers sum each bin's
-    contiguous span at that bin's own K (``x_slice_binned`` /
+    the waves it executes.  At ``p = 1`` the per-wave numbers sum each
+    bin's contiguous span at that bin's own K (``x_slice_binned`` /
     ``theta_batch_binned``'s exact shapes) — still exact integers, so the
-    ledger's ``fill_waste_ratio`` stays an equality under binning.  A
-    stacked store (``p > 1`` with ``n_bins > 1``) prices the theta half
-    from the batch-uniform ``rt_stacked`` shapes (``theta_wave_stacked``'s
-    exact per-batch payloads) while the solve-X side stays on the uniform
-    mesh layout.
+    ledger's ``fill_waste_ratio`` stays an equality under binning.  On a
+    ``p > 1`` schedule the solve-X side uses the mesh triplet layout
+    (``x_slice_mesh_triplet``'s pre-padding shapes) and the theta half the
+    batch-uniform ``rt_stacked`` shapes (``theta_wave_stacked``'s exact
+    per-batch payloads).
     """
     p = sched.p
-    binned = getattr(store, "r_binned", None) is not None
     cnt_rows = store.r.cnt                    # [m_pad], padded rows cnt = 0
     x_bytes, x_slots, x_nnz = [], [], []
-    if binned:
+    if p == 1:
         for w in sched.waves:
             x_bytes.append(_binned_span_bytes(
                 store.r_binned, w.row_start, w.row_stop))
@@ -368,22 +359,15 @@ def predicted_stream_stats(store, sched: IterationSchedule, f: int) -> dict:
                 store.r_binned, w.row_start, w.row_stop))
             x_nnz.append(int(cnt_rows[w.row_start:w.row_stop].sum()))
     else:
-        if p == 1:
-            K = store.r.K
-            per_row_bytes = K * 8 + 4         # idx + val slots, cnt
-            per_row_slots = K
-        else:
-            K_loc = store.r_model_parts.idx.shape[-1]
-            per_row_bytes = p * (K_loc * 8 + 4)  # [rows, p*K_loc] x2 + [rows, p]
-            per_row_slots = p * K_loc
+        K_loc = store.r_model_parts.idx.shape[-1]
+        per_row_bytes = p * (K_loc * 8 + 4)  # [rows, p*K_loc] x2 + [rows, p]
+        per_row_slots = p * K_loc
         for w in sched.waves:
             x_bytes.append(w.rows * per_row_bytes)
             x_slots.append(w.rows * per_row_slots)
             x_nnz.append(int(cnt_rows[w.row_start:w.row_stop].sum()))
-    n = store.n
-    stacked = getattr(store, "rt_stacked", None)
     t_bytes, t_slots, t_nnz = [], [], []
-    if binned:
+    if p == 1:
         from repro.outofcore.store import binned_nbytes
         shard_bytes = [binned_nbytes(b) for b in store.rt_binned]
         shard_slots = [int(b.padded_slots) for b in store.rt_binned]
@@ -394,28 +378,19 @@ def predicted_stream_stats(store, sched: IterationSchedule, f: int) -> dict:
             t_slots.append(sum(shard_slots[b.index] for b in w.batches))
             t_nnz.append(sum(store.rt_binned[b.index].nnz
                              for b in w.batches))
-    elif stacked is not None:
+    else:
         # batch-uniform stacks: every batch streams the same per-bin shapes
         # (rows_b x K_b triplets), so one constant prices all batches
+        stacked = store.rt_stacked
         batch_trip = sum(st.rows * (st.K * 8 + 4) for st in stacked)
         batch_slots = sum(st.rows * st.K for st in stacked)
+        batch_nnz = sum(st.cnt.sum(axis=1, dtype=np.int64) for st in stacked)
         for w in sched.waves:
             t_bytes.append(sum(
                 batch_trip + (b.row_stop - b.row_start) * f * 4
                 for b in w.batches))
             t_slots.append(len(w.batches) * batch_slots)
-            t_nnz.append(sum(int(store.rt_parts.cnt[b.index].sum())
-                             for b in w.batches))
-    else:
-        K_t = store.rt_parts.idx.shape[-1]
-        batch_trip = n * (K_t * 8 + 4)        # one R^T shard's triplet
-        for w in sched.waves:
-            t_bytes.append(sum(
-                batch_trip + (b.row_stop - b.row_start) * f * 4
-                for b in w.batches))
-            t_slots.append(len(w.batches) * n * K_t)
-            t_nnz.append(sum(int(store.rt_parts.cnt[b.index].sum())
-                             for b in w.batches))
+            t_nnz.append(sum(int(batch_nnz[b.index]) for b in w.batches))
     return {"x_bytes": x_bytes, "x_slots": x_slots, "x_nnz": x_nnz,
             "t_bytes": t_bytes, "t_slots": t_slots, "t_nnz": t_nnz}
 

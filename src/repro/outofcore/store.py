@@ -1,28 +1,25 @@
 """Host-resident stores for out-of-core ALS (paper §4.4 "keep R and R^T").
 
 The rating matrix lives in host memory in *both* orientations, pre-cut into
-the shapes the wave driver streams:
+the shapes the wave driver streams.  Rows are grouped into ~log-spaced
+degree bins, each padded at its own tight K (cuMF's degree binning applied
+to the streaming layout); ``n_bins = 1`` is one bin per orientation.
 
-- ``RatingStore.r`` — R row-major (rows = users), sliced per wave with
-  ``sparse.padded.row_slice`` for the solve-X half.
-- ``RatingStore.rt_parts`` — R^T column-partitioned into the plan's q
-  user-batches (``partition_padded``), one ``[n, K_loc]`` shard per batch
-  with batch-local user coordinates, for the accumulate-Theta half (absent
-  on a binned p = 1 store, which keeps R^T binned only).
+- ``RatingStore.r`` — R row-major (rows = users), kept whole for
+  evaluation and pricing.
+- p = 1 (one device): ``r_binned`` (R binned, cut per solve-X wave by
+  ``x_slice_binned``) and ``rt_binned`` (one ``BinnedELL`` per R^T
+  user-batch of the plan's q, batch-local user coordinates, item rows
+  binned by in-batch degree, for the accumulate-Theta half).
+- p > 1 (mesh streaming): ``r_model_parts`` (R column-partitioned into the
+  p theta shards, the solve-X half's mesh layout) and ``rt_stacked`` (one
+  ``sparse.padded.BinShardStack`` per bin): bin caps are chosen globally
+  across all q batches so every batch's bin presents one ``[rows_b, K_b]``
+  shape the mesh herm stack can shard, while per-batch membership stays
+  free (the ``items`` scatter map carries it).
 
-With ``n_bins > 1`` both orientations additionally carry degree-binned
-shards (``r_binned``, one ``BinnedELL`` per R^T user-batch in
-``rt_binned``): the wave driver streams each wave's rows cut bin-wise
-(``x_slice_binned`` / ``theta_batch_binned``) so heavy rows pay a large K
-and light rows a small one — cuMF's degree binning applied to the
-streaming layout.  With ``p > 1`` (mesh streaming) the theta half instead
-carries batch-uniform stacked bins (``rt_stacked``, one
-``sparse.padded.BinShardStack`` per bin): bin caps are chosen globally
-across all q batches so every batch's bin presents one ``[rows_b, K_b]``
-shape the mesh herm stack can shard, while per-batch membership stays
-free (the ``items`` scatter map carries it).  ``n_bins="auto"`` consults
-the layout autotuner (``repro.core.autotune``) and records the chosen
-config in ``RatingStore.tune`` for the ledger.
+``n_bins="auto"`` consults the layout autotuner (``repro.core.autotune``)
+and records the chosen config in ``RatingStore.tune`` for the ledger.
 
 Factors live in ``FactorStore`` as plain numpy arrays; the driver reads
 slices onto device and writes solved slices back, so device memory only ever
@@ -35,21 +32,12 @@ from typing import Tuple
 
 import numpy as np
 
-from repro.sparse.padded import (BinnedELL, BinShardStack, PaddedELL,
-                                 bin_padded, bin_rows, csr_from_coo,
-                                 pad_csr_fast, pad_rows, partition_padded,
-                                 row_slice, stack_binned_parts)
+from repro.sparse.padded import (BinnedELL, PaddedELL, bin_padded,
+                                 bin_rows, csr_from_coo, pad_csr_fast,
+                                 pad_rows, partition_padded,
+                                 stack_binned_parts)
 
 Triplet = Tuple[np.ndarray, np.ndarray, np.ndarray]
-
-
-def _triplet(ell: PaddedELL) -> Triplet:
-    # copy=False: the arrays are already int32/float32 out of pad_csr_fast /
-    # partition_padded, and row_slice made the one deliberate copy — a
-    # second astype copy per streamed wave would double host traffic
-    return (ell.idx.astype(np.int32, copy=False),
-            ell.val.astype(np.float32, copy=False),
-            ell.cnt.astype(np.int32, copy=False))
 
 
 def triplet_nbytes(t: Triplet) -> int:
@@ -118,14 +106,14 @@ class RatingStore:
     every wave buffer — has identical shape; padded rows carry cnt = 0 and
     solve to x_u = 0 without touching Theta.
 
-    ``n_bins > 1`` additionally materializes degree-binned shards of both
-    orientations (``bin_padded`` re-bins the uniform layouts in place, no
-    COO round trip): the driver then streams each wave bin-wise through
-    ``x_slice_binned`` / ``theta_batch_binned``, cutting padded slots from
-    ``fill`` x nnz down to the per-bin sum.  With ``p > 1`` the theta half
-    is binned batch-uniform instead (``rt_stacked``, globally-chosen caps)
-    so the bins stream on a real (data, model) mesh; the solve-X half stays
-    on the uniform mesh layout (``x_slice_mesh_triplet``).
+    Both orientations are degree-binned at ``n_bins`` (one bin at
+    ``n_bins = 1``): the driver streams each wave bin-wise, so padded slots
+    are the per-bin sum instead of every row padded to the top degree.  At
+    ``p = 1`` R is re-binned from its padded rows (``bin_padded``) and
+    each R^T batch is binned straight from its CSR (``rt_binned``).  With ``p > 1`` the
+    theta half is binned batch-uniform instead (``rt_stacked``,
+    globally-chosen caps) so the bins stream on a real (data, model) mesh;
+    the solve-X half streams the mesh layout (``x_slice_mesh_triplet``).
 
     ``n_bins="auto"`` resolves the bin count (and bin ``k_multiple``)
     through ``repro.core.autotune.tune_als_layout`` — argmin of predicted
@@ -165,20 +153,18 @@ class RatingStore:
         self.r_model_parts = (partition_padded(self.r, p,
                                                k_multiple=k_multiple)
                               if p > 1 else None)
-        # n_bins > 1: degree-binned shards.  p = 1: both orientations, each
-        # R^T shard binned independently (its item degrees are batch-local)
-        # straight from the shard's CSR — the uniform [q, n, K_loc] stack
-        # (``rt_parts``) pads every item to the top in-batch degree, tens of
-        # GB at Netflix scale under item skew, so a binned p = 1 store never
-        # builds it.  p > 1 (mesh): the theta half gets batch-uniform
-        # stacked bins instead — caps chosen globally over all q batches,
-        # per-batch membership carried by the stack's ``items`` map — while
-        # the solve-X half keeps the uniform mesh layout (r_model_parts).
-        self.rt_parts = None
+        # p = 1: both orientations binned, each R^T batch independently
+        # (its item degrees are batch-local) straight from the batch's CSR,
+        # so no [q, n, K_loc] R^T stack padded to the top in-batch degree
+        # (tens of GB at Netflix scale under item skew) is ever built.
+        # p > 1 (mesh): the theta half gets batch-uniform stacked bins —
+        # caps chosen globally over all q batches, per-batch membership
+        # carried by the stack's ``items`` map — cut from the uniform R^T
+        # partition, which is not kept.
         self.r_binned = None
         self.rt_binned = None
         self.rt_stacked = None
-        if n_bins > 1 and p == 1:
+        if p == 1:
             self.r_binned = bin_padded(self.r, n_bins, k_multiple=k_multiple)
             mq = self.m_pad // q
             batch = users // mq
@@ -192,10 +178,9 @@ class RatingStore:
         ptr, cc, vv = csr_from_coo(items, users, vals, self.n)
         rt = pad_csr_fast(ptr, cc, vv, n_cols=self.m_pad,
                           k_multiple=k_multiple)
-        self.rt_parts = partition_padded(rt, q, k_multiple=k_multiple)
-        if n_bins > 1:
-            self.rt_stacked = stack_binned_parts(
-                self.rt_parts, n_bins, k_multiple=k_multiple, p=p)
+        self.rt_stacked = stack_binned_parts(
+            partition_padded(rt, q, k_multiple=k_multiple), n_bins,
+            k_multiple=k_multiple, p=p)
 
     @property
     def nnz(self) -> int:
@@ -204,26 +189,21 @@ class RatingStore:
     @property
     def fill_r(self) -> float:
         """Padding overhead of the row-major orientation (solve-X waves):
-        per-bin padded slots over nnz when binned, uniform-K fill otherwise.
-        """
+        per-bin padded slots over nnz at p = 1, uniform-K fill of R at
+        p > 1 (whose waves stream ``fill_r_model``)."""
         if self.r_binned is not None:
             return self.r_binned.fill
         return self.r.fill
 
     @property
     def fill_rt(self) -> float:
-        """Padding overhead of the q-partitioned R^T shards.  Much worse than
-        ``fill_r`` on power-law data: every item row pads to the max in-batch
-        item degree — feed this to ``plan_for(fill=...)`` so the eq. (8)
+        """Padding overhead of the binned R^T shards: per-bin padded slots
+        over nnz — feed this to ``plan_for(fill=...)`` so the eq. (8)
         budget prices what the driver actually streams."""
-        if self.rt_binned is not None:
-            slots = sum(b.padded_slots for b in self.rt_binned)
-            return float(slots) / max(self.nnz, 1)
-        if self.rt_stacked is not None:
-            slots = sum(st.padded_slots for st in self.rt_stacked)
-            return float(slots) / max(self.nnz, 1)
-        q, n, K_loc = self.rt_parts.idx.shape
-        return float(q * n * K_loc) / max(self.nnz, 1)
+        bins = self.rt_binned if self.rt_binned is not None \
+            else self.rt_stacked
+        slots = sum(b.padded_slots for b in bins)
+        return float(slots) / max(self.nnz, 1)
 
     @property
     def fill_r_model(self) -> float:
@@ -254,17 +234,14 @@ class RatingStore:
 
     def bin_fill_pairs(self) -> list:
         """Per-bin ``(padded_slots, nnz)`` of the worst-fill orientation —
-        the ``plan_for(bin_fills=...)`` pricing input.  Requires a binned
-        store.  p = 1: their aggregate equals ``worst_fill``, so the planner
-        prices exactly the binned bytes the driver streams.  p > 1
-        (stacked): the pairs price the batch-uniform theta-half stacks —
-        the binned component of the mesh run (the uniform solve-X side is
-        priced by ``fill_r_model``)."""
+        the ``plan_for(bin_fills=...)`` pricing input.  p = 1: their
+        aggregate equals ``worst_fill``, so the planner prices exactly the
+        binned bytes the driver streams.  p > 1 (stacked): the pairs price
+        the batch-uniform theta-half stacks — the binned component of the
+        mesh run (the solve-X side is priced by ``fill_r_model``)."""
         if self.rt_stacked is not None:
             return [(int(st.padded_slots), int(st.nnz))
                     for st in self.rt_stacked]
-        assert self.r_binned is not None, \
-            "RatingStore was built with n_bins=1; pass n_bins to price bins"
         if self.fill_r >= self.fill_rt:
             src = self.r_binned.bins
         else:
@@ -274,9 +251,6 @@ class RatingStore:
     @property
     def host_nbytes(self) -> int:
         total = int(self.r.idx.nbytes + self.r.val.nbytes + self.r.cnt.nbytes)
-        if self.rt_parts is not None:
-            total += int(self.rt_parts.idx.nbytes + self.rt_parts.val.nbytes
-                         + self.rt_parts.cnt.nbytes)
         if self.r_model_parts is not None:
             total += int(self.r_model_parts.idx.nbytes
                          + self.r_model_parts.val.nbytes
@@ -289,17 +263,10 @@ class RatingStore:
                          for st in self.rt_stacked)
         return total
 
-    def x_slice_triplet(self, row_start: int, row_stop: int) -> Triplet:
-        """R rows for one solve-X wave slice (global item indices)."""
-        return _triplet(row_slice(self.r, row_start, row_stop))
-
     def x_slice_binned(self, row_start: int, row_stop: int) -> BinnedELL:
         """R rows for one solve-X wave slice, cut bin-wise: a BinnedELL
         whose per-bin rows are slice-local (congruent bin structure across
-        waves — every wave carries all bins, possibly empty).  Requires the
-        store to have been built with ``n_bins > 1``."""
-        assert self.r_binned is not None, \
-            "RatingStore was built with n_bins=1; pass n_bins to bin waves"
+        waves — every wave carries all bins, possibly empty); p = 1."""
         return self.r_binned.row_slice(row_start, row_stop)
 
     def x_slice_mesh_triplet(self, row_start: int, row_stop: int) -> Triplet:
@@ -324,26 +291,10 @@ class RatingStore:
                 val.astype(np.float32, copy=False),
                 cnt.astype(np.int32, copy=False))
 
-    def theta_batch_triplet(self, j: int) -> Triplet:
-        """R^T shard of user-batch ``j`` (batch-local user indices).
-
-        Returns host views into the precomputed shard stack (no per-wave
-        copy — the driver only reads them to stage device transfers).
-        A binned p = 1 store has no uniform stack; stream
-        ``theta_batch_binned`` there."""
-        assert self.rt_parts is not None, \
-            "binned p = 1 RatingStore keeps R^T binned only"
-        assert 0 <= j < self.q, (j, self.q)
-        return (self.rt_parts.idx[j].astype(np.int32, copy=False),
-                self.rt_parts.val[j].astype(np.float32, copy=False),
-                self.rt_parts.cnt[j].astype(np.int32, copy=False))
-
     def theta_batch_binned(self, j: int) -> BinnedELL:
         """Degree-binned R^T shard of user-batch ``j`` (batch-local user
-        indices, item rows grouped by in-batch degree).  Host views — the
-        binned shards are precomputed at store build."""
-        assert self.rt_binned is not None, \
-            "RatingStore was built with n_bins=1; pass n_bins to bin shards"
+        indices, item rows grouped by in-batch degree); p = 1.  Host views
+        — the binned shards are precomputed at store build."""
         assert 0 <= j < self.q, (j, self.q)
         return self.rt_binned[j]
 
@@ -404,11 +355,9 @@ class RatingStore:
         items ``[nbatch, rows_b]``) cut to the wave's batches — host views
         of the precomputed batch-uniform stacks (``items`` stays on host;
         it is the scatter map for the per-bin partials, not a transfer).
-        Requires the store to have been built with ``p > 1`` and
-        ``n_bins > 1``."""
+        Requires the store to have been built with ``p > 1``."""
         assert self.rt_stacked is not None, \
-            "RatingStore was built without stacked bins; pass p > 1 and " \
-            "n_bins > 1 to stream binned waves on a mesh"
+            "RatingStore was built with p=1; pass p to stream on a mesh"
         js = np.asarray(list(batch_indices), dtype=np.int64)
         assert js.size and js.min() >= 0 and js.max() < self.q, (js, self.q)
         return [(st.idx[js], st.val[js], st.cnt[js], st.items[js])

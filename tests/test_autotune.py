@@ -45,15 +45,47 @@ def _store_bytes(r, q, cfg, p):
 # Analytic pricing: exact vs brute-force store builds
 # ---------------------------------------------------------------------------
 
+def _batch0_heavy(seed=0):
+    """SPEC's 96 users x 40 items, where three items are rated by every
+    user of batch 0 (users 0-23 at q = 4) and by nobody else, beside four
+    light ratings a user: the q batches' top item degrees differ (24 in
+    batch 0, a few elsewhere)."""
+    from repro.sparse.padded import csr_from_coo, pad_csr_fast
+
+    rng = np.random.default_rng(seed)
+    rows, cols = [], []
+    for u in range(SPEC.m):
+        heavy = np.arange(3) if u < SPEC.m // 4 else np.zeros(0, np.int64)
+        light = rng.choice(np.arange(3, SPEC.n), 4, replace=False)
+        cols.append(np.concatenate([heavy, light]))
+        rows.append(np.full(cols[-1].size, u))
+    rows, cols = np.concatenate(rows), np.concatenate(cols)
+    vals = rng.normal(size=rows.size).astype(np.float32)
+    return pad_csr_fast(*csr_from_coo(rows, cols, vals, SPEC.m), SPEC.n)
+
+
 @pytest.mark.parametrize("p", [1, 2])
-def test_analytic_pricing_matches_real_store_per_rung(p):
+@pytest.mark.parametrize("problem", ["synth", "batch0_heavy"])
+def test_analytic_pricing_matches_real_store_per_rung(problem, p):
     """Every ladder rung's analytic price equals, to the byte, what the
-    schedule layer predicts for a real store built at that config — on the
-    uniform topology and on a p = 2 mesh (stacked bins)."""
-    r, _, _, _ = _problem()
+    schedule layer predicts for a real store built at that config — on
+    one device (per-batch bins) and on a p = 2 mesh (stacked bins).  On
+    ``batch0_heavy`` one R^T batch's top degree is far above the others',
+    so pricing every batch at the top K of any batch, as a uniform
+    ``[q, n, K_loc]`` R^T stack would stream, misses the store there."""
+    r = _problem()[0] if problem == "synth" else _batch0_heavy()
     for cfg in at.als_ladder(8):
         priced = at.predicted_als_bytes(r, 4, cfg, p=p, f=SPEC.f)
         assert priced["bytes"] == _store_bytes(r, 4, cfg, p), cfg
+    if problem == "batch0_heavy" and p == 1:
+        from repro.outofcore.store import binned_nbytes
+        from repro.sparse.padded import round_k
+
+        store = RatingStore(r, q=4)
+        k_loc = round_k(int(at._batch_item_degrees(r, 4).max()))
+        assert k_loc == 24
+        assert sum(binned_nbytes(b) for b in store.rt_binned) \
+            < 4 * SPEC.n * (k_loc * 8 + 4)
 
 
 @pytest.mark.parametrize("p", [1, 2])

@@ -116,7 +116,8 @@ def test_rating_store_model_partition_roundtrips():
         if live.any():
             assert blk[live].max() < npp
     # slice holds the same nonzero values as the same rows of plain R
-    _, rval, rcnt = store.x_slice_triplet(0, store.m_pad // 4)
+    rows_r = slice(0, store.m_pad // 4)
+    rval, rcnt = store.r.val[rows_r], store.r.cnt[rows_r]
     assert int(cnt.sum()) == int(rcnt.sum())
     np.testing.assert_allclose(np.sort(val[val != 0]),
                                np.sort(rval[rval != 0]), rtol=1e-6)

@@ -19,7 +19,8 @@ from repro.outofcore import (RatingStore, SimulatedFailure, TileStore,
                              run_streaming_sgd)
 from repro.sgd import SgdConfig, block_ell, sgd_train
 from repro.sparse import synth
-from repro.sparse.padded import PaddedELL, bin_padded
+from repro.sparse.padded import (PaddedELL, bin_padded, csr_from_coo,
+                                 pad_csr_fast, partition_padded)
 
 SPEC = synth.SynthSpec("oc", 96, 40, 1500, 8, 0.05)
 
@@ -98,14 +99,20 @@ def test_rating_store_keeps_both_orientations():
     assert store.r.m == store.m_pad
     # padded rows are empty
     assert int(store.r.cnt[r.m:].sum()) == 0
-    # the q R^T shards hold exactly the same nonzeros as R
-    assert int(store.rt_parts.cnt.sum()) == r.nnz == store.nnz
+    # the q binned R^T shards hold exactly the same nonzeros as R, and a
+    # p = 1 store keeps no uniform [q, n, K_loc] R^T stack
+    assert not hasattr(store, "rt_parts")
+    assert sum(b.nnz for b in store.rt_binned) == r.nnz == store.nnz
+    assert sum(int(bb.cnt.sum()) for b in store.rt_binned
+               for bb in b.bins) == int(store.r.cnt.sum())
     # shard j only references batch-local user coordinates
     npp = store.m_pad // 4
     for j in range(4):
-        idx, val, cnt = store.theta_batch_triplet(j)
-        live = np.arange(idx.shape[1])[None, :] < cnt[:, None]
-        assert live.sum() and idx[live].max() < npp
+        shard = store.theta_batch_binned(j)
+        assert shard.m == store.n and shard.n_cols == npp
+        live = [bb.idx[bb.mask() > 0] for bb in shard.bins]
+        assert sum(x.size for x in live) and max(
+            int(x.max()) for x in live if x.size) < npp
     assert store.fill_rt >= 1.0 and store.worst_fill >= store.fill_r
 
 
@@ -116,13 +123,14 @@ def test_rating_store_roundtrips_the_matrix():
     store = RatingStore(r, q=q)
     npp = store.m_pad // q
     for j in range(q):
-        idx, val, cnt = store.x_slice_triplet(j * npp, (j + 1) * npp)
-        live = np.arange(idx.shape[1])[None, :] < cnt[:, None]
-        _, tval, tcnt = store.theta_batch_triplet(j)
-        tlive = np.arange(tval.shape[1])[None, :] < tcnt[:, None]
-        assert int(cnt.sum()) == int(tcnt.sum())
-        np.testing.assert_allclose(val[live].sum(), tval[tlive].sum(),
-                                   rtol=1e-5)
+        rows = slice(j * npp, (j + 1) * npp)
+        val, cnt = store.r.val[rows], store.r.cnt[rows]
+        live = np.arange(val.shape[1])[None, :] < cnt[:, None]
+        tbins = store.theta_batch_binned(j).bins
+        assert int(cnt.sum()) == sum(int(bb.cnt.sum()) for bb in tbins)
+        np.testing.assert_allclose(
+            val[live].sum(), sum(bb.val[bb.mask() > 0].sum() for bb in tbins),
+            rtol=1e-5)
 
 
 def test_build_schedule_covers_rows_once():
@@ -157,14 +165,18 @@ def test_streaming_ragged_last_wave():
     assert tel.peak_bytes <= tel.capacity_bytes
 
 
-@pytest.mark.parametrize("mode,padded", [("ref", 168),
-                                         ("kernel_interpret", 640)])
+@pytest.mark.parametrize("mode,padded", [("ref", 384),
+                                         ("kernel_interpret", 384)])
 def test_streaming_counts_solved_systems(mode, padded):
     """``solve_systems`` counts the rows solved (96 users + 40 items);
-    ``solve_padded_systems`` the systems factored: two waves of 48 rows,
-    each in row blocks of 32 (64 systems), and the 40 items' accumulated
-    systems in one solve (40), and on the kernel path each block and the
-    items widened to the kernel's 128 lanes."""
+    ``solve_padded_systems`` the systems factored.  Each of the two waves
+    of 48 users is one bin (``n_bins`` 1), laid out in whole row blocks
+    of ``batch_rows`` 32 rounded up to 128 lanes: 128 accumulator
+    columns, solved at once.  The 40 items' one bin is likewise 128
+    columns.  ``kops.solve_width(128, 8, mode)`` is 128 in both modes
+    (``ref`` factors what it is given; the kernel's step at f = 8 is 128
+    lanes), so 3 x 128 = 384."""
+    from repro.kernels import ops as kops
     from repro.obs.metrics import MetricsRegistry
 
     r, _, _, _ = _problem()
@@ -174,6 +186,10 @@ def test_streaming_counts_solved_systems(mode, padded):
     sched = build_schedule(_forced_plan(r, q=4, n_data=2, store=store),
                            SPEC.m, SPEC.n, n_data=2)
     assert [w.rows for w in sched.waves] == [48, 48]
+    widths = [store.x_wave_layout(w.row_start, w.row_stop, 32).width
+              for w in sched.waves] + [store.theta_acc_layout(32).width]
+    assert widths == [128, 128, 128]
+    assert padded == sum(kops.solve_width(w, SPEC.f, mode) for w in widths)
     reg = MetricsRegistry()
     run_streaming_als(store, sched, cfg, registry=reg)
     assert reg.counter("solve_systems").value == SPEC.m + SPEC.n
@@ -417,54 +433,59 @@ def test_kill_and_resume_reaches_same_result(tmp_path, kill_after):
 def test_rating_store_binned_invariants():
     """Binned shards hold the same nonzeros, expose per-component fills,
     and price the planner through per-bin (slots, nnz) pairs whose
-    aggregate equals the worst-orientation fill."""
+    aggregate equals the worst-orientation fill.  ``n_bins = 1`` is one
+    bin per orientation, at the uniform K on the X side."""
     r, _, _, _ = _problem()
     store_u = RatingStore(r, q=4)
     store_b = RatingStore(r, q=4, n_bins=4)
     assert store_b.n_bins == 4 and store_u.n_bins == 1
     assert store_b.r_binned.nnz == r.nnz
     assert sum(b.nnz for b in store_b.rt_binned) == r.nnz
-    # binned fills never exceed the uniform ones
+    # one bin: every row at R's own K, every R^T batch at its top degree
+    assert len(store_u.r_binned.bins) == 1
+    assert store_u.r_binned.bins[0].K == store_u.r.K
+    assert store_u.fill_r == store_u.r.fill
+    assert all(len(b.bins) == 1 for b in store_u.rt_binned)
+    # more bins never pad more than one
     assert store_b.fill_r <= store_u.fill_r
     assert store_b.fill_rt <= store_u.fill_rt
     assert store_b.worst_fill <= store_u.worst_fill
     fb = store_b.fill_breakdown()
     assert set(fb) == {"r", "rt"}
     assert fb["r"] == store_b.fill_r and fb["rt"] == store_b.fill_rt
-    pairs = store_b.bin_fill_pairs()
-    slots = sum(s for s, _ in pairs)
-    nnz = sum(z for _, z in pairs)
-    assert abs(slots / nnz - store_b.worst_fill) < 1e-12
+    for store in (store_u, store_b):
+        pairs = store.bin_fill_pairs()
+        slots = sum(s for s, _ in pairs)
+        nnz = sum(z for _, z in pairs)
+        assert abs(slots / nnz - store.worst_fill) < 1e-12
     # the planner prices exactly that aggregate
+    pairs = store_b.bin_fill_pairs()
     pa = plan_for(SPEC.m, SPEC.n, r.nnz, SPEC.f, p=1, q=4,
                   fill=store_b.worst_fill)
     pb = plan_for(SPEC.m, SPEC.n, r.nnz, SPEC.f, p=1, q=4, bin_fills=pairs)
     assert pa.terms["R_shard"] == pb.terms["R_shard"]
     # row slices cover the binned matrix exactly
     npp = store_b.m_pad // 4
-    assert sum(store_b.x_slice_binned(j * npp, (j + 1) * npp).nnz
-               for j in range(4)) == r.nnz
-    # uniform stores don't grow binned shards or accept binned queries
-    assert store_u.r_binned is None
-    with pytest.raises(AssertionError):
-        store_u.x_slice_binned(0, npp)
+    for store in (store_u, store_b):
+        assert sum(store.x_slice_binned(j * npp, (j + 1) * npp).nnz
+                   for j in range(4)) == r.nnz
 
 
 def test_binned_store_bins_rt_from_csr_like_the_uniform_shards():
-    """A binned p = 1 store bins each batch's R^T straight from CSR and
-    keeps no uniform R^T stack; its bins equal re-binning the uniform
-    store's shards, slot for slot."""
+    """A p = 1 store bins each batch's R^T straight from CSR and keeps no
+    uniform R^T stack; its bins equal re-binning the q-partitioned padded
+    R^T, built here, slot for slot."""
     r, _, _, _ = _problem()
-    store_u = RatingStore(r, q=4)
     store_b = RatingStore(r, q=4, n_bins=4)
-    assert store_b.rt_parts is None
-    with pytest.raises(AssertionError):
-        store_b.theta_batch_triplet(0)
+    assert not hasattr(store_b, "rt_parts")
+    items, users, vals = store_b.r.transpose_coo()
+    rt = pad_csr_fast(*csr_from_coo(items, users, vals, store_b.n),
+                      n_cols=store_b.m_pad)
+    parts = partition_padded(rt, 4)
     for j, got in enumerate(store_b.rt_binned):
         want = bin_padded(
-            PaddedELL(idx=store_u.rt_parts.idx[j], val=store_u.rt_parts.val[j],
-                      cnt=store_u.rt_parts.cnt[j],
-                      n_cols=store_u.m_pad // 4), 4)
+            PaddedELL(idx=parts.idx[j], val=parts.val[j], cnt=parts.cnt[j],
+                      n_cols=store_b.m_pad // 4), 4)
         assert got.m == want.m and got.n_cols == want.n_cols
         for gb, wb, gr, wr in zip(got.bins, want.bins, got.rows, want.rows):
             np.testing.assert_array_equal(gr, wr)
